@@ -579,9 +579,8 @@ def _sharded_rows() -> List[Row]:
     4-host-device mesh, reported as *per-slot step cost* (drain time /
     steps / slots) so the quotients vs the 1-shard oracle isolate the
     per-step sharding overhead: one sharded dispatch plus host-local
-    scheduling, no gathers on the hot path.  Runs in a subprocess; on
-    failure the rows are skipped (the gate treats missing rows as a
-    skip, never a pass/fail)."""
+    scheduling, no gathers on the hot path.  Runs in a subprocess on
+    forced CPU host devices; a failed child raises."""
     import json
     import os
     import subprocess
@@ -589,15 +588,16 @@ def _sharded_rows() -> List[Row]:
 
     env = dict(os.environ)
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+    env["JAX_PLATFORMS"] = "cpu"       # forced host devices, never a chip
     r = subprocess.run([sys.executable, "-c", _SHARDED_SCRIPT], env=env,
                        capture_output=True, text=True, timeout=1200)
     print("\n== decode_costs: data-axis sharded engine drains ==")
     line = next((ln for ln in r.stdout.splitlines()
                  if ln.startswith("SHARDED_ROWS ")), None)
     if r.returncode != 0 or line is None:
-        print(f"sharded drains skipped (subprocess rc={r.returncode}): "
-              f"{r.stderr[-500:]}")
-        return []
+        raise RuntimeError(
+            f"sharded drains failed (subprocess rc={r.returncode}): "
+            f"{r.stderr[-2000:]}")
     rows = [tuple(row) for row in json.loads(line.split(" ", 1)[1])]
     for name, us, derived in rows:
         print(f"{name}: {us:.1f}us/slot-step  {derived}")

@@ -147,7 +147,6 @@ class ModelConfig:
     svdq_bits: Tuple[int, ...] = ()      # per-rank key bits for svdq,
                                          # non-increasing {8,4,2}; () =>
                                          # default_svdq_bits at the rank
-    use_pallas: bool = False             # TPU path; CPU dry-run uses lax
     scan_layers: bool = True             # stack layers & lax.scan over them
     remat_policy: str = "nothing"        # nothing | dots | full
     attn_block_q: int = 512              # blockwise-attention tiles

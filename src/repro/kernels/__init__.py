@@ -8,14 +8,24 @@ ssd/        Mamba-2 SSD chunk scan (jamba / mamba2 hot spot; inter-chunk
 
 Each kernel ships <name>.py (pl.pallas_call + BlockSpec), ops.py (jit'd
 wrapper) and ref.py (pure-jnp oracle); tests sweep shapes/dtypes in
-interpret mode.  The lax blockwise path in repro.models.attention is the
-dry-run/compile twin (Pallas TPU kernels do not lower on the CPU backend).
+interpret mode.  The model's attention runs the kq_decode kernels on a
+TPU backend (``use_kernels``); elsewhere it runs the lax twins in
+repro.models.attention, which are also the kernels' test references.
 """
 
 import jax
 import jax.numpy as jnp
 
 LANE = 128          # TPU lane count: Mosaic trailing-axis multiple
+
+
+def use_kernels() -> bool:
+    """Whether model attention dispatches the Pallas kernels: decided by
+    the platform, never by a setting — on TPU the kernels are the path,
+    elsewhere the lax twins run.  Read at trace time through the module
+    attribute, so a test can patch it to drive the interpret-mode
+    kernels through the whole model on CPU."""
+    return jax.default_backend() == "tpu"
 
 
 def default_interpret() -> bool:

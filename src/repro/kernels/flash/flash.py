@@ -17,11 +17,14 @@ from __future__ import annotations
 
 import functools
 import math
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels import default_interpret
 
 NEG_INF = -1e30
 
@@ -78,8 +81,11 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
 
 def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
                     block_q: int = 512, block_k: int = 512,
-                    scale=None, interpret: bool = True):
-    """q: (B,H,S,dh); k/v: (B,Hkv,S,dh) -> (B,H,S,dh)."""
+                    scale=None, interpret: Optional[bool] = None):
+    """q: (B,H,S,dh); k/v: (B,Hkv,S,dh) -> (B,H,S,dh).  ``interpret``
+    defaults to the backend: Mosaic on TPU, the interpreter elsewhere."""
+    if interpret is None:
+        interpret = default_interpret()
     B, H, S, dh = q.shape
     Hkv = k.shape[1]
     dv = v.shape[-1]

@@ -9,7 +9,6 @@ import functools
 
 import jax
 
-from repro.kernels import default_interpret
 from repro.kernels.flash.flash import flash_attention
 
 
@@ -18,8 +17,6 @@ from repro.kernels.flash.flash import flash_attention
 def flash_attention_op(q, k, v, *, causal=True, window=0, block_q=512,
                        block_k=512, interpret=None):
     """jit'd flash attention (``flash_attention``); q/k/v (B,H,T,d)."""
-    if interpret is None:
-        interpret = default_interpret()
     return flash_attention(q, k, v, causal=causal, window=window,
                            block_q=block_q, block_k=block_k,
                            interpret=interpret)

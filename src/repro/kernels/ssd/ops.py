@@ -9,14 +9,11 @@ import functools
 
 import jax
 
-from repro.kernels import default_interpret
 from repro.kernels.ssd.ssd import ssd_chunk_scan
 
 
 @functools.partial(jax.jit, static_argnames=("chunk", "interpret"))
 def ssd_chunk_scan_op(x, a, dt, B, C, *, chunk=128, interpret=None):
     """jit'd SSD chunk scan (``ssd_chunk_scan``) over chunked time."""
-    if interpret is None:
-        interpret = default_interpret()
     return ssd_chunk_scan(x, a, dt, B, C, chunk=chunk,
                           interpret=interpret)

@@ -16,11 +16,14 @@ kernel; a = dt * A and dt come in precomputed as (B, nh, S) f32.
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels import default_interpret
 
 
 def _ssd_kernel(x_ref, a_ref, dt_ref, b_ref, c_ref, y_ref, h_ref, *,
@@ -62,8 +65,12 @@ def _ssd_kernel(x_ref, a_ref, dt_ref, b_ref, c_ref, y_ref, h_ref, *,
 
 
 def ssd_chunk_scan(x, a, dt, B, C, *, chunk: int = 128,
-                   interpret: bool = True):
-    """x: (B,nh,S,hd); a=dt*A, dt: (B,nh,S); B/C: (B,G,S,n) -> y like x."""
+                   interpret: Optional[bool] = None):
+    """x: (B,nh,S,hd); a=dt*A, dt: (B,nh,S); B/C: (B,G,S,n) -> y like x.
+    ``interpret`` defaults to the backend: Mosaic on TPU, the
+    interpreter elsewhere."""
+    if interpret is None:
+        interpret = default_interpret()
     Bsz, nh, S, hd = x.shape
     G, n = B.shape[1], B.shape[-1]
     rep = nh // G

@@ -6,17 +6,62 @@
 from __future__ import annotations
 
 import argparse
+from typing import List, Optional, Sequence
 
 import jax
 import numpy as np
 
-from repro.config import CompressionConfig, ServeConfig
+from repro.config import CompressionConfig, ModelConfig, ServeConfig
 from repro.configs import get_config
-from repro.core.calibration import calibrate_model
+from repro.core.calibration import ModelProjections, calibrate_model
 from repro.core.compressed import cache_footprint
 from repro.data import calibration_batches
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import build_model
 from repro.serving import Request, ServingEngine
+
+
+def calibrated_model(cfg: ModelConfig, *, method: str = "kqsvd",
+                     epsilon: float = 0.1, calib_seqs: int = 8,
+                     calib_len: int = 64, seed: int = 0
+                     ) -> tuple[dict, Optional[ModelProjections]]:
+    """Weights drawn from ``seed`` plus, for a compressing ``method``,
+    projections calibrated on seeded synthetic batches: the
+    ``(params, projections)`` pair ``ServingEngine`` serves."""
+    model = build_model(cfg)
+    params = model.init(jax.random.PRNGKey(seed))
+    if method == "none" or cfg.attention_free:
+        return params, None
+    calib = calibration_batches(cfg.vocab_size, calib_seqs, calib_len,
+                                batch=4)
+    proj = calibrate_model(model, params,
+                           [jax.numpy.asarray(b) for b in calib],
+                           CompressionConfig(method=method,
+                                             epsilon=epsilon))
+    return params, proj
+
+
+def synthetic_requests(vocab_size: int, lens: Sequence[int],
+                       max_new_tokens: int, rng: np.random.Generator, *,
+                       shared_frac: float = 0.0,
+                       tiers: Sequence[int] = (0,),
+                       deadline_steps: Optional[int] = None
+                       ) -> List[Request]:
+    """One request per prompt length, tokens drawn from ``rng``; the
+    first ``shared_frac`` of each prompt comes from one common prefix
+    and priorities cycle over ``tiers``."""
+    common = rng.integers(0, vocab_size,
+                          max(int(max(lens)), 1)).astype(np.int32)
+    reqs = []
+    for i, n in enumerate(int(x) for x in lens):
+        n_common = min(int(round(shared_frac * n)), n - 1)
+        tail = rng.integers(0, vocab_size, n - n_common).astype(np.int32)
+        reqs.append(Request(rid=i,
+                            prompt=np.concatenate([common[:n_common], tail]),
+                            max_new_tokens=max_new_tokens,
+                            priority=tiers[i % len(tiers)],
+                            deadline_steps=deadline_steps))
+    return reqs
 
 
 def main() -> None:
@@ -143,6 +188,7 @@ def main() -> None:
     ap.add_argument("--chaos-rate", type=float, default=0.05,
                     help="per-hit fault probability under --chaos-seed")
     args = ap.parse_args()
+    enable_compile_cache()
     if args.shards > 1 and not args.prefill_chunk:
         print("--shards shards the chunked-prefill dispatch: enabling "
               "chunked prefill (--prefill-chunk 8)")
@@ -186,18 +232,11 @@ def main() -> None:
     cfg = get_config(args.arch)
     if args.reduced:
         cfg = cfg.reduced()
-    model = build_model(cfg)
-    params = model.init(jax.random.PRNGKey(0))
-
-    proj = None
-    if args.method != "none" and not cfg.attention_free:
-        calib = calibration_batches(cfg.vocab_size, args.calib_seqs,
-                                    args.calib_len, batch=4)
-        ccfg = CompressionConfig(method=args.method,
-                                 epsilon=args.epsilon)
-        proj = calibrate_model(model, params,
-                               [jax.numpy.asarray(b) for b in calib],
-                               ccfg)
+    params, proj = calibrated_model(cfg, method=args.method,
+                                    epsilon=args.epsilon,
+                                    calib_seqs=args.calib_seqs,
+                                    calib_len=args.calib_len)
+    if proj is not None:
         fp = cache_footprint(max(cfg.n_kv_heads, 1), cfg.d_head or 1,
                              proj.rank_k, proj.rank_v)
         print(f"calibrated {args.method}: ranks k={proj.ranks_k} "
@@ -233,21 +272,10 @@ def main() -> None:
     lens = rng.integers(min(4, args.prompt_len), args.prompt_len + 1,
                         args.requests)
     tiers = [int(x) for x in args.priority.split(",") if x.strip()] or [0]
-    common = rng.integers(0, cfg.vocab_size,
-                          max(int(lens.max()), 1)).astype(np.int32)
-
-    def mk_prompt(i):
-        n = int(lens[i])
-        n_common = min(int(round(args.shared_frac * n)), n - 1)
-        tail = rng.integers(0, cfg.vocab_size, n - n_common)
-        return np.concatenate([common[:n_common],
-                               tail.astype(np.int32)])
-
-    reqs = [Request(rid=i, prompt=mk_prompt(i),
-                    max_new_tokens=args.max_new_tokens,
-                    priority=tiers[i % len(tiers)],
-                    deadline_steps=args.deadline_steps or None)
-            for i in range(args.requests)]
+    reqs = synthetic_requests(cfg.vocab_size, lens, args.max_new_tokens,
+                              rng, shared_frac=args.shared_frac,
+                              tiers=tiers,
+                              deadline_steps=args.deadline_steps or None)
     eng.generate(reqs)
     for r in reqs:
         note = "  [truncated]" if r.truncated else ""

@@ -13,6 +13,7 @@ import argparse
 from repro.config import TrainConfig
 from repro.configs import get_config
 from repro.data import DataConfig, batches
+from repro.launch.compile_cache import enable_compile_cache
 from repro.train import Trainer
 
 
@@ -33,6 +34,7 @@ def main() -> None:
     ap.add_argument("--ckpt-dir", default=None)
     ap.add_argument("--checkpoint-every", type=int, default=50)
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_config(args.arch)
     if args.reduced:

@@ -27,6 +27,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import kernels
 from repro.config import ModelConfig
 from repro.kernels.kq_decode.kq_decode import kq_decode_attention
 from repro.kernels.kq_decode.paged import (kq_decode_paged_attention,
@@ -311,8 +312,8 @@ def split_decode_attention(q, cache_k, cache_v, valid_mask, scale,
     segment contributes a partial (out, LSE) pair, and the pairs merge
     with the log-sum-exp rule — the same math as the Pallas split
     kernel's combine pass, in plain lax.  Exercised as the paged decode
-    path whenever ``decode_splits > 1`` without ``use_pallas``, so the
-    whole serving suite covers the split+combine algebra on CPU.
+    path whenever ``decode_splits > 1`` off TPU, so the whole serving
+    suite covers the split+combine algebra on CPU.
 
     q: (B,H,1,dk); cache_k/v: (B,Hkv,T,*); valid_mask: (T,) or (B,T).
     Returns (B,Hkv,m,rv) like ``decode_attention``.
@@ -611,6 +612,8 @@ def attn_prefill_chunk(p, x, cache: Dict, pos0, cfg: ModelConfig,
     Hkv = cfg.n_kv_heads
     Hp = padded_heads(cfg)
     m_p = Hp // Hkv
+    qg = q.reshape(B, Hkv, m_p, S, dh)
+    quant = False
     if proj is not None:
         k_st = jnp.einsum("bhtd,hdr->bhtr", k_new, proj["a_k"])
         v_st = jnp.einsum("bhtd,hdr->bhtr", v_new, proj["a_v"])
@@ -626,52 +629,46 @@ def attn_prefill_chunk(p, x, cache: Dict, pos0, cfg: ModelConfig,
             for name, val in enc.items():
                 new_cache[name] = append_chunk(cache[name], block_table,
                                                pos0, val, wvalid)
-            kc, vc = new_cache["kc"], new_cache["vc"]
         else:
             kc = append_chunk(cache["kc"], block_table, pos0, k_st, wvalid)
             vc = append_chunk(cache["vc"], block_table, pos0, v_st, wvalid)
             new_cache = dict(cache, kc=kc, vc=vc)
-        qg = q.reshape(B, Hkv, m_p, S, dh)
-        qc = jnp.einsum("bgmsd,gdr->bgmsr", qg, proj["b_q"])
-        if quant:
-            # dequantize-then-attend lax twin: prefill is compute-bound
-            # (the decode kernels carry the int8 HBM story), so chunks
-            # gather + dequantize the written pages for every layout
-            rk_ = proj["a_k"].shape[-1]
-            rv_ = proj["a_v"].shape[-1]
-            k_seq = layout.decode("k", {
-                name: gather_pages(new_cache[name], block_table)
-                for name, _, _ in layout.leaves("k", rk_)}, rk_)
-            v_seq = layout.decode("v", {
-                name: gather_pages(new_cache[name], block_table)
-                for name, _, _ in layout.leaves("v", rv_)}, rv_)
-            agg = chunk_decode_attention(qc, k_seq, v_seq, positions,
-                                         scale)
-        elif cfg.use_pallas:
-            # TPU runtime hot path: the prefill-append kernel streams
-            # the written pages in place via the block table
-            agg = kq_prefill_paged_attention(
-                qc.reshape(B, Hp, S, -1), kc, vc, lengths, pos0,
-                block_table, scale=scale,
-                max_len=T).reshape(B, Hkv, m_p, S, -1)
-        else:
-            # lax reference: materialize the slot's pages, then the
-            # masked chunk attention (parity oracle for the kernel)
-            k_seq = gather_pages(kc, block_table)
-            v_seq = gather_pages(vc, block_table)
-            agg = chunk_decode_attention(qc, k_seq, v_seq, positions,
-                                         scale)
+        qg = jnp.einsum("bgmsd,gdr->bgmsr", qg, proj["b_q"])
+    else:
+        kc = append_chunk(cache["k"], block_table, pos0, k_new, wvalid)
+        vc = append_chunk(cache["v"], block_table, pos0, v_new, wvalid)
+        new_cache = dict(cache, k=kc, v=vc)
+    if quant:
+        # dequantize-then-attend lax twin: prefill is compute-bound
+        # (the decode kernels carry the int8 HBM story), so chunks
+        # gather + dequantize the written pages for every layout
+        rk_ = proj["a_k"].shape[-1]
+        rv_ = proj["a_v"].shape[-1]
+        k_seq = layout.decode("k", {
+            name: gather_pages(new_cache[name], block_table)
+            for name, _, _ in layout.leaves("k", rk_)}, rk_)
+        v_seq = layout.decode("v", {
+            name: gather_pages(new_cache[name], block_table)
+            for name, _, _ in layout.leaves("v", rv_)}, rv_)
+        agg = chunk_decode_attention(qg, k_seq, v_seq, positions, scale)
+    elif kernels.use_kernels():
+        # TPU path: the prefill-append kernel streams the written pages
+        # in place via the block table
+        agg = kq_prefill_paged_attention(
+            qg.reshape(B, Hp, S, -1), kc, vc, lengths, pos0,
+            block_table, scale=scale,
+            max_len=T).reshape(B, Hkv, m_p, S, -1)
+    else:
+        # lax path and the kernel's reference: materialize the slot's
+        # pages, then the masked chunk attention
+        agg = chunk_decode_attention(qg, gather_pages(kc, block_table),
+                                     gather_pages(vc, block_table),
+                                     positions, scale)
+    if proj is not None:
         m = cfg.n_heads // Hkv                  # real heads (c_v is real-m)
         c_v = proj["c_v"].reshape(Hkv, -1, m, cfg.d_model)
         y = jnp.einsum("bgmsr,grmd->bsd", agg[:, :, :m], c_v)
     else:
-        kk = append_chunk(cache["k"], block_table, pos0, k_new, wvalid)
-        vv = append_chunk(cache["v"], block_table, pos0, v_new, wvalid)
-        new_cache = dict(cache, k=kk, v=vv)
-        k_seq = gather_pages(kk, block_table)
-        v_seq = gather_pages(vv, block_table)
-        qg = q.reshape(B, Hkv, m_p, S, dh)
-        agg = chunk_decode_attention(qg, k_seq, v_seq, positions, scale)
         out = agg.reshape(B, Hp, S, dh)
         y = jnp.einsum("bhse,hed->bsd", out, p["wo"])
     return y.astype(x.dtype), new_cache
@@ -783,7 +780,7 @@ def attn_decode(p, x, cache: Dict, pos, cfg: ModelConfig,
         # and split-KV variants); the lax twin runs the same
         # dot-then-scale math on gathered pages
         Hkv = cfg.n_kv_heads
-        if cfg.use_pallas:
+        if kernels.use_kernels():
             agg = kq_decode_paged_attention(
                 qq.reshape(B, -1, qq.shape[-1]), keys, vals, pos + 1,
                 block_table, scale=scale, max_len=T,
@@ -819,17 +816,17 @@ def attn_decode(p, x, cache: Dict, pos, cfg: ModelConfig,
                                          num_splits)
         else:
             agg = decode_attention(qq, k_seq, v_seq, valid, scale)
-    elif paged and proj is not None and cfg.use_pallas:
-        # TPU runtime hot path, paged: the kernel dereferences the block
-        # table via scalar prefetch — no page gather is materialized
+    elif paged and kernels.use_kernels():
+        # TPU path, paged: the kernel dereferences the block table via
+        # scalar prefetch — no page gather is materialized
         Hkv = cfg.n_kv_heads
         agg = kq_decode_paged_attention(
             qq.reshape(B, -1, qq.shape[-1]), keys, vals, pos + 1,
             block_table, scale=scale, max_len=T,
             num_splits=num_splits).reshape(B, Hkv, -1, vals.shape[-1])
     elif paged:
-        # lax reference: materialize each slot's pages, then the dense
-        # masked decode (parity oracle for the paged kernel); with
+        # lax path and the paged kernel's reference: materialize each
+        # slot's pages, then the dense masked decode; with
         # decode_splits > 1 the split twin runs the same partial-LSE
         # merge the split kernel uses
         k_seq = gather_pages(keys, block_table)
@@ -839,9 +836,9 @@ def attn_decode(p, x, cache: Dict, pos, cfg: ModelConfig,
                                          num_splits)
         else:
             agg = decode_attention(qq, k_seq, v_seq, valid, scale)
-    elif proj is not None and cfg.use_pallas and not W:
-        # TPU runtime hot path: the Pallas kernel streams the compressed
-        # cache with per-sequence lengths (interpret-mode on CPU)
+    elif kernels.use_kernels() and not W:
+        # TPU path, dense: the Pallas kernel streams the cache with
+        # per-sequence lengths
         Hkv = cfg.n_kv_heads
         agg = kq_decode_attention(
             qq.reshape(B, -1, qq.shape[-1]), keys, vals, pos + 1,

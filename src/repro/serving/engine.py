@@ -127,7 +127,6 @@ from typing import Any, Dict, List, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.config import ModelConfig, ServeConfig
@@ -1833,6 +1832,17 @@ class ServingEngine:
             pass
         return requests
 
+    def lower_decode(self):
+        """The fused decode dispatch ``step()`` runs, lowered at the
+        started engine's state shapes — for compile checks such as
+        whether a TPU build carries its Pallas kernels."""
+        assert self._started, "call start(requests) first"
+        btab = self._btabs.device() if self.sc.paged else None
+        return self._decode_chunk.lower(
+            self.params, self.proj, self._cache, self._logits, self._pos,
+            self._emitted, self._max_new, self._done, self._trunc,
+            self.rng, btab, num_splits=self._decode_splits)
+
 
 # ---------------------------------------------------------------------------
 # Data-axis sharded engine (DESIGN.md §sharded-engine)
@@ -2035,6 +2045,11 @@ class ShardedServingEngine(ServingEngine):
         sc = self.sc
         S = sc.shards
         self._mesh = partition.serve_mesh(S)
+        # weights and projections replicate onto every shard's device
+        # once; left on one device, each dispatch would send them again
+        rep = partition.named(self._mesh)
+        self.params = jax.device_put(self.params, rep)
+        self.proj = jax.device_put(self.proj, rep)
         # per-shard sampling keys must exist before the workers: base
         # __init__ assigns worker.rng through the routed property
         self._g_rng = jnp.stack(
@@ -2162,11 +2177,11 @@ class ShardedServingEngine(ServingEngine):
             return self._prefill_chunk_impl(params, proj, cache, tokens,
                                             pos0, n_valid, rows)
 
-        return shard_map(
-            _body, self._mesh,
+        return jax.shard_map(
+            _body, mesh=self._mesh,
             in_specs=(self._cache_spec(), d, d, d, d),
             out_specs=(d, self._cache_spec()),
-            check_rep=False)(cache, tokens, pos0, n_valid, rows)
+            check_vma=False)(cache, tokens, pos0, n_valid, rows)
 
     def _sharded_decode_impl(self, params, proj, cache, logits, pos,
                              emitted, max_new, done, trunc, rngs,
@@ -2190,12 +2205,12 @@ class ShardedServingEngine(ServingEngine):
             return (logits, cache, pos, emitted, done, trunc, rng[None],
                     toks, emits)
 
-        return shard_map(
-            _body, self._mesh,
+        return jax.shard_map(
+            _body, mesh=self._mesh,
             in_specs=(cspec, d, d, d, d, d, d, d, d),
             out_specs=(d, cspec, d, d, d, d, d, P(None, "data"),
                        P(None, "data")),
-            check_rep=False)(cache, logits, pos, emitted, max_new, done,
+            check_vma=False)(cache, logits, pos, emitted, max_new, done,
                              trunc, rngs, block_table)
 
     # -- lifecycle -----------------------------------------------------------

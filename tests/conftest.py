@@ -138,6 +138,14 @@ def rng():
     return np.random.default_rng(0)
 
 
+@pytest.fixture
+def tpu_kernels(monkeypatch):
+    """Route model attention through the Pallas kernels as on a TPU
+    backend; off TPU they run in interpret mode."""
+    import repro.kernels
+    monkeypatch.setattr(repro.kernels, "use_kernels", lambda: True)
+
+
 def dropless(cfg):
     """Reduced config with capacity high enough that no token drops
     (required for exact train/decode consistency checks)."""

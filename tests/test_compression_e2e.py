@@ -133,7 +133,7 @@ def test_int8_compressed_cache_close_to_bf16():
 # ---------------------------------------------------------------------------
 
 
-def _compressed_varlen(cfg_xform=None, use_pallas=False, rtol=1e-4):
+def _compressed_varlen(cfg_xform=None, rtol=1e-4):
     """Per-sequence-position compressed decode == per-request decode."""
     from test_attention import merge_slot_caches
     cfg, model, params, acc = calibrated("tinyllama-1.1b", n_batches=2)
@@ -142,8 +142,6 @@ def _compressed_varlen(cfg_xform=None, use_pallas=False, rtol=1e-4):
     mp = acc.solve(ccfg, model.group_output_weights(params))
     if cfg_xform is not None:
         cfg = cfg_xform(cfg)
-    if use_pallas:
-        cfg = dataclasses.replace(cfg, use_pallas=True)
     model = build_model(cfg)
     proj = model.projections_pytree(mp, jnp.float32)
     lens, extra = (6, 13, 9), 3
@@ -183,8 +181,8 @@ def test_varlen_compressed_decode_int8():
         rtol=0.05)
 
 
-def test_varlen_compressed_decode_pallas_kernel():
-    """cfg.use_pallas routes compressed decode through the lengths-aware
+def test_varlen_compressed_decode_pallas_kernel(tpu_kernels):
+    """As on a TPU backend, compressed decode runs the lengths-aware
     Pallas kernel (interpret mode on CPU); outputs must match the lax
     path bit-for-tolerance."""
-    _compressed_varlen(use_pallas=True)
+    _compressed_varlen()

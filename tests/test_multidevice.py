@@ -16,8 +16,9 @@ SCRIPT = r"""
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import dataclasses
+import tempfile
 import jax, jax.numpy as jnp, numpy as np
-from jax.sharding import NamedSharding, PartitionSpec as P
+from jax.sharding import AxisType, NamedSharding, PartitionSpec as P
 
 from repro.configs import get_config
 from repro.config import TrainConfig
@@ -31,7 +32,8 @@ from repro.checkpoint.manager import CheckpointManager
 cfg = get_config("tinyllama-1.1b").reduced()
 cfg = dataclasses.replace(cfg, n_layers=4)
 model = build_model(cfg)
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+AUTO = (AxisType.Auto,) * 2
+mesh = jax.make_mesh((2, 4), ("data", "model"), axis_types=AUTO)
 
 with use_mesh(mesh):
     params = model.init(jax.random.PRNGKey(0))
@@ -73,10 +75,10 @@ with use_mesh(mesh):
     print("DECODE_COMPILE_OK")
 
     # elastic: save on (2,4), restore onto (4,2)
-    ck = CheckpointManager("/tmp/repro_md_ckpt", keep=1, async_save=False)
+    ck = CheckpointManager(tempfile.mkdtemp(), keep=1, async_save=False)
     ck.save(1, {"params": p2})
 
-mesh2 = jax.make_mesh((4, 2), ("data", "model"))
+mesh2 = jax.make_mesh((4, 2), ("data", "model"), axis_types=AUTO)
 with use_mesh(mesh2):
     template = jax.eval_shape(lambda: model.init(jax.random.PRNGKey(0)))
     ps2 = params_shardings(template, mesh2, fsdp=True)
@@ -96,6 +98,7 @@ def test_multidevice_subprocess():
     env = dict(os.environ)
     env["PYTHONPATH"] = os.path.join(os.path.dirname(__file__), "..",
                                      "src")
+    env["JAX_PLATFORMS"] = "cpu"       # forced host devices, never a chip
     r = subprocess.run([sys.executable, "-c", SCRIPT], env=env,
                        capture_output=True, text=True, timeout=900)
     assert r.returncode == 0, r.stdout[-2000:] + r.stderr[-4000:]

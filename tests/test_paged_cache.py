@@ -192,10 +192,8 @@ def test_lane_padding_paged():
 # ---------------------------------------------------------------------------
 
 
-def _tiny(compressed=False, use_pallas=False, rank=None):
+def _tiny(compressed=False, rank=None):
     cfg = get_config("tinyllama-1.1b").reduced()
-    if use_pallas:
-        cfg = dataclasses.replace(cfg, use_pallas=True)
     model = build_model(cfg)
     params = model.init(jax.random.PRNGKey(0))
     proj = None
@@ -246,10 +244,11 @@ def test_paged_engine_matches_dense_mixed_lengths():
     assert eng.pool.free_count == eng.pool.n_pages
 
 
-def test_paged_engine_compressed_pallas_kernel():
-    """Compressed cache + use_pallas: the paged Pallas kernel runs
-    inside the fused decode scan and matches the dense engine."""
-    cfg, model, params, proj = _tiny(compressed=True, use_pallas=True)
+def test_paged_engine_compressed_pallas_kernel(tpu_kernels):
+    """Compressed cache with the kernels on the path, as on a TPU
+    backend: the paged Pallas kernel runs inside the fused decode scan
+    and matches the dense engine."""
+    cfg, model, params, proj = _tiny(compressed=True)
     prompts = _mixed_prompts(cfg, [4, 11, 7], seed=5)
     sc = ServeConfig(max_seq_len=32, max_batch=2, temperature=0.0,
                      decode_chunk=4)
@@ -258,6 +257,25 @@ def test_paged_engine_compressed_pallas_kernel():
     _, paged = _run(cfg, params, proj, sc_p, prompts, max_new=5)
     for d, p in zip(dense, paged):
         assert d.out_tokens == p.out_tokens, d.rid
+
+
+def test_engine_full_cache_kernels_match_lax(monkeypatch):
+    """A full (uncompressed) cache takes the kernels too when they are
+    on the path: dense decode, paged decode and paged chunked prefill
+    all match the lax engine token for token."""
+    import repro.kernels
+    cfg, model, params, _ = _tiny()
+    prompts = _mixed_prompts(cfg, [4, 11, 7], seed=9)
+    sc = ServeConfig(max_seq_len=32, max_batch=2, temperature=0.0,
+                     decode_chunk=4)
+    sc_p = dataclasses.replace(sc, paged=True, page_size=8,
+                               chunked_prefill=True, prefill_chunk=8)
+    _, ref = _run(cfg, params, None, sc, prompts, max_new=5)
+    monkeypatch.setattr(repro.kernels, "use_kernels", lambda: True)
+    for conf in (sc, sc_p):
+        _, got = _run(cfg, params, None, conf, prompts, max_new=5)
+        for r, g in zip(ref, got):
+            assert r.out_tokens == g.out_tokens, (conf.paged, r.rid)
 
 
 def test_paged_engine_oversubscribed_pool_reuses_freed_pages():

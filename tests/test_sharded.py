@@ -134,6 +134,7 @@ def test_sharded_subprocess():
     env["PYTHONPATH"] = os.path.join(os.path.dirname(__file__), "..",
                                      "src")
     env.pop("REPRO_ENGINE", None)      # configs above are pinned
+    env["JAX_PLATFORMS"] = "cpu"       # forced host devices, never a chip
     r = subprocess.run([sys.executable, "-c", SCRIPT], env=env,
                        capture_output=True, text=True, timeout=900)
     assert r.returncode == 0, r.stdout[-2000:] + r.stderr[-4000:]
