@@ -1,0 +1,45 @@
+"""``bench/run.py`` prints no result and exits non-zero off the chip,
+and where the program is missing."""
+import json
+import os
+import shutil
+import subprocess
+import sys
+from pathlib import Path
+
+ROOT = Path(__file__).resolve().parents[2]
+
+
+def _run(cwd: Path):
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    env.pop("PYTHONPATH", None)
+    return subprocess.run(
+        [sys.executable, "bench/run.py", "--workload", "phi3v-azconv",
+         "--seed", "1", "--seconds", "1", "--trace", "0"],
+        cwd=cwd, env=env, capture_output=True, text=True, timeout=300)
+
+
+def _no_result(out: str) -> bool:
+    for line in out.splitlines():
+        try:
+            if "metrics" in json.loads(line):
+                return False
+        except ValueError:
+            pass
+    return True
+
+
+def test_refuses_a_cpu_backend():
+    p = _run(ROOT)
+    assert p.returncode != 0
+    assert "needs a TPU" in p.stderr
+    assert _no_result(p.stdout)
+
+
+def test_fails_with_only_the_benchmark(tmp_path):
+    shutil.copy(ROOT / "BENCHMARK.json", tmp_path)
+    shutil.copytree(ROOT / "bench", tmp_path / "bench",
+                    ignore=shutil.ignore_patterns("__pycache__"))
+    p = _run(tmp_path)
+    assert p.returncode != 0
+    assert _no_result(p.stdout)
