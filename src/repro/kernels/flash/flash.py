@@ -117,4 +117,5 @@ def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
             pltpu.VMEM((bq, dv), jnp.float32),    # output accumulator
         ],
         interpret=interpret,
+        name="flash_attention",
     )(q, k, v)

@@ -174,5 +174,6 @@ def kq_decode_attention(qc, kc, vc, lengths, *, block_t: int = 256,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, Hkv, m, Rv), qc.dtype),
         interpret=interpret,
+        name="kq_decode_attention",
     )(lengths, qg, kc, vc)
     return out.reshape(B, H, Rv)

@@ -284,6 +284,7 @@ def _kq_decode_paged_split(qg, kc_pool, vc_pool, lengths, block_table, *,
             jax.ShapeDtypeStruct((B, Hkv, n_splits, m, Rv), jnp.float32),
         ],
         interpret=interpret,
+        name="kq_decode_paged_split",
     )(lengths, block_table, *inputs)
     out = combine_split_partials(o_parts, lse_parts[..., 0])
     return out.astype(qg.dtype)
@@ -429,6 +430,7 @@ def kq_prefill_paged_attention(qc, kc_pool, vc_pool, lengths, pos0,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, Hkv, m * S, Rv), qc.dtype),
         interpret=interpret,
+        name="kq_prefill_paged_attention",
     )(lengths, pos0, block_table, qg, kc_pool, vc_pool)
     return out.reshape(B, H, S, Rv)
 
@@ -559,5 +561,6 @@ def kq_decode_paged_attention(qc, kc_pool, vc_pool, lengths, block_table,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, Hkv, m, Rv), qc.dtype),
         interpret=interpret,
+        name="kq_decode_paged_attention",
     )(lengths, block_table, *inputs)
     return out.reshape(B, H, Rv)

@@ -95,4 +95,5 @@ def ssd_chunk_scan(x, a, dt, B, C, *, chunk: int = 128,
         out_shape=jax.ShapeDtypeStruct((Bsz, nh, S, hd), x.dtype),
         scratch_shapes=[pltpu.VMEM((n, hd), jnp.float32)],
         interpret=interpret,
+        name="ssd_chunk_scan",
     )(x, a, dt, B, C)

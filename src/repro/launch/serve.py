@@ -64,6 +64,22 @@ def synthetic_requests(vocab_size: int, lens: Sequence[int],
     return reqs
 
 
+def latency_line(reqs: Sequence[Request]) -> str:
+    """Queue wait (start to admission) and admission to first token,
+    p50/p95 in ms over the requests that reached each stamp."""
+    def pct(xs):
+        if not xs:
+            return "n/a"
+        p50, p95 = np.percentile(1e3 * np.asarray(xs), [50, 95])
+        return f"{p50:.1f}/{p95:.1f} ms"
+    waits = [r.t_admitted - r.t_submitted for r in reqs
+             if r.t_admitted is not None]
+    firsts = [r.t_first_token - r.t_admitted for r in reqs
+              if r.t_first_token is not None and r.t_admitted is not None]
+    return (f"queue wait p50/p95: {pct(waits)}; admission to first "
+            f"token p50/p95: {pct(firsts)}")
+
+
 def main() -> None:
     """CLI entry: calibrate + compress a (reduced) arch, then drain a
     synthetic request batch through the serving engine, printing the
@@ -288,6 +304,7 @@ def main() -> None:
         print(f"req {r.rid} (prompt {len(r.prompt):3d}): "
               f"{r.out_tokens}{note}")
     print(f"capacity gain vs full cache: {eng.capacity_gain():.2f}x")
+    print(latency_line(reqs))
     if eng.n_failed:
         kinds = ", ".join(f"{k}={n}" for k, n in
                           eng.error_counts.items() if n)

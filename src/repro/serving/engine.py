@@ -122,11 +122,14 @@ sequences (``capacity_gain``) — the serving-level payoff of the paper.
 from __future__ import annotations
 
 import dataclasses
+import functools
+import time
 from typing import Any, Dict, List, Optional
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 from jax.sharding import PartitionSpec as P
 
 from repro.config import ModelConfig, ServeConfig
@@ -216,6 +219,12 @@ class Request:
     done: bool = False
     truncated: bool = False            # hit max_seq_len before max_new_tokens
     error: Optional[RequestError] = None   # structured terminal failure
+    # host-clock stamps (time.perf_counter()): handed to start(), first
+    # admitted to a slot, first token on the host — queue wait is
+    # t_admitted - t_submitted
+    t_submitted: Optional[float] = None
+    t_admitted: Optional[float] = None
+    t_first_token: Optional[float] = None
 
     @property
     def failed(self) -> bool:
@@ -232,6 +241,32 @@ def sample_token(logits: jnp.ndarray, temperature: float, rng) -> jnp.ndarray:
     if temperature <= 0.0:
         return jnp.argmax(logits, axis=-1)
     return jax.random.categorical(rng, logits / temperature, axis=-1)
+
+
+# -- instrumentation ---------------------------------------------------------
+# Host spans (``TraceAnnotation``) name the engine's host work in a
+# profiler trace, on the device trace's clock; without a profiler attached
+# each costs about a microsecond.  docs/SERVING.md §Tracing lists them.
+
+
+def _to_host(x, what: str, copy: bool = False) -> np.ndarray:
+    """``np.asarray(x)`` (``np.array`` with ``copy``).  Every blocking
+    device-to-host read of the step path goes through here, under an
+    ``engine.fetch`` span whose ``what`` names the value read; a host
+    array passes through without a span."""
+    read = np.array if copy else np.asarray
+    if not isinstance(x, jax.Array):
+        return read(x)
+    with TraceAnnotation("engine.fetch", what=what):
+        return read(x)
+
+
+def _stamp_submitted(requests: List[Request]) -> None:
+    """``start`` has made room for ``requests``: submitted now, not yet
+    admitted."""
+    now = time.perf_counter()
+    for r in requests:
+        r.t_submitted, r.t_admitted, r.t_first_token = now, None, None
 
 
 class ServingEngine:
@@ -387,7 +422,7 @@ class ServingEngine:
         scan can touch."""
         if not self.sc.paged or not self._dynamic_splits:
             return self._decode_splits
-        pos_np = np.asarray(self._pos)
+        pos_np = _to_host(self._pos, "pos")
         live_max = int(pos_np[live].max()) if live.any() else 1
         return self._splits_for_step(live_max + self.sc.decode_chunk)
 
@@ -418,10 +453,11 @@ class ServingEngine:
         kw: Dict[str, Any] = {"block_table": btab_row}
         if self.proj is not None:
             kw["proj"] = proj
-        logits, cache = self.model.prefill_chunk(params, cache, tokens,
-                                                 pos0, valid, **kw)
-        last = jnp.take_along_axis(
-            logits, (n_valid - 1)[:, None, None], axis=1)[:, 0]
+        with jax.named_scope("prefill_chunk"):
+            logits, cache = self.model.prefill_chunk(params, cache, tokens,
+                                                     pos0, valid, **kw)
+            last = jnp.take_along_axis(
+                logits, (n_valid - 1)[:, None, None], axis=1)[:, 0]
         return last, cache
 
     def _insert_impl(self, cache, slot_cache, slot):
@@ -565,8 +601,9 @@ class ServingEngine:
                     (out_tok, emit))
 
         carry = (logits, cache, pos, emitted, done, trunc, rng)
-        carry, (toks, emits) = jax.lax.scan(
-            _body, carry, None, length=self.sc.decode_chunk)
+        with jax.named_scope("decode_chunk"):
+            carry, (toks, emits) = jax.lax.scan(
+                _body, carry, None, length=self.sc.decode_chunk)
         return carry, toks, emits
 
     def _fused_step_impl(self, params, proj, cache, pf_tokens, pf_pos0,
@@ -586,11 +623,12 @@ class ServingEngine:
         ``len(buckets)`` for this path.  Returns
         ``(chunk last-valid logits, decode carry, tokens, emit mask)``.
         """
-        last, cache = self._prefill_chunk_impl(
-            params, proj, cache, pf_tokens, pf_pos0, pf_n_valid, pf_row)
-        carry, toks, emits = self._decode_chunk_impl(
-            params, proj, cache, logits, pos, emitted, max_new, done,
-            trunc, rng, block_table, num_splits)
+        with jax.named_scope("fused_step"):
+            last, cache = self._prefill_chunk_impl(
+                params, proj, cache, pf_tokens, pf_pos0, pf_n_valid, pf_row)
+            carry, toks, emits = self._decode_chunk_impl(
+                params, proj, cache, logits, pos, emitted, max_new, done,
+                trunc, rng, block_table, num_splits)
         return last, carry, toks, emits
 
     # -- capacity accounting --------------------------------------------------
@@ -727,6 +765,7 @@ class ServingEngine:
         # a slot armed between the live-mask snapshot and the decode
         # scan would decode against a garbage block-table row)
         self._activation_queue: Optional[List[tuple]] = None
+        _stamp_submitted(requests)
         self._started = True
 
     def _busy(self) -> bool:
@@ -823,7 +862,8 @@ class ServingEngine:
         chunk's sampled tokens are discarded for those slots — they
         were drawn from garbage — and their pages go back to the pool
         (never indexed: only a finished harvest leaves index pins)."""
-        finite = np.asarray(jnp.all(jnp.isfinite(self._logits), axis=-1))
+        finite = _to_host(jnp.all(jnp.isfinite(self._logits), axis=-1),
+                          "finite")
         for b in np.nonzero(live & ~finite)[0]:
             r = self._slot_req[int(b)]
             emits_np[:, b] = False      # drop this chunk's tokens
@@ -969,7 +1009,8 @@ class ServingEngine:
         activation lands after the scan and the slot joins decode next
         step, where it is charged like any other decoding slot."""
         if self._activation_queue is not None:
-            self._activation_queue.append((b, r, np.asarray(last_logits)))
+            self._activation_queue.append(
+                (b, r, _to_host(last_logits, "logits")))
             return
         self._logits = self._logits.at[b].set(last_logits)
         self._pos = self._pos.at[b].set(len(self._slot_prompt[b]))
@@ -983,7 +1024,7 @@ class ServingEngine:
             # terminal next-token logits: attached to the prompt's
             # index entry at release, so an exact-duplicate prompt can
             # later skip prefill entirely
-            self._prompt_logits[b] = np.asarray(last_logits)
+            self._prompt_logits[b] = _to_host(last_logits, "logits")
 
     def _index_terminal(self, b: int) -> None:
         """Leave a finished slot's prompt tail in the prefix index
@@ -1123,6 +1164,7 @@ class ServingEngine:
             return self._pending.pop(i)
         return None
 
+    @functools.partial(jax.profiler.annotate_function, name="engine.admit")
     def _admit(self, limit: Optional[int] = None) -> int:
         """Fill free slots from the pending queue; returns how many
         requests were admitted.  ``limit`` caps the count (the budget
@@ -1242,6 +1284,8 @@ class ServingEngine:
                     # to the normal prefill path below — greedy
                     # outputs are unchanged, only latency is paid
                     self.n_swap_fallbacks += 1
+            if r.t_admitted is None:
+                r.t_admitted = time.perf_counter()
             if sc.chunked_prefill:
                 if slog is not None:
                     # whole prompt served from the index, next-token
@@ -1278,41 +1322,43 @@ class ServingEngine:
         sc = self.sc
         if self._prefilled[b] is None:
             return None
-        if self._late_match(b):
-            return None                      # whole prompt mapped in
-        if self._fires("prefill_delay"):
-            return None  # injected slow prefill: chunk runs later
-        r = self._slot_req[b]
-        prompt = self._slot_prompt[b]
-        start = self._prefilled[b]
-        n = min(sc.prefill_chunk, len(prompt) - start)
-        if cap is not None and n > cap:
-            n = cap                          # residual-budget truncation
-            self.n_truncated_chunks += 1
-        try:
-            # a chunk starting inside a shared page (the first
-            # unshared token of a partially-matched prefix) must
-            # fork it before writing (DESIGN.md §prefix-sharing)
-            for j in self._fork_candidates(b, start, start + n):
-                self._cow_fork(b, j)
-        except PagePoolExhausted:
-            # optimistic admission may find the pool dry at fork
-            # time (another slot's growth won the race): preempt
-            # this slot; it requeues and retries when pages free
-            self._preempt(b)
-            return None
-        try:
-            bucket = sc.bucket_for(n)
-        except ValueError as e:
-            # a chunk no bucket holds can never prefill: structured
-            # per-request failure, not an engine abort (the scheduler
-            # sizes chunks within (0, prefill_chunk], so this is
-            # defense in depth against config/bucket drift)
-            self._fail_request(r, "oversize", str(e))
-            return None
-        toks = np.zeros((1, bucket), np.int32)
-        toks[0, :n] = prompt[start: start + n]
-        return b, r, start, n, bucket, toks
+        with TraceAnnotation("engine.prefill", rid=self._slot_req[b].rid,
+                             start=self._prefilled[b]):
+            if self._late_match(b):
+                return None                      # whole prompt mapped in
+            if self._fires("prefill_delay"):
+                return None  # injected slow prefill: chunk runs later
+            r = self._slot_req[b]
+            prompt = self._slot_prompt[b]
+            start = self._prefilled[b]
+            n = min(sc.prefill_chunk, len(prompt) - start)
+            if cap is not None and n > cap:
+                n = cap                          # residual-budget truncation
+                self.n_truncated_chunks += 1
+            try:
+                # a chunk starting inside a shared page (the first
+                # unshared token of a partially-matched prefix) must
+                # fork it before writing (DESIGN.md §prefix-sharing)
+                for j in self._fork_candidates(b, start, start + n):
+                    self._cow_fork(b, j)
+            except PagePoolExhausted:
+                # optimistic admission may find the pool dry at fork
+                # time (another slot's growth won the race): preempt
+                # this slot; it requeues and retries when pages free
+                self._preempt(b)
+                return None
+            try:
+                bucket = sc.bucket_for(n)
+            except ValueError as e:
+                # a chunk no bucket holds can never prefill: structured
+                # per-request failure, not an engine abort (the scheduler
+                # sizes chunks within (0, prefill_chunk], so this is
+                # defense in depth against config/bucket drift)
+                self._fail_request(r, "oversize", str(e))
+                return None
+            toks = np.zeros((1, bucket), np.int32)
+            toks[0, :n] = prompt[start: start + n]
+            return b, r, start, n, bucket, toks
 
     def _finish_chunk(self, b: int, r: Request, start: int, n: int,
                       bucket: int, last) -> None:
@@ -1348,12 +1394,13 @@ class ServingEngine:
     def _dispatch_chunk(self, prep) -> None:
         """Run one staged chunk as its own device call."""
         b, r, start, n, bucket, toks = prep
-        last, self._cache = self._prefill_chunk(
-            self.params, self.proj, self._cache, jnp.asarray(toks),
-            jnp.asarray([start], jnp.int32),
-            jnp.asarray([n], jnp.int32),
-            jnp.asarray(self._btabs.rows[b: b + 1]))
-        self._finish_chunk(b, r, start, n, bucket, last)
+        with TraceAnnotation("engine.prefill", rid=r.rid, start=start, n=n):
+            last, self._cache = self._prefill_chunk(
+                self.params, self.proj, self._cache, jnp.asarray(toks),
+                jnp.asarray([start], jnp.int32),
+                jnp.asarray([n], jnp.int32),
+                jnp.asarray(self._btabs.rows[b: b + 1]))
+            self._finish_chunk(b, r, start, n, bucket, last)
 
     def _prefill_step(self, budget: Optional[int] = None) -> int:
         """Advance in-flight chunked prefills by up to ``budget``
@@ -1411,12 +1458,13 @@ class ServingEngine:
         """Copy slot ``b``'s first ``n_tokens`` cache entries of every
         layer to host RAM (before its pages are released)."""
         row = self._btabs.rows[b].copy()
+        fetch = functools.partial(_to_host, what="swap")
 
         def _out0(pool):                     # prefix leaves: (P, ...)
-            return swap_out(pool, row, n_tokens)
+            return swap_out(pool, row, n_tokens, fetch=fetch)
 
         def _out1(pools):                    # scanned steps: (n_steps, P, ...)
-            return np.stack([swap_out(pools[i], row, n_tokens)
+            return np.stack([swap_out(pools[i], row, n_tokens, fetch=fetch)
                              for i in range(pools.shape[0])])
 
         bufs = {"prefix": jax.tree.map(_out0, self._cache["prefix"])}
@@ -1462,7 +1510,7 @@ class ServingEngine:
         r = self._slot_req[b]
         mid_prefill = self._prefilled[b] is not None
         if self.sc.preempt_mode == "swap" and not mid_prefill:
-            pos = int(np.asarray(self._pos)[b])  # == len(effective prompt)
+            pos = int(_to_host(self._pos, "pos")[b])  # len(prompt)
             try:
                 if self._fires("swap_out"):
                     raise SwapFailed("injected swap_out fault")
@@ -1474,7 +1522,7 @@ class ServingEngine:
                 if self._fires("swap_corrupt"):
                     bufs = self._corrupt_swap(bufs)
                 self._swapped[id(r)] = {
-                    "logits": np.asarray(self._logits[b]),
+                    "logits": _to_host(self._logits[b], "logits"),
                     "bufs": bufs,
                     "crc": crc,
                 }
@@ -1523,6 +1571,7 @@ class ServingEngine:
             self._preempt(b)
             live[b] = False
 
+    @functools.partial(jax.profiler.annotate_function, name="engine.headroom")
     def _ensure_chunk_headroom(self, live: np.ndarray) -> None:
         """Grow live sequences page-by-page: every decoding slot gets
         pages covering the next ``decode_chunk`` tokens before the
@@ -1537,7 +1586,7 @@ class ServingEngine:
         were allocated at admission and they grow only once they join
         decode."""
         sc = self.sc
-        pos_np = np.asarray(self._pos)
+        pos_np = _to_host(self._pos, "pos")
         needs: Dict[int, int] = {}
         grow: Dict[int, int] = {}
         forks: Dict[int, List[int]] = {}
@@ -1609,20 +1658,22 @@ class ServingEngine:
         forever."""
         assert self._started, "call start(requests) first"
         self._step_count += 1
-        self._progress = False
-        self._check_deadlines()
-        busy = self._step_inner()
-        if self.sc.audit and self._step_count % self.sc.audit_every == 0:
-            invariants.audit(self)
-            self.n_audits += 1
-        if busy and not self._progress:
-            self._no_progress += 1
-            if (self.sc.stall_steps
-                    and self._no_progress >= self.sc.stall_steps):
-                raise EngineStalledError(
-                    self._no_progress, invariants.scheduler_dump(self))
-        else:
-            self._no_progress = 0
+        with TraceAnnotation("engine.step", step=self._step_count):
+            self._progress = False
+            self._check_deadlines()
+            busy = self._step_inner()
+            if (self.sc.audit
+                    and self._step_count % self.sc.audit_every == 0):
+                invariants.audit(self)
+                self.n_audits += 1
+            if busy and not self._progress:
+                self._no_progress += 1
+                if (self.sc.stall_steps
+                        and self._no_progress >= self.sc.stall_steps):
+                    raise EngineStalledError(
+                        self._no_progress, invariants.scheduler_dump(self))
+            else:
+                self._no_progress = 0
         return busy
 
     def _step_inner(self) -> bool:
@@ -1657,13 +1708,16 @@ class ServingEngine:
             btab_dev = self._btabs.device(live=live)
             self.peak_used_pages = max(self.peak_used_pages,
                                        self.pool.used_count)
-        carry, toks, emits = self._decode_chunk(
-            self.params, self.proj, self._cache, self._logits, self._pos,
-            self._emitted, self._max_new, self._done, self._trunc,
-            self.rng, btab_dev, num_splits=self._live_splits(live))
+        num_splits = self._live_splits(live)
+        with TraceAnnotation("engine.dispatch", live=int(live.sum())):
+            carry, toks, emits = self._decode_chunk(
+                self.params, self.proj, self._cache, self._logits,
+                self._pos, self._emitted, self._max_new, self._done,
+                self._trunc, self.rng, btab_dev, num_splits=num_splits)
         (self._logits, self._cache, self._pos, self._emitted, self._done,
          self._trunc, self.rng) = carry
-        freed = self._harvest(live, toks, emits)
+        with TraceAnnotation("engine.harvest"):
+            freed = self._harvest(live, toks, emits)
         if freed and self._pending:
             # refill the freed slots now: the next request prefills in
             # this very step instead of sitting idle for one chunk
@@ -1679,9 +1733,9 @@ class ServingEngine:
         and release slots whose request finished.  Returns whether any
         slot was freed (the same-step refill trigger)."""
         sc = self.sc
-        toks_np = np.asarray(toks)            # (N, B)
-        emits_np = np.array(emits)            # writable: quarantine
-                                              # masks poisoned slots
+        toks_np = _to_host(toks, "toks")      # (N, B)
+        # writable: quarantine masks poisoned slots
+        emits_np = _to_host(emits, "emits", copy=True)
         if self._fires("nan_logits"):
             # kernel numerics fault: poison the lowest live slot's
             # next-token logits (the guard below quarantines it)
@@ -1691,8 +1745,9 @@ class ServingEngine:
             self._quarantine_nonfinite(live, emits_np)
         if emits_np[:, live].any():
             self._progress = True
-        done_np = np.asarray(self._done)
-        trunc_np = np.asarray(self._trunc)
+        done_np = _to_host(self._done, "done")
+        trunc_np = _to_host(self._trunc, "trunc")
+        now = time.perf_counter()
         freed = False
         for b in range(sc.max_batch):
             if not live[b]:
@@ -1701,6 +1756,8 @@ class ServingEngine:
             r.out_tokens.extend(
                 int(toks_np[t, b]) for t in range(sc.decode_chunk)
                 if emits_np[t, b])
+            if r.t_first_token is None and r.out_tokens:
+                r.t_first_token = now
             if done_np[b]:
                 r.done = True
                 r.truncated = bool(trunc_np[b])
@@ -1780,32 +1837,39 @@ class ServingEngine:
             self.peak_used_pages = max(self.peak_used_pages,
                                        self.pool.used_count)
             num_splits = self._live_splits(live)
+            dispatch = TraceAnnotation("engine.dispatch",
+                                       live=int(live.sum()))
             if fused is not None:
                 fb, fr, fstart, fn, fbucket, ftoks = fused
-                last, carry, toks, emits = self._fused_step(
-                    self.params, self.proj, self._cache,
-                    jnp.asarray(ftoks),
-                    jnp.asarray([fstart], jnp.int32),
-                    jnp.asarray([fn], jnp.int32),
-                    jnp.asarray(self._btabs.rows[fb: fb + 1]),
-                    self._logits, self._pos, self._emitted,
-                    self._max_new, self._done, self._trunc, self.rng,
-                    btab_dev, num_splits=num_splits)
+                with dispatch:
+                    last, carry, toks, emits = self._fused_step(
+                        self.params, self.proj, self._cache,
+                        jnp.asarray(ftoks),
+                        jnp.asarray([fstart], jnp.int32),
+                        jnp.asarray([fn], jnp.int32),
+                        jnp.asarray(self._btabs.rows[fb: fb + 1]),
+                        self._logits, self._pos, self._emitted,
+                        self._max_new, self._done, self._trunc, self.rng,
+                        btab_dev, num_splits=num_splits)
                 (self._logits, self._cache, self._pos, self._emitted,
                  self._done, self._trunc, self.rng) = carry
                 # after the carry unpack: activation must overwrite
                 # the stale decode logits for the finishing slot
-                self._finish_chunk(fb, fr, fstart, fn, fbucket, last)
+                with TraceAnnotation("engine.prefill", rid=fr.rid,
+                                     start=fstart, n=fn):
+                    self._finish_chunk(fb, fr, fstart, fn, fbucket, last)
                 self.n_fused_steps += 1
             else:
-                carry, toks, emits = self._decode_chunk(
-                    self.params, self.proj, self._cache, self._logits,
-                    self._pos, self._emitted, self._max_new,
-                    self._done, self._trunc, self.rng, btab_dev,
-                    num_splits=num_splits)
+                with dispatch:
+                    carry, toks, emits = self._decode_chunk(
+                        self.params, self.proj, self._cache, self._logits,
+                        self._pos, self._emitted, self._max_new,
+                        self._done, self._trunc, self.rng, btab_dev,
+                        num_splits=num_splits)
                 (self._logits, self._cache, self._pos, self._emitted,
                  self._done, self._trunc, self.rng) = carry
-            freed = self._harvest(live, toks, emits)
+            with TraceAnnotation("engine.harvest"):
+                freed = self._harvest(live, toks, emits)
         # flush deferred activations: the armed slots join decode next
         # step (and are charged there); a slot unwound since queueing
         # (failed / preempted mid-step) is skipped
@@ -2177,11 +2241,12 @@ class ShardedServingEngine(ServingEngine):
             return self._prefill_chunk_impl(params, proj, cache, tokens,
                                             pos0, n_valid, rows)
 
-        return jax.shard_map(
-            _body, mesh=self._mesh,
-            in_specs=(self._cache_spec(), d, d, d, d),
-            out_specs=(d, self._cache_spec()),
-            check_vma=False)(cache, tokens, pos0, n_valid, rows)
+        with jax.named_scope("sharded_prefill"):
+            return jax.shard_map(
+                _body, mesh=self._mesh,
+                in_specs=(self._cache_spec(), d, d, d, d),
+                out_specs=(d, self._cache_spec()),
+                check_vma=False)(cache, tokens, pos0, n_valid, rows)
 
     def _sharded_decode_impl(self, params, proj, cache, logits, pos,
                              emitted, max_new, done, trunc, rngs,
@@ -2205,13 +2270,14 @@ class ShardedServingEngine(ServingEngine):
             return (logits, cache, pos, emitted, done, trunc, rng[None],
                     toks, emits)
 
-        return jax.shard_map(
-            _body, mesh=self._mesh,
-            in_specs=(cspec, d, d, d, d, d, d, d, d),
-            out_specs=(d, cspec, d, d, d, d, d, P(None, "data"),
-                       P(None, "data")),
-            check_vma=False)(cache, logits, pos, emitted, max_new, done,
-                             trunc, rngs, block_table)
+        with jax.named_scope("sharded_decode"):
+            return jax.shard_map(
+                _body, mesh=self._mesh,
+                in_specs=(cspec, d, d, d, d, d, d, d, d),
+                out_specs=(d, cspec, d, d, d, d, d, P(None, "data"),
+                           P(None, "data")),
+                check_vma=False)(cache, logits, pos, emitted, max_new,
+                                 done, trunc, rngs, block_table)
 
     # -- lifecycle -----------------------------------------------------------
 
@@ -2276,6 +2342,7 @@ class ShardedServingEngine(ServingEngine):
         self._step_count = 0
         self._no_progress = 0
         self.n_audits = 0
+        _stamp_submitted(requests)
         self._started = True
 
     def _busy(self) -> bool:
@@ -2364,18 +2431,20 @@ class ShardedServingEngine(ServingEngine):
                 pos0[s] = start
                 nval[s] = n
                 rows[s] = self.workers[s]._btabs.rows[b]
-            last, self._g_cache = self._sharded_prefill(
-                self.params, self.proj, self._g_cache,
-                jnp.asarray(toks), jnp.asarray(pos0), jnp.asarray(nval),
-                jnp.asarray(rows))
-            last_np = np.asarray(last)
-            self.prefill_chunk_shapes.add(bucket)
-            for s, p in enumerate(preps):
-                if p is None:
-                    continue
-                b, req, start, n, _, _ = p
-                self.workers[s]._finish_chunk(b, req, start, n, bucket,
-                                              last_np[s: s + 1])
+            with TraceAnnotation("engine.prefill", round=rnd,
+                                 n=int(nval.sum())):
+                last, self._g_cache = self._sharded_prefill(
+                    self.params, self.proj, self._g_cache,
+                    jnp.asarray(toks), jnp.asarray(pos0),
+                    jnp.asarray(nval), jnp.asarray(rows))
+                last_np = _to_host(last, "logits")
+                self.prefill_chunk_shapes.add(bucket)
+                for s, p in enumerate(preps):
+                    if p is None:
+                        continue
+                    b, req, start, n, _, _ = p
+                    self.workers[s]._finish_chunk(b, req, start, n, bucket,
+                                                  last_np[s: s + 1])
 
     def _dispatch_decode(self, lives) -> bool:
         """One sharded decode scan over every shard's live slots, then
@@ -2387,29 +2456,31 @@ class ShardedServingEngine(ServingEngine):
         rows = np.concatenate(
             [w._btabs.host(live=live)
              for w, live in zip(self.workers, lives)])
+        g_live = np.concatenate(lives)
         if self._dynamic_splits:
-            g_live = np.concatenate(lives)
-            pos_np = np.asarray(self._g_pos)
+            pos_np = _to_host(self._g_pos, "pos")
             live_max = int(pos_np[g_live].max()) if g_live.any() else 1
             num_splits = self._splits_for_step(live_max + sc.decode_chunk)
         else:
             num_splits = self._decode_splits
-        out = self._sharded_decode(
-            self.params, self.proj, self._g_cache, self._g_logits,
-            self._g_pos, self._g_emitted, self._g_max_new, self._g_done,
-            self._g_trunc, self._g_rng, jnp.asarray(rows),
-            num_splits=num_splits)
+        with TraceAnnotation("engine.dispatch", live=int(g_live.sum())):
+            out = self._sharded_decode(
+                self.params, self.proj, self._g_cache, self._g_logits,
+                self._g_pos, self._g_emitted, self._g_max_new,
+                self._g_done, self._g_trunc, self._g_rng,
+                jnp.asarray(rows), num_splits=num_splits)
         (self._g_logits, self._g_cache, self._g_pos, self._g_emitted,
          self._g_done, self._g_trunc, self._g_rng, toks, emits) = out
-        toks_np = np.asarray(toks)
-        emits_np = np.asarray(emits)
-        freed = False
-        for w, live in zip(self.workers, lives):
-            if not live.any():
-                continue
-            lo, hi = w._base, w._base + w.sc.max_batch
-            freed |= w._harvest(live, toks_np[:, lo:hi],
-                                emits_np[:, lo:hi])
+        with TraceAnnotation("engine.harvest"):
+            toks_np = _to_host(toks, "toks")
+            emits_np = _to_host(emits, "emits")
+            freed = False
+            for w, live in zip(self.workers, lives):
+                if not live.any():
+                    continue
+                lo, hi = w._base, w._base + w.sc.max_batch
+                freed |= w._harvest(live, toks_np[:, lo:hi],
+                                    emits_np[:, lo:hi])
         return freed
 
     def step(self) -> bool:
@@ -2424,53 +2495,54 @@ class ShardedServingEngine(ServingEngine):
         assert self._started, "call start(requests) first"
         sc = self.sc
         self._step_count += 1
-        self._progress_global = False
-        for w in self.workers:
-            # workers share the parent's scheduler clock so retry
-            # backoff, deadlines and chaos schedules line up with the
-            # global step count
-            w._step_count = self._step_count
-            w._progress = False
-            w._check_deadlines()
-        self._check_global_deadlines()
-        self._route()
-        for w in self.workers:
-            w._admit()
-            w.peak_used_pages = max(w.peak_used_pages, w.pool.used_count)
-        self._run_prefill_rounds()
-        lives = [np.array([w._slot_req[b] is not None
-                           and w._prefilled[b] is None
-                           for b in range(w.sc.max_batch)])
-                 for w in self.workers]
-        for w, live in zip(self.workers, lives):
-            if live.any():
-                w._ensure_chunk_headroom(live)
-                w.peak_used_pages = max(w.peak_used_pages,
-                                        w.pool.used_count)
-        if any(live.any() for live in lives):
-            if self._dispatch_decode(lives):
-                # refill freed slots in the same step (the base
-                # engine's refill-bubble fix, routed globally)
-                self._route()
-                for w in self.workers:
-                    w._admit()
-        busy = self._busy()
-        if sc.audit and self._step_count % sc.audit_every == 0:
+        with TraceAnnotation("engine.step", step=self._step_count):
+            self._progress_global = False
             for w in self.workers:
-                invariants.audit(w)
-            invariants.audit_sharded(self)
-            self.n_audits += 1
-        progress = (self._progress_global
-                    or any(w._progress for w in self.workers))
-        if busy and not progress:
-            self._no_progress += 1
-            if (sc.stall_steps
-                    and self._no_progress >= sc.stall_steps):
-                raise EngineStalledError(
-                    self._no_progress,
-                    "\n".join(f"[shard {s}] "
-                              + invariants.scheduler_dump(w)
-                              for s, w in enumerate(self.workers)))
-        else:
-            self._no_progress = 0
+                # workers share the parent's scheduler clock so retry
+                # backoff, deadlines and chaos schedules line up with the
+                # global step count
+                w._step_count = self._step_count
+                w._progress = False
+                w._check_deadlines()
+            self._check_global_deadlines()
+            self._route()
+            for w in self.workers:
+                w._admit()
+                w.peak_used_pages = max(w.peak_used_pages, w.pool.used_count)
+            self._run_prefill_rounds()
+            lives = [np.array([w._slot_req[b] is not None
+                               and w._prefilled[b] is None
+                               for b in range(w.sc.max_batch)])
+                     for w in self.workers]
+            for w, live in zip(self.workers, lives):
+                if live.any():
+                    w._ensure_chunk_headroom(live)
+                    w.peak_used_pages = max(w.peak_used_pages,
+                                            w.pool.used_count)
+            if any(live.any() for live in lives):
+                if self._dispatch_decode(lives):
+                    # refill freed slots in the same step (the base
+                    # engine's refill-bubble fix, routed globally)
+                    self._route()
+                    for w in self.workers:
+                        w._admit()
+            busy = self._busy()
+            if sc.audit and self._step_count % sc.audit_every == 0:
+                for w in self.workers:
+                    invariants.audit(w)
+                invariants.audit_sharded(self)
+                self.n_audits += 1
+            progress = (self._progress_global
+                        or any(w._progress for w in self.workers))
+            if busy and not progress:
+                self._no_progress += 1
+                if (sc.stall_steps
+                        and self._no_progress >= sc.stall_steps):
+                    raise EngineStalledError(
+                        self._no_progress,
+                        "\n".join(f"[shard {s}] "
+                                  + invariants.scheduler_dump(w)
+                                  for s, w in enumerate(self.workers)))
+            else:
+                self._no_progress = 0
         return busy

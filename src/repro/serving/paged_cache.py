@@ -452,7 +452,8 @@ def copy_page(pool: jnp.ndarray, src, dst) -> jnp.ndarray:
     return pool.at[dst].set(pool[src])
 
 
-def swap_out(pool: jnp.ndarray, row, n_tokens: int) -> np.ndarray:
+def swap_out(pool: jnp.ndarray, row, n_tokens: int,
+             fetch=np.asarray) -> np.ndarray:
     """Swap one slot's cache entries out to a host-RAM buffer.
 
     pool: (P, Hkv, ps, R); row: (n_pages,) block-table row of the
@@ -461,12 +462,13 @@ def swap_out(pool: jnp.ndarray, row, n_tokens: int) -> np.ndarray:
     copies its first ``n_tokens`` entries to host memory ->
     (Hkv, n_tokens, R) numpy, so the transfer is ~``n_tokens`` wide,
     not ``max_seq_len``.  The victim's pages can then be freed;
-    ``swap_in`` restores the bytes through a fresh row.
+    ``swap_in`` restores the bytes through a fresh row.  ``fetch`` does
+    the device-to-host read (the engine passes its spanned reader).
     """
     ps = pool.shape[2]
     occupied = pages_needed(n_tokens, ps)
     seq = gather_pages(pool, jnp.asarray(row[:occupied], jnp.int32)[None])
-    return np.asarray(seq[0])[:, :n_tokens]
+    return fetch(seq[0])[:, :n_tokens]
 
 
 def swap_in(pool: jnp.ndarray, row, vals: np.ndarray) -> jnp.ndarray:

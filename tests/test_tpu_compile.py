@@ -96,3 +96,101 @@ def test_dense_decode_compiles_for_v5e(one_chip):
                            interpret=False, max_len=T)
     _compile(fn, one_chip, ((B, H, R), _BF), ((B, HKV, T, R), _BF),
              ((B, HKV, T, R), _BF), ((B,), _I32))
+
+
+def _named(name):
+    """The compiled text's HLO instruction for the Pallas call ``name``
+    (``pl.pallas_call(name=...)`` names the custom call after it)."""
+    return f"%{name}."
+
+
+def _flash(q, k, v):
+    from repro.kernels.flash.flash import flash_attention
+    return flash_attention(q, k, v, interpret=False)
+
+
+
+_PREFILL = [((1, H, S, R), _BF), *_DECODE[1:3], ((1,), _I32),
+            ((1,), _I32), ((1, NPP), _I32)]
+_KERNELS = {
+    "kq_decode_paged_attention": (functools.partial(
+        kq_decode_paged_attention, scale=SCALE, interpret=False,
+        max_len=T, num_splits=1), _DECODE),
+    "kq_decode_paged_split": (functools.partial(
+        kq_decode_paged_attention, scale=SCALE, interpret=False,
+        max_len=T, num_splits=4), _DECODE),
+    "kq_prefill_paged_attention": (functools.partial(
+        kq_prefill_paged_attention, scale=SCALE, interpret=False,
+        max_len=T), _PREFILL),
+    "kq_decode_attention": (functools.partial(
+        kq_decode_attention, scale=SCALE, interpret=False, max_len=T),
+        [((B, H, R), _BF), ((B, HKV, T, R), _BF), ((B, HKV, T, R), _BF),
+         ((B,), _I32)]),
+    "flash_attention": (_flash, [((1, H, S, 64), _BF),
+                                 ((1, HKV, S, 64), _BF),
+                                 ((1, HKV, S, 64), _BF)]),
+}
+
+
+@pytest.mark.parametrize("name", list(_KERNELS))
+def test_compiled_kernel_carries_its_name(one_chip, name):
+    fn, shapes = _KERNELS[name]
+    text = _compile(fn, one_chip, *shapes)
+    calls = [ln for ln in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in ln]
+    assert calls and all(_named(name) in ln for ln in calls), calls
+
+
+@pytest.fixture(scope="module")
+def engine_programs(one_chip):
+    """A started paged engine (reduced TinyLlama) with its Pallas
+    kernels on the path as on a TPU, and its state as abstract values
+    on the described chip."""
+    import numpy as np
+    import repro.kernels
+    import repro.kernels.kq_decode.paged as paged
+    from repro.config import ServeConfig
+    from repro.configs import get_config
+    from repro.models import build_model
+    from repro.serving import ServingEngine
+    cfg = get_config("tinyllama-1.1b").reduced()
+    params = build_model(cfg).init(jax.random.PRNGKey(0))
+    eng = ServingEngine(cfg, params, ServeConfig(
+        max_seq_len=64, max_batch=4, paged=True, page_size=16,
+        chunked_prefill=True, prefill_chunk=32))
+    eng.start([])
+    state = jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(np.shape(x), x.dtype,
+                                       sharding=one_chip),
+        (eng.params, eng.proj, eng._cache, eng._logits, eng._pos,
+         eng._emitted, eng._max_new, eng._done, eng._trunc, eng.rng,
+         eng._btabs.device()))
+    chunk = [jax.ShapeDtypeStruct(s, _I32, sharding=one_chip)
+             for s in ((1, 32), (1,), (1,), eng._btabs.rows[:1].shape)]
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(repro.kernels, "use_kernels", lambda: True)
+        mp.setattr(paged, "default_interpret", lambda: False)
+        yield eng, state, chunk
+
+
+@pytest.mark.parametrize("program, kernels", [
+    ("decode_chunk", ["kq_decode_paged_attention"]),
+    ("prefill_chunk", ["kq_prefill_paged_attention"]),
+    ("fused_step", ["kq_prefill_paged_attention",
+                    "kq_decode_paged_attention"])])
+def test_engine_programs_carry_kernel_names(engine_programs, program,
+                                            kernels):
+    eng, state, chunk = engine_programs
+    if program == "decode_chunk":
+        low = eng._decode_chunk.lower(*state, num_splits=1)
+    elif program == "prefill_chunk":
+        low = eng._prefill_chunk.lower(*state[:3], *chunk)
+    else:
+        low = eng._fused_step.lower(*state[:3], *chunk, *state[3:],
+                                    num_splits=1)
+    text = low.compile().as_text()
+    calls = [ln for ln in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in ln]
+    assert len(calls) == len(kernels), calls
+    for name in kernels:
+        assert any(_named(name) in ln for ln in calls), (name, calls)
