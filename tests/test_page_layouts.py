@@ -34,7 +34,7 @@ from repro.serving.page_layouts import (FpLayout, Int8Layout, SvdqLayout,
                                         svdq_bits_from_spectrum)
 
 try:
-    from hypothesis import given, settings, strategies as st
+    from hypothesis import example, given, settings, strategies as st
     HAVE_HYPOTHESIS = True
 except ImportError:          # container has no hypothesis; CI does
     HAVE_HYPOTHESIS = False
@@ -115,20 +115,32 @@ def test_svdq_bit_allocation_shapes():
 # ---------------------------------------------------------------------------
 
 
-def _paged_int8_case(seed, num_splits):
-    B, G, m, T, ps, R = 2, 2, 2, 16, 4, 8
+# (KV heads, group size): the narrow default, then the benchmark cells'
+# head shapes — MHA, group 1 (phi-3), and GQA with 8 KV heads of group
+# 8 (deepseek-67b)
+_INT8_SHAPES = [(2, 2), (4, 1), (8, 8)]
+
+
+def _paged_int8_case(seed, num_splits, shape=(2, 2)):
+    G, m = shape
+    T, ps, R = 16, 4, 8
+    # one token, one page, one past a page edge, the full bound, a tail
+    lens = jnp.asarray([13, T, 1, ps, ps + 1], jnp.int32)
+    B = lens.shape[0]
     rng = np.random.default_rng(seed)
     k = jnp.asarray(rng.normal(size=(B, G, T, R)), jnp.float32)
     v = jnp.asarray(rng.normal(size=(B, G, T, R)), jnp.float32)
-    q = jnp.asarray(rng.normal(size=(B, G * m, R)), jnp.float32)
-    lens = jnp.asarray([13, T], jnp.int32)
+    q = jnp.asarray(rng.normal(size=(B + 1, G * m, R)), jnp.float32)
     lay = Int8Layout()
     enc_k, enc_v = lay.encode("k", k), lay.encode("v", v)
 
-    # repage the dense-quantized leaves into shuffled physical pools
+    # repage the dense-quantized leaves into shuffled physical pools; a
+    # last slot is exported as the garbage page, whose contents are the
+    # first slot's first page
     n_phys = 1 + B * (T // ps)
     perm = rng.permutation(np.arange(1, n_phys, dtype=np.int32))
-    btab = perm.reshape(B, T // ps)
+    btab = np.concatenate([perm.reshape(B, T // ps),
+                           np.zeros((1, T // ps), np.int32)])
 
     def pool_of(dense, width):
         pool = np.zeros((n_phys, G, ps, width), np.asarray(dense).dtype)
@@ -136,29 +148,28 @@ def _paged_int8_case(seed, num_splits):
         for b in range(B):
             for j in range(T // ps):
                 pool[btab[b, j]] = d[b, :, j * ps:(j + 1) * ps, :]
+        pool[0] = d[0, :, :ps, :]
         return jnp.asarray(pool)
 
+    pools = (pool_of(enc_k["kc"], R), pool_of(enc_v["vc"], R))
+    scales = (pool_of(enc_k["kscale"], 1), pool_of(enc_v["vscale"], 1))
+    all_lens = jnp.concatenate([lens, lens[1:2]])
     out = kq_decode_paged_attention_op(
-        q, pool_of(enc_k["kc"], R), pool_of(enc_v["vc"], R),
-        lens, jnp.asarray(btab), scale=0.3, max_len=T,
-        num_splits=num_splits,
-        kscale=pool_of(enc_k["kscale"], 1),
-        vscale=pool_of(enc_v["vscale"], 1))
+        q, *pools, all_lens, jnp.asarray(btab), scale=0.3, max_len=T,
+        num_splits=num_splits, kscale=scales[0], vscale=scales[1])
 
     valid = jnp.arange(T)[None, :] < lens[:, None]
     dense = int8_decode_attention(
-        q.reshape(B, G, m, R), enc_k["kc"], enc_v["vc"],
+        q[:B].reshape(B, G, m, R), enc_k["kc"], enc_v["vc"],
         jnp.asarray(enc_k["kscale"])[..., 0],
         jnp.asarray(enc_v["vscale"])[..., 0], valid, 0.3)
     # the dense twin casts its output to bf16 — compare at bf16 grain
-    np.testing.assert_allclose(np.asarray(out, np.float32),
+    np.testing.assert_allclose(np.asarray(out[:B], np.float32),
                                np.asarray(dense,
                                           np.float32).reshape(B, G * m, R),
                                rtol=1e-2, atol=1e-2)
     ref = kq_decode_paged_attention_int8_ref(
-        q, pool_of(enc_k["kc"], R), pool_of(enc_v["vc"], R),
-        pool_of(enc_k["kscale"], 1), pool_of(enc_v["vscale"], 1),
-        lens, jnp.asarray(btab), scale=0.3)
+        q, *pools, *scales, all_lens, jnp.asarray(btab), scale=0.3)
     np.testing.assert_allclose(np.asarray(out, np.float32),
                                np.asarray(ref, np.float32),
                                rtol=2e-5, atol=2e-5)
@@ -167,18 +178,25 @@ def _paged_int8_case(seed, num_splits):
 if HAVE_HYPOTHESIS:
     @settings(max_examples=10, deadline=None)
     @given(seed=st.integers(min_value=0, max_value=2 ** 16),
-           num_splits=st.integers(min_value=1, max_value=4))
-    def test_paged_int8_matches_dense_int8(seed, num_splits):
+           num_splits=st.integers(min_value=1, max_value=4),
+           shape=st.sampled_from(_INT8_SHAPES))
+    @example(seed=0, num_splits=1, shape=(4, 1))
+    @example(seed=1, num_splits=3, shape=(4, 1))
+    @example(seed=2, num_splits=1, shape=(8, 8))
+    @example(seed=3, num_splits=3, shape=(8, 8))
+    def test_paged_int8_matches_dense_int8(seed, num_splits, shape):
         """The dequantize-on-the-fly paged kernel (unsplit and split)
         equals the dense int8 decode on the same quantized entries."""
-        _paged_int8_case(seed, num_splits)
+        _paged_int8_case(seed, num_splits, shape)
 else:
-    @pytest.mark.parametrize("seed,num_splits",
-                             [(0, 1), (1, 2), (2, 3), (3, 4)])
-    def test_paged_int8_matches_dense_int8(seed, num_splits):
+    @pytest.mark.parametrize("seed,num_splits,shape",
+                             [(0, 1, (2, 2)), (1, 2, (2, 2)), (2, 3, (2, 2)),
+                              (3, 4, (2, 2)), (0, 1, (4, 1)), (1, 3, (4, 1)),
+                              (2, 1, (8, 8)), (3, 3, (8, 8))])
+    def test_paged_int8_matches_dense_int8(seed, num_splits, shape):
         """Fixed-grid fallback of the hypothesis property when
         hypothesis is not installed (CI runs the full property)."""
-        _paged_int8_case(seed, num_splits)
+        _paged_int8_case(seed, num_splits, shape)
 
 
 # ---------------------------------------------------------------------------

@@ -13,6 +13,7 @@ from repro.configs import get_config
 from repro.core.calibration import GramAccumulator
 from repro.kernels.kq_decode import (kq_decode_attention_op,
                                      kq_decode_attention_ref,
+                                     kq_decode_paged_attention_int8_ref,
                                      kq_decode_paged_attention_op,
                                      kq_decode_paged_attention_ref)
 from repro.models import build_model
@@ -126,11 +127,20 @@ def _paged_setup(B, Hkv, n_pages, ps, Rk, Rv, seed=0):
     (3, 4, 2, 5, 8, 16, 8, (40, 8, 9)),           # page-boundary edges
     (1, 8, 4, 3, 16, 8, 16, (17,)),               # crosses into page 2
     (2, 2, 2, 2, 32, 16, 16, (1, 33)),
+    # the benchmark cells' head shapes at narrow ranks: MHA, group 1
+    # (phi-3), and GQA with 8 KV heads of group 8 (deepseek-67b); one
+    # token, one page, one past a page edge, the full bound
+    (4, 8, 8, 3, 8, 16, 16, (1, 8, 9, 24)),
+    (4, 64, 8, 3, 8, 16, 8, (1, 8, 9, 24)),
 ])
 def test_paged_kernel_matches_ref(B, H, Hkv, n_pages, ps, Rk, Rv, lengths,
                                   dtype):
     kq, kc, vc, btab = _paged_setup(B, Hkv, n_pages, ps, Rk, Rv)
-    qc = jax.random.normal(kq, (B, H, Rk)).astype(dtype)
+    # one more slot exported as the garbage page (page 0), as the engine
+    # exports an idle or mid-prefill slot, at the longest length
+    btab = jnp.concatenate([btab, jnp.zeros((1, n_pages), jnp.int32)])
+    lengths = (*lengths, max(lengths))
+    qc = jax.random.normal(kq, (B + 1, H, Rk)).astype(dtype)
     kc, vc = kc.astype(dtype), vc.astype(dtype)
     lens = jnp.asarray(lengths, jnp.int32)
     out = kq_decode_paged_attention_op(qc, kc, vc, lens, btab, scale=0.25)
@@ -139,6 +149,42 @@ def test_paged_kernel_matches_ref(B, H, Hkv, n_pages, ps, Rk, Rv, lengths,
     np.testing.assert_allclose(np.asarray(out, np.float32),
                                np.asarray(ref, np.float32),
                                rtol=tol, atol=tol)
+
+
+@pytest.mark.parametrize("num_splits", [1, 3])
+@pytest.mark.parametrize("quant", [False, True], ids=["fp", "int8"])
+def test_paged_kernel_grouped_heads(monkeypatch, quant, num_splits):
+    """A VMEM budget that holds fewer than all KV heads' pages folds them
+    in groups: 6 KV heads at a budget of 4 heads' blocks take 3 a
+    program, so the grid walks two head groups."""
+    from repro.kernels.kq_decode import paged
+    B, H, Hkv, n_pages, ps, R = 3, 12, 6, 3, 8, 16
+    kq, kc, vc, btab = _paged_setup(B, Hkv, n_pages, ps, R, R, seed=4)
+    qc = jax.random.normal(kq, (B, H, R))
+    lens = jnp.asarray([24, 9, 1], jnp.int32)
+    kw = {}
+    if quant:
+        kc, vc = (jnp.round(x * 40).astype(jnp.int8) for x in (kc, vc))
+        kw = {"kscale": jnp.full(kc.shape[:3] + (1,), 0.025, jnp.bfloat16),
+              "vscale": jnp.full(vc.shape[:3] + (1,), 0.03, jnp.bfloat16)}
+    sc_bytes = 2 if quant else 0
+    # double-buffered K and V blocks of one head, a scale block filling
+    # a 128-lane row per token
+    per_head = 2 * ps * (2 * R * kc.dtype.itemsize + 2 * 128 * sc_bytes)
+    monkeypatch.setattr(paged, "DECODE_VMEM_BUDGET", 4 * per_head)
+    assert paged.decode_heads_per_block(Hkv, ps, R, R, kc.dtype.itemsize,
+                                        sc_bytes) == 3
+    out = paged.kq_decode_paged_attention(qc, kc, vc, lens, btab,
+                                          scale=0.3, num_splits=num_splits,
+                                          **kw)
+    if quant:
+        ref = kq_decode_paged_attention_int8_ref(
+            qc, kc, vc, kw["kscale"], kw["vscale"], lens, btab, scale=0.3)
+    else:
+        ref = kq_decode_paged_attention_ref(qc, kc, vc, lens, btab,
+                                            scale=0.3)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                               rtol=2e-5, atol=2e-5)
 
 
 def test_paged_kernel_matches_dense_kernel():

@@ -39,20 +39,33 @@ def _paged_setup(B, Hkv, n_pages, ps, Rk, Rv, seed=0):
 # ---------------------------------------------------------------------------
 
 
-@pytest.mark.parametrize("num_splits", [2, 3, 4])
-def test_split_kernel_matches_ref_boundary_lengths(num_splits):
+@pytest.mark.parametrize("num_splits,H,Hkv", [
+    pytest.param(2, 4, 2, id="2"),
+    pytest.param(3, 4, 2, id="3"),
+    pytest.param(4, 4, 2, id="4"),
+    # the benchmark cells' head shapes: MHA, group 1 (phi-3), and GQA
+    # with 8 KV heads of group 8 (deepseek-67b)
+    pytest.param(2, 8, 8, id="mha-2"),
+    pytest.param(4, 8, 8, id="mha-4"),
+    pytest.param(2, 64, 8, id="gqa8-2"),
+    pytest.param(4, 64, 8, id="gqa8-4"),
+])
+def test_split_kernel_matches_ref_boundary_lengths(num_splits, H, Hkv):
     """Lengths straddling every split boundary: for each span edge,
-    len % (span*ps) in {0, 1, span*ps - 1} plus the global edges."""
-    B, H, Hkv, n_pages, ps, Rk, Rv = 1, 4, 2, 6, 4, 16, 16
-    kq, kc, vc, btab = _paged_setup(B, Hkv, n_pages, ps, Rk, Rv)
-    qc = jax.random.normal(kq, (B, H, Rk))
+    len % (span*ps) in {0, 1, span*ps - 1} plus the global edges.  A
+    second slot, exported as the garbage page, runs at the same
+    length."""
+    n_pages, ps, Rk, Rv = 6, 4, 16, 16
+    kq, kc, vc, btab = _paged_setup(1, Hkv, n_pages, ps, Rk, Rv)
+    btab = jnp.concatenate([btab, jnp.zeros((1, n_pages), jnp.int32)])
+    qc = jax.random.normal(kq, (2, H, Rk))
     span = -(-n_pages // num_splits)
     step = span * ps
-    lengths = {1, n_pages * ps}
+    lengths = {1, ps, ps + 1, n_pages * ps}
     for edge in range(step, n_pages * ps + 1, step):
         lengths |= {edge - 1, edge, min(edge + 1, n_pages * ps)}
     for L in sorted(lengths):
-        lens = jnp.asarray([L], jnp.int32)
+        lens = jnp.asarray([L, L], jnp.int32)
         out = kq_decode_paged_attention_op(qc, kc, vc, lens, btab,
                                            scale=0.3,
                                            num_splits=num_splits)

@@ -1,5 +1,6 @@
 """Compile rehearsal: the serving path's Pallas kernels compiled for a
-described TPU v5e at TinyLlama-1.1B widths, with no chip attached.
+described TPU v5e at TinyLlama-1.1B widths, and the paged decode kernel
+at the benchmark cells' decode shapes too, with no chip attached.
 
 Mosaic refuses things interpret mode accepts (tiles not aligned to the
 layout, more VMEM than a kernel may use), so these compiles guard the
@@ -17,7 +18,8 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from repro.kernels.kq_decode.kq_decode import kq_decode_attention
-from repro.kernels.kq_decode.paged import (kq_decode_paged_attention,
+from repro.kernels.kq_decode.paged import (decode_heads_per_block,
+                                           kq_decode_paged_attention,
                                            kq_prefill_paged_attention)
 
 # TinyLlama-1.1B serving shapes: 8 slots, 32 query / 4 kv heads, KQ-SVD
@@ -64,24 +66,55 @@ _BF, _I32, _I8 = jnp.bfloat16, jnp.int32, jnp.int8
 _DECODE = [((B, H, R), _BF), ((N_PHYS, HKV, PS, R), _BF),
            ((N_PHYS, HKV, PS, R), _BF), ((B,), _I32), ((B, NPP), _I32)]
 
+# decode shapes (B, H, Hkv, R, page_size, T, pool pages): TinyLlama's
+# above, and the benchmark cells' (bench/configs): phi-3's MHA at rank
+# 48 (lane-padded to 128 by the kernel), 48 pages; deepseek-67b's GQA-8
+# at rank 64, 384 pages
+_SHAPES = {"tinyllama": (B, H, HKV, R, PS, T, B * NPP),
+           "phi3v-azconv": (3, 32, 32, 48, 64, 3072, 48),
+           "dsk67b-sharegpt": (48, 64, 8, 64, 64, 2048, 384)}
+_DECODE_CASES = [pytest.param("tinyllama", 1, id="1"),
+                 pytest.param("tinyllama", 4, id="4")] + [
+    pytest.param(cell, n, id=f"{cell}-{n}")
+    for cell in ("phi3v-azconv", "dsk67b-sharegpt") for n in (1, 8)]
 
-@pytest.mark.parametrize("num_splits", [1, 4])
-def test_paged_decode_compiles_for_v5e(one_chip, num_splits):
+
+def _decode_shapes(shape, dtype=_BF):
+    b, h, hkv, r, ps, t, pages = _SHAPES[shape]
+    pool = ((pages + 1, hkv, ps, r), dtype)
+    return ([((b, h, r), _BF), pool, pool, ((b,), _I32),
+             ((b, t // ps), _I32)], (pages + 1, hkv, ps, 1), t)
+
+
+@pytest.mark.parametrize("shape, num_splits", _DECODE_CASES)
+def test_paged_decode_compiles_for_v5e(one_chip, shape, num_splits):
+    shapes, _, t = _decode_shapes(shape)
     fn = functools.partial(kq_decode_paged_attention, scale=SCALE,
-                           interpret=False, max_len=T,
+                           interpret=False, max_len=t,
                            num_splits=num_splits)
-    _compile(fn, one_chip, *_DECODE)
+    _compile(fn, one_chip, *shapes)
 
 
-@pytest.mark.parametrize("num_splits", [1, 4])
-def test_paged_decode_int8_compiles_for_v5e(one_chip, num_splits):
+@pytest.mark.parametrize("shape, num_splits", _DECODE_CASES)
+def test_paged_decode_int8_compiles_for_v5e(one_chip, shape, num_splits):
+    shapes, scale_pool, t = _decode_shapes(shape, _I8)
+
     def fn(q, kc, vc, lens, btab, ks, vs):
         return kq_decode_paged_attention(
             q, kc, vc, lens, btab, scale=SCALE, interpret=False,
-            max_len=T, num_splits=num_splits, kscale=ks, vscale=vs)
-    pools = [((N_PHYS, HKV, PS, R), _I8)] * 2
-    scales = [((N_PHYS, HKV, PS, 1), _BF)] * 2
-    _compile(fn, one_chip, _DECODE[0], *pools, *_DECODE[3:], *scales)
+            max_len=t, num_splits=num_splits, kscale=ks, vscale=vs)
+    _compile(fn, one_chip, *shapes, *[(scale_pool, _BF)] * 2)
+
+
+@pytest.mark.parametrize("shape", list(_SHAPES))
+@pytest.mark.parametrize("int8", [False, True], ids=["bf16", "int8"])
+def test_decode_folds_every_kv_head(shape, int8):
+    """At TinyLlama's and both cells' shapes one decode program moves
+    the page of every KV head: the VMEM budget admits them all."""
+    _, _, hkv, r, ps, _, _ = _SHAPES[shape]
+    r = -(-r // 128) * 128                      # as lane-padded
+    assert decode_heads_per_block(hkv, ps, r, r, 1 if int8 else 2,
+                                  2 if int8 else 0) == hkv
 
 
 def test_paged_prefill_compiles_for_v5e(one_chip):
