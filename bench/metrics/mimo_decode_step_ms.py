@@ -1,0 +1,4 @@
+"""Model step: ``decode_step_ms`` in the cells of a ``mimo_reference``
+configuration, under an entry of their own (the reader depends on no
+model dimension)."""
+from bench.metrics.decode_step_ms import read  # noqa: F401

@@ -1,0 +1,4 @@
+"""Device: ``idle_fetch_frac`` in the cells of a ``mimo_reference``
+configuration, under an entry of their own (the reader depends on no
+model dimension)."""
+from bench.metrics.idle_fetch_frac import read  # noqa: F401

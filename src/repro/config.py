@@ -83,6 +83,60 @@ class HybridConfig:
     attn_offset: int = 4
 
 
+@dataclass(frozen=True)
+class AttnKind:
+    """One kind of attention layer in a model that mixes kinds."""
+
+    n_kv_heads: int
+    rope_theta: float
+    window: int = 0                     # 0 => full attention
+    sink: bool = False                  # learnable per-head softmax sink
+
+
+@dataclass(frozen=True)
+class AttnKindsConfig:
+    """Per-layer attention kinds (MiMo-V2-Flash: window and full layers
+    with different KV head counts).  Layer ``i`` is of kind
+    ``kinds[pattern[i]]``; the query/key head size is
+    ``ModelConfig.d_head`` and the value head size ``v_head_dim``."""
+
+    pattern: Tuple[int, ...]
+    kinds: Tuple[AttnKind, ...]
+    v_head_dim: int = 0                 # 0 => d_head
+    rotary_dim: int = 0                 # leading q/k dims rotated; 0 => all
+    value_scale: float = 1.0            # multiplies the values
+
+
+@dataclass(frozen=True)
+class RoutingConfig:
+    """A DeepSeek-V3 style router (``noaux_tc``, MiMo-V2-Flash): sigmoid
+    scores, a selection bias that enters the top-k choice only, weights
+    normalised over the chosen k; served by the dropless held-experts
+    layer (``moe.held_moe_ffn``).  ``held`` is the contiguous range
+    [first, end) of experts whose weights this device holds (the chip's
+    share under expert parallelism); (0, 0) holds them all."""
+
+    held: Tuple[int, int] = (0, 0)
+
+    def held_range(self, n_experts: int) -> Tuple[int, int]:
+        return self.held if self.held != (0, 0) else (0, n_experts)
+
+
+@dataclass(frozen=True)
+class AttnSpec:
+    """The shapes and rules of one attention layer, resolved from the
+    model config (``ModelConfig.attn_spec``)."""
+
+    n_kv_heads: int
+    d_qk: int
+    d_v: int
+    rotary_dim: int
+    rope_theta: float
+    window: int
+    sink: bool
+    value_scale: float
+
+
 # ---------------------------------------------------------------------------
 # Compression (the paper's technique)
 # ---------------------------------------------------------------------------
@@ -139,6 +193,8 @@ class ModelConfig:
     mla: Optional[MLAConfig] = None
     ssm: Optional[SSMConfig] = None
     hybrid: Optional[HybridConfig] = None
+    attn_kinds: Optional[AttnKindsConfig] = None
+    routing: Optional[RoutingConfig] = None
     compression: CompressionConfig = field(default_factory=CompressionConfig)
     # runtime knobs
     dtype: str = "bfloat16"
@@ -163,6 +219,36 @@ class ModelConfig:
         if self.qhead_pad:
             assert self.qhead_pad >= self.n_heads
             assert self.qhead_pad % max(1, self.n_kv_heads) == 0
+        if self.attn_kinds is not None:
+            ak = self.attn_kinds
+            if len(ak.pattern) < self.n_layers:
+                raise ValueError("attn_kinds.pattern is shorter than the "
+                                 "layer stack")
+            if self.qhead_pad or self.sliding_window:
+                raise ValueError("attn_kinds sets windows per kind and "
+                                 "takes no qhead_pad or sliding_window")
+
+    def attn_spec(self, layer_idx: int = 0) -> AttnSpec:
+        """Head counts, sizes, rotary and window of attention layer
+        ``layer_idx`` (one kind for every layer without ``attn_kinds``)."""
+        ak = self.attn_kinds
+        if ak is None:
+            return AttnSpec(self.n_kv_heads, self.d_head, self.d_head,
+                            self.d_head, self.rope_theta,
+                            self.sliding_window, False, 1.0)
+        k = ak.kinds[ak.pattern[layer_idx]]
+        return AttnSpec(k.n_kv_heads, self.d_head,
+                        ak.v_head_dim or self.d_head,
+                        ak.rotary_dim or self.d_head, k.rope_theta,
+                        k.window, k.sink, ak.value_scale)
+
+    @property
+    def ring_window(self) -> int:
+        """Largest window of a windowed layer kind whose paged state is a
+        per-slot ring (0 without such kinds)."""
+        if self.attn_kinds is None:
+            return 0
+        return max((k.window for k in self.attn_kinds.kinds), default=0)
 
     @property
     def attention_free(self) -> bool:
@@ -228,6 +314,27 @@ class ModelConfig:
                 every_n_layers=self.moe.every_n_layers)
             n_layers = max(n_layers, self.moe.first_k_dense + 2
                            * self.moe.every_n_layers)
+        if self.routing is not None and self.moe is not None:
+            # 16 experts, 4 a token; a cut share keeps a quarter of them
+            full = self.routing.held_range(self.moe.n_experts) == (
+                0, self.moe.n_experts)
+            kw["moe"] = dataclasses.replace(kw["moe"], n_experts=16,
+                                            top_k=4)
+            kw["routing"] = dataclasses.replace(
+                self.routing, held=(0, 0) if full else (0, 4))
+        d_head = 16
+        if self.attn_kinds is not None:
+            # two kinds with 2 and 1 KV heads, q/k 24 and v 16, 8 of 24
+            # dims rotated, windows of 16; the first 6 layers of the
+            # pattern hold a window layer between two full ones
+            n_layers = max(n_layers, 6)
+            kinds = tuple(dataclasses.replace(
+                k, n_kv_heads=2 if k.window else 1,
+                window=16 if k.window else 0) for k in self.attn_kinds.kinds)
+            kw["attn_kinds"] = dataclasses.replace(
+                self.attn_kinds, pattern=self.attn_kinds.pattern[:n_layers],
+                kinds=kinds, v_head_dim=16, rotary_dim=8)
+            d_head = 24
         if self.mla is not None:
             kw["mla"] = dataclasses.replace(
                 self.mla, kv_lora_rank=32, q_lora_rank=0,
@@ -245,7 +352,7 @@ class ModelConfig:
             d_model=64,
             n_heads=n_heads,
             n_kv_heads=n_kv,
-            d_head=16 if n_heads else 0,
+            d_head=d_head if n_heads else 0,
             qhead_pad=0,
             d_ff=128,
             vocab_size=256,
@@ -269,10 +376,11 @@ def _count_params(cfg: ModelConfig, active_only: bool) -> int:
         total += 2 * D                              # two RMSNorm gains
         kind = cfg.layer_kinds()[i]
         if kind == "attn":
-            dh = cfg.d_head
-            total += D * cfg.n_heads * dh           # Wq
-            total += 2 * D * cfg.n_kv_heads * dh    # Wk, Wv
-            total += cfg.n_heads * dh * D           # Wo
+            sp = cfg.attn_spec(i)
+            total += D * cfg.n_heads * sp.d_qk      # Wq
+            total += D * sp.n_kv_heads * (sp.d_qk + sp.d_v)   # Wk, Wv
+            total += cfg.n_heads * sp.d_v * D       # Wo
+            total += cfg.n_heads if sp.sink else 0
         elif kind == "mla":
             m = cfg.mla
             qk = m.qk_nope_dim + m.qk_rope_dim
@@ -301,7 +409,12 @@ def _count_params(cfg: ModelConfig, active_only: bool) -> int:
         else:
             mo = cfg.moe
             per_expert = 3 * D * mo.expert_ff
-            n_used = mo.top_k if active_only else mo.n_experts
+            n_held = mo.n_experts
+            if cfg.routing is not None:
+                lo, hi = cfg.routing.held_range(mo.n_experts)
+                n_held = hi - lo
+                total += mo.n_experts               # selection bias
+            n_used = mo.top_k if active_only else n_held
             total += n_used * per_expert
             total += mo.n_shared_experts * per_expert
             total += D * mo.n_experts               # router

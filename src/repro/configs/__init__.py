@@ -22,6 +22,7 @@ _ARCH_MODULES = {
     "smollm-360m": "smollm_360m",
     "h2o-danube-1.8b": "h2o_danube_1_8b",
     "phi-3-vision-4.2b": "phi_3_vision_4_2b",
+    "mimo-v2-flash": "mimo_v2_flash",
     # the paper's own evaluation model (not part of the assigned 10)
     "paper-llama2-7b": "paper_llama2_7b",
 }

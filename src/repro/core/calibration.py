@@ -19,7 +19,7 @@ per-group stacked output weights ``(Hkv, dv, Do_group)``.
 from __future__ import annotations
 
 from dataclasses import dataclass, field
-from typing import Dict, List, Optional, Sequence
+from typing import Dict, List, Optional, Sequence, Union
 
 import numpy as np
 
@@ -40,28 +40,31 @@ class LayerGrams:
 
 @dataclass
 class ModelProjections:
-    """Solved projections for every attention layer, shape-uniform.
+    """Solved projections for every attention layer.
 
     Arrays are zero-padded to the layer-max rank so they stack cleanly for
     scan-over-layers execution; ``ranks_k``/``ranks_v`` record the true
-    per-layer ranks (paper's per-layer selection).
+    per-layer ranks (paper's per-layer selection).  Where attention
+    layers differ in KV head count (per-layer kinds, MiMo-V2-Flash's 8
+    and 4), each field is instead a list of per-layer arrays, in layer
+    order: indexing by layer works the same either way.
     """
 
-    a_k: np.ndarray            # (L_attn, Hkv, dk, R)
-    b_q: np.ndarray            # (L_attn, Hkv, dk, R)
-    a_v: Optional[np.ndarray]  # (L_attn, Hkv, dv, Rv)
-    c_v: Optional[np.ndarray]  # (L_attn, Hkv, Rv, Do_group)
+    a_k: Union[np.ndarray, List[np.ndarray]]   # (L_attn, Hkv, dk, R)
+    b_q: Union[np.ndarray, List[np.ndarray]]   # (L_attn, Hkv, dk, R)
+    a_v: Optional[Union[np.ndarray, List[np.ndarray]]]  # (L, Hkv, dv, Rv)
+    c_v: Optional[Union[np.ndarray, List[np.ndarray]]]  # (L, Hkv, Rv, Do)
     ranks_k: List[int] = field(default_factory=list)
     ranks_v: List[int] = field(default_factory=list)
     method: str = "kqsvd"
 
     @property
     def rank_k(self) -> int:
-        return self.a_k.shape[-1]
+        return self.a_k[0].shape[-1]
 
     @property
     def rank_v(self) -> int:
-        return 0 if self.a_v is None else self.a_v.shape[-1]
+        return 0 if self.a_v is None else self.a_v[0].shape[-1]
 
 
 class GramAccumulator:
@@ -152,7 +155,15 @@ class GramAccumulator:
                                 method=cfg.method)
 
 
-def _stack_pad(layers: List[List[np.ndarray]], R: int) -> np.ndarray:
+def _stack(out: List[np.ndarray]):
+    """One (L, ...) array, or the per-layer list where layers differ in
+    shape (per-layer attention kinds)."""
+    if len({a.shape for a in out}) == 1:
+        return np.stack(out)
+    return out
+
+
+def _stack_pad(layers: List[List[np.ndarray]], R: int):
     """Stack (d, r_l) factors into (L, H, d, R), zero-padding columns."""
     out = []
     for layer in layers:
@@ -161,10 +172,10 @@ def _stack_pad(layers: List[List[np.ndarray]], R: int) -> np.ndarray:
             pad = R - M.shape[1]
             heads.append(np.pad(M, ((0, 0), (0, pad))) if pad else M)
         out.append(np.stack(heads))
-    return np.stack(out)
+    return _stack(out)
 
 
-def _stack_pad_rows(layers: List[List[np.ndarray]], R: int) -> np.ndarray:
+def _stack_pad_rows(layers: List[List[np.ndarray]], R: int):
     """Stack (r_l, Do) factors into (L, H, R, Do), zero-padding rows."""
     out = []
     for layer in layers:
@@ -173,7 +184,7 @@ def _stack_pad_rows(layers: List[List[np.ndarray]], R: int) -> np.ndarray:
             pad = R - M.shape[0]
             heads.append(np.pad(M, ((0, pad), (0, 0))) if pad else M)
         out.append(np.stack(heads))
-    return np.stack(out)
+    return _stack(out)
 
 
 # ---------------------------------------------------------------------------

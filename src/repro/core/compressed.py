@@ -11,6 +11,7 @@ from __future__ import annotations
 from dataclasses import dataclass
 
 import jax.numpy as jnp
+import numpy as np
 
 from repro.core.calibration import ModelProjections
 
@@ -59,7 +60,6 @@ def cache_footprint(n_kv_heads: int, d_head: int, rank_k: int, rank_v: int,
 
 
 def projection_param_bytes(p: ModelProjections, itemsize: int = 2) -> int:
-    total = p.a_k.size + p.b_q.size
-    if p.a_v is not None:
-        total += p.a_v.size + p.c_v.size
-    return total * itemsize
+    fields = [p.a_k, p.b_q] + ([p.a_v, p.c_v] if p.a_v is not None else [])
+    # a field is one stacked array or a list of per-layer arrays
+    return itemsize * sum(sum(np.size(a) for a in f) for f in fields)

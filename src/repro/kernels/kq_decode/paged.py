@@ -75,6 +75,12 @@ NEG_INF = -1e30
 # served models fit one block (phi-3's 32 heads of 64x128 bf16 pages:
 # 2 MiB double-buffered); a model with more folds them in groups.
 DECODE_VMEM_BUDGET = 4 << 20
+# Query rows (query heads of a group times chunk tokens) one prefill
+# program holds.  A (8192, 128) query block makes Mosaic compile for
+# about two minutes (v5e, described) against 24 s at 4096 rows, so a
+# group with more rows than this runs as several calls, each over a
+# slice of the group's query heads.
+PREFILL_ROWS = 4096
 
 
 def decode_heads_per_block(hkv: int, page_size: int, rk: int, rv: int,
@@ -108,7 +114,7 @@ def _init_softmax(m_ref, l_ref, acc_ref):
 
 
 def _page_update(q_ref, pages, m_ref, l_ref, acc_ref, *, start, length,
-                 scale: float):
+                 scale: float, lo=None):
     """Online-softmax update of ``hb`` KV heads with one page whose first
     token sits at logical position ``start`` — the body both decode
     kernels share.
@@ -118,7 +124,8 @@ def _page_update(q_ref, pages, m_ref, l_ref, acc_ref, *, start, length,
     and sum (hb, m, 1) — keepdims layouts, so nothing is relaid out
     between the reductions and the broadcasts — and the f32 accumulator
     (hb, m, Rv).  Per head this is the single-head update: f32
-    widening, f32 accumulation, ``tpos < length`` masking.
+    widening, f32 accumulation, ``tpos < length`` masking; with ``lo``
+    (the window kernel) also ``tpos >= lo``.
     """
     k_ref, ks_ref, v_ref, vs_ref = pages
     q = q_ref[0].astype(jnp.float32)                      # (hb, m, Rk)
@@ -130,7 +137,10 @@ def _page_update(q_ref, pages, m_ref, l_ref, acc_ref, *, start, length,
     s = jax.lax.dot_general(q, k, (((2,), (2,)), ((0,), (0,))),
                             preferred_element_type=jnp.float32) * scale
     tpos = start + jax.lax.broadcasted_iota(jnp.int32, s.shape, 2)
-    s = jnp.where(tpos < length, s, NEG_INF)              # (hb, m, ps)
+    live = tpos < length
+    if lo is not None:
+        live = live & (tpos >= lo)
+    s = jnp.where(live, s, NEG_INF)                       # (hb, m, ps)
     m_prev = m_ref[...]
     m_new = jnp.maximum(m_prev, s.max(axis=2, keepdims=True))
     p = jnp.exp(s - m_new)
@@ -404,6 +414,19 @@ def kq_prefill_paged_attention(qc, kc_pool, vc_pool, lengths, pos0,
     P, Hkv, ps, _ = kc_pool.shape
     Rv = vc_pool.shape[-1]
     m = H // Hkv
+    n_calls = -(-m * S // PREFILL_ROWS)
+    if n_calls > 1 and m % n_calls == 0:
+        # each call takes m / n_calls query heads of every group
+        sub = m // n_calls
+        qs = qc.reshape(B, Hkv, m, S, Rk)
+        outs = [kq_prefill_paged_attention(
+            qs[:, :, i * sub:(i + 1) * sub].reshape(B, Hkv * sub, S, Rk),
+            kc_pool, vc_pool, lengths, pos0, block_table, scale=scale,
+            interpret=interpret, max_len=max_len, pad_lanes=False)
+            for i in range(n_calls)]
+        return jnp.concatenate(
+            [o.reshape(B, Hkv, sub, S, Rv) for o in outs],
+            axis=2).reshape(B, H, S, Rv)
     n_pages = block_table.shape[1]
     T = n_pages * ps
     lengths = jnp.asarray(lengths, jnp.int32)
@@ -576,3 +599,108 @@ def kq_decode_paged_attention(qc, kc_pool, vc_pool, lengths, block_table,
         name="kq_decode_paged_attention",
     )(lengths, block_table, qg, *pools)
     return out.reshape(B, H, Rv)
+
+
+def _kq_window_paged_kernel(len_ref, rtab_ref, q_ref, sink_ref, *refs,
+                            page_size: int, window: int, scale: float):
+    pages, (o_ref,), (m_ref, l_ref, acc_ref) = _unpack_refs(refs, False, 1)
+    b = pl.program_id(0)
+    t = pl.program_id(2)
+    length = len_ref[b]
+    lo = jnp.maximum(length - window, 0)
+    start = (lo // page_size + t) * page_size
+
+    @pl.when(t == 0)
+    def _init():
+        _init_softmax(m_ref, l_ref, acc_ref)
+
+    @pl.when(start < length)
+    def _update():
+        _page_update(q_ref, pages, m_ref, l_ref, acc_ref, start=start,
+                     length=length, scale=scale, lo=lo)
+
+    @pl.when(t == pl.num_programs(2) - 1)
+    def _finish():
+        # the sink takes probability mass and gives no value: rescale
+        # to the larger of the running max and the sink, then add the
+        # sink's exp to the sum
+        sink = sink_ref[...].astype(jnp.float32)            # (hb, m, 1)
+        m = m_ref[...]
+        top = jnp.maximum(m, sink)
+        c = jnp.exp(m - top)
+        denom = jnp.maximum(l_ref[...] * c + jnp.exp(sink - top), 1e-30)
+        o_ref[0] = (acc_ref[...] * c / denom).astype(o_ref.dtype)
+
+
+def kq_window_paged_attention(qc, kc_pool, vc_pool, lengths, ring_table,
+                              *, window: int, scale: float = 1.0,
+                              sink=None, interpret: Optional[bool] = None,
+                              pad_lanes: Optional[bool] = None):
+    """Decode over a window kind's page rings (DESIGN.md §window-rings).
+
+    qc: (B, H, Rk); kc_pool/vc_pool: (P, Hkv, ps, R) ring pools;
+    ``lengths``: (B,) live positions (the newest token included);
+    ``ring_table``: (B, ring) the slot's ring pages, logical page j in
+    column ``j % ring``; ``sink``: optional (H,) sink logits.  The query
+    attends positions ``[length - window, length)``.  Grid (B, Hkv / hb,
+    ring): program t reads the window's t-th page, so a slot moves at
+    most ``ring`` pages whatever its context; pages past the newest
+    repeat its page id (no DMA) and skip the update.  Same online
+    softmax as ``kq_decode_paged_attention`` (``_page_update`` with a
+    lower bound), and the sink enters the final normalisation.
+    Returns (B, H, Rv)."""
+    if interpret is None:
+        interpret = default_interpret()
+    if (not interpret) if pad_lanes is None else pad_lanes:
+        rv = vc_pool.shape[-1]
+        if qc.shape[-1] % 128 or rv % 128:
+            out = kq_window_paged_attention(
+                pad_to_lane(qc), pad_to_lane(kc_pool),
+                pad_to_lane(vc_pool), lengths, ring_table, window=window,
+                scale=scale, sink=sink, interpret=interpret,
+                pad_lanes=False)
+            return out[..., :rv]
+    B, H, Rk = qc.shape
+    P, Hkv, ps, _ = kc_pool.shape
+    Rv = vc_pool.shape[-1]
+    m = H // Hkv
+    ring = ring_table.shape[1]
+    lengths = jnp.broadcast_to(jnp.asarray(lengths, jnp.int32), (B,))
+    ring_table = jnp.asarray(ring_table, jnp.int32)
+    if sink is None:
+        sink = jnp.full((H,), NEG_INF, jnp.float32)
+    sink = sink.astype(jnp.float32).reshape(Hkv, m, 1)
+    hb = decode_heads_per_block(Hkv, ps, Rk, Rv, kc_pool.dtype.itemsize)
+
+    def _kv_map(b, g, t, lens, rtab):
+        # the window's t-th logical page, clamped to the newest, then
+        # its ring column
+        lo = jnp.maximum(lens[b] - window, 0) // ps
+        last = jnp.maximum((lens[b] + ps - 1) // ps - 1, 0)
+        return (rtab[b, jnp.minimum(lo + t, last) % ring], g, 0, 0)
+
+    def _q_map(b, g, t, lens, rtab):
+        return (b, g, 0, 0)
+
+    kernel = functools.partial(_kq_window_paged_kernel, page_size=ps,
+                               window=window, scale=scale)
+    in_specs = _decode_in_specs(_q_map, _kv_map, hb, m, ps, Rk, Rv, False)
+    in_specs.insert(1, pl.BlockSpec((hb, m, 1),
+                                    lambda b, g, t, lens, rtab: (g, 0, 0)))
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
+        grid=(B, Hkv // hb, ring),
+        in_specs=in_specs,
+        out_specs=pl.BlockSpec((1, hb, m, Rv), _q_map),
+        scratch_shapes=_softmax_scratch(hb, m, Rv),
+    )
+    out = pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((B, Hkv, m, Rv), qc.dtype),
+        interpret=interpret,
+        name="kq_window_paged_attention",
+    )(lengths, ring_table, qc.reshape(B, Hkv, m, Rk), sink, kc_pool,
+      vc_pool)
+    return out.reshape(B, H, Rv)
+

@@ -28,14 +28,17 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro import kernels
-from repro.config import ModelConfig
+from repro.config import AttnSpec, ModelConfig
 from repro.kernels.kq_decode.kq_decode import kq_decode_attention
 from repro.kernels.kq_decode.paged import (kq_decode_paged_attention,
+                                           kq_window_paged_attention,
                                            kq_prefill_paged_attention)
 from repro.models.layers import apply_rope, init_dense
 from repro.serving.page_layouts import get_layout, quantize_int8  # noqa: F401
-from repro.serving.paged_cache import (append_chunk, append_token,
-                                       gather_pages)
+from repro.serving.paged_cache import (append_chunk, append_ring,
+                                       append_token, gather_pages,
+                                       gather_ring, ring_pages,
+                                       ring_positions)
 from repro.sharding import partition
 
 NEG_INF = -1e30
@@ -142,10 +145,21 @@ def _gqa_expand(k: jnp.ndarray, n_heads: int) -> jnp.ndarray:
     return jnp.repeat(k, m, axis=1)
 
 
+def _sink_denominator(l, m, sink):
+    """Softmax denominator with a sink logit per query head (GPT-oss
+    style: the sink takes probability mass and contributes no value).
+    l, m: (B, H, ...) running sum and max; sink: (H,) or None."""
+    if sink is None:
+        return l
+    sk = sink.astype(jnp.float32).reshape((1, -1) + (1,) * (m.ndim - 2))
+    return l + jnp.exp(sk - m)
+
+
 def reference_attention(q, k, v, *, causal=True, window=0,
                         scale: Optional[float] = None,
-                        pos0_q: int = 0):
-    """O(S^2)-memory oracle (tests + tiny shapes). q:(B,H,S,dh)."""
+                        pos0_q: int = 0, sink=None):
+    """O(S^2)-memory oracle (tests + tiny shapes). q:(B,H,S,dh);
+    ``sink``: optional (H,) sink logits."""
     B, H, Sq, dh = q.shape
     Sk = k.shape[2]
     k = _gqa_expand(k, H)
@@ -160,13 +174,15 @@ def reference_attention(q, k, v, *, causal=True, window=0,
     if window:
         mask = mask & (qpos[:, None] - kpos[None, :] < window)
     s = jnp.where(mask[None, None], s, NEG_INF)
-    p = jax.nn.softmax(s, axis=-1)
+    m = s.max(-1)
+    e = jnp.exp(s - m[..., None])
+    p = e / _sink_denominator(e.sum(-1), m, sink)[..., None]
     return jnp.einsum("bhqk,bhkd->bhqd", p.astype(v.dtype), v)
 
 
 def blockwise_attention(q, k, v, *, causal=True, window=0,
                         block_q=512, block_k=512,
-                        packed=True, scale=None):
+                        packed=True, scale=None, sink=None):
     """Flash-style blockwise attention.  q:(B,H,S,dh), k/v:(B,Hkv,S,dh).
 
     ``packed=True`` uses triangular block packing (causal only, requires
@@ -181,13 +197,13 @@ def blockwise_attention(q, k, v, *, causal=True, window=0,
     bk = min(block_k, S)
     if S % bq or S % bk:
         return reference_attention(q, k, v, causal=causal, window=window,
-                                   scale=scale)
+                                   scale=scale, sink=sink)
     if packed and causal and bq == bk:
-        return _packed_causal(q, k, v, bq, window, scale)
-    return _masked_blockwise(q, k, v, bq, bk, causal, window, scale)
+        return _packed_causal(q, k, v, bq, window, scale, sink)
+    return _masked_blockwise(q, k, v, bq, bk, causal, window, scale, sink)
 
 
-def _masked_blockwise(q, k, v, bq, bk, causal, window, scale):
+def _masked_blockwise(q, k, v, bq, bk, causal, window, scale, sink=None):
     B, H, S, dh = q.shape
     dv = v.shape[-1]
     Nq, Nk = S // bq, S // bk
@@ -225,13 +241,14 @@ def _masked_blockwise(q, k, v, bq, bk, causal, window, scale):
         a0 = jnp.zeros((B, H, bq, dv), jnp.float32)
         (m, l, acc), _ = jax.lax.scan(kv_step, (m0, l0, a0),
                                       jnp.arange(Nk))
+        l = _sink_denominator(l, m, sink)
         return (acc / jnp.maximum(l, 1e-30)[..., None]).astype(q.dtype)
 
     out = jax.lax.map(q_block, jnp.arange(Nq))              # (Nq,B,H,bq,dv)
     return out.transpose(1, 2, 0, 3, 4).reshape(B, H, S, dv)
 
 
-def _packed_causal(q, k, v, b, window, scale):
+def _packed_causal(q, k, v, b, window, scale, sink=None):
     """Triangular block packing: scan over exactly the needed pairs."""
     B, H, S, dh = q.shape
     dv = v.shape[-1]
@@ -278,6 +295,7 @@ def _packed_causal(q, k, v, b, window, scale):
     l0 = jnp.zeros((B, H, N, b), jnp.float32)
     a0 = jnp.zeros((B, H, N, b, dv), jnp.float32)
     (m, l, acc), _ = jax.lax.scan(step, (m0, l0, a0), (qi_arr, kj_arr))
+    l = _sink_denominator(l, m, sink)
     out = acc / jnp.maximum(l, 1e-30)[..., None]
     return out.astype(q.dtype).reshape(B, H, S, dv)
 
@@ -368,6 +386,124 @@ def chunk_decode_attention(qg, cache_k, cache_v, qpos, scale):
                       cache_v)                              # (B,Hkv,m,S,rv)
 
 
+def window_attention(qg, keys, vals, kpos, kvalid, qpos, window: int,
+                     scale: float, sink=None):
+    """Queries over a window of keys whose positions are given (the lax
+    path of the window rings, DESIGN.md §window-rings).
+
+    qg: (B, Hkv, m, S, Rk) at positions ``qpos`` (B, S); keys/vals:
+    (B, Hkv, T, R) at positions ``kpos`` (B, T), entries with ``kvalid``
+    False ignored; query s attends keys with ``qpos - window < kpos <=
+    qpos``.  ``sink``: optional (Hkv * m,) sink logits.  Returns
+    (B, Hkv, m, S, Rv) f32."""
+    B, Hkv, m = qg.shape[:3]
+    s = jnp.einsum("bgmsr,bgtr->bgmst", qg.astype(jnp.float32),
+                   keys.astype(jnp.float32)) * scale
+    d = qpos[:, :, None] - kpos[:, None, :]                 # (B, S, T)
+    ok = kvalid[:, None, :] & (d >= 0) & (d < window)
+    s = jnp.where(ok[:, None, None], s, NEG_INF)
+    mx = s.max(-1)                                          # (B,Hkv,m,S)
+    if sink is not None:
+        sk = sink.astype(jnp.float32).reshape(1, Hkv, m, 1)
+        mx = jnp.maximum(mx, sk)
+    e = jnp.exp(s - mx[..., None])
+    den = e.sum(-1)
+    if sink is not None:
+        den = den + jnp.exp(sk - mx)
+    out = jnp.einsum("bgmst,bgtr->bgmsr", e, vals.astype(jnp.float32))
+    return out / jnp.maximum(den, 1e-30)[..., None]
+
+
+def _split_table(cfg: ModelConfig, block_table, ps: int):
+    """(context columns, ring columns) of a block table: the rows end in
+    the window ring's columns when the model has a ringed kind."""
+    w = cfg.ring_window
+    if not w:
+        return block_table, None
+    n = ring_pages(w, ps)
+    return block_table[:, :-n], block_table[:, -n:]
+
+
+def _compress_kv(k, v, proj):
+    """Keys and values of new tokens as the cache stores them."""
+    if proj is None:
+        return k, v
+    return (jnp.einsum("bhtd,hdr->bhtr", k, proj["a_k"]),
+            jnp.einsum("bhtd,hdr->bhtr", v, proj["a_v"]))
+
+
+def _attn_out(p, agg, cfg: ModelConfig, Hkv: int, proj):
+    """Group aggregates (B, Hkv, m_p, S, R) -> (B, S, D): through C_v
+    with projections, else through W^O."""
+    B, S = agg.shape[0], agg.shape[3]
+    if proj is not None:
+        m = cfg.n_heads // Hkv                  # real heads (c_v is real-m)
+        c_v = proj["c_v"].reshape(Hkv, -1, m, cfg.d_model)
+        return jnp.einsum("bgmsr,grmd->bsd", agg[:, :, :m], c_v)
+    out = agg.reshape(B, padded_heads(cfg), S, agg.shape[-1])
+    return jnp.einsum("bhse,hed->bsd", out, p["wo"])
+
+
+def _window_step(p, x, cache, pos0, cfg: ModelConfig, spec: AttnSpec,
+                 proj, ring_table, valid, decode: bool):
+    """A windowed kind's prefill chunk or decode token over its per-slot
+    page ring (DESIGN.md §window-rings).
+
+    Prefill: the chunk's queries attend the ring as it stands (positions
+    before ``pos0``) and the chunk's own new entries, so a chunk longer
+    than the window attends across its boundary exactly; then the
+    chunk's newest entries are written into the ring.  Decode: the
+    token is written first, then attends the ring (on TPU the
+    ``kq_window_paged_attention`` kernel, which reads at most
+    ``ring_pages`` pages a slot)."""
+    B, S, _ = x.shape
+    Hkv, dh = spec.n_kv_heads, spec.d_qk
+    m_p = padded_heads(cfg) // Hkv
+    scale = 1.0 / math.sqrt(dh)
+    positions = pos0[:, None] + jnp.arange(S)[None, :]       # (B, S)
+    q, k_new, v_new = _qkv(p, x, cfg, positions[:, None, :], spec)
+    kname, vname = ("kc", "vc") if proj is not None else ("k", "v")
+    k_st, v_st = _compress_kv(k_new, v_new, proj)
+    qg = q.reshape(B, Hkv, m_p, S, dh)
+    if proj is not None:
+        qg = jnp.einsum("bgmsd,gdr->bgmsr", qg, proj["b_q"])
+    sink = p.get("sink")
+    kp, vp = cache[kname], cache[vname]
+    n = ring_table.shape[1] * kp.shape[2]                   # ring entries
+    if decode:
+        kp = append_ring(kp, ring_table, pos0, k_st, valid)
+        vp = append_ring(vp, ring_table, pos0, v_st, valid)
+        if kernels.use_kernels():
+            agg = kq_window_paged_attention(
+                qg.reshape(B, Hkv * m_p, -1), kp, vp, pos0 + 1,
+                ring_table, window=spec.window, scale=scale,
+                sink=sink).reshape(B, Hkv, m_p, 1, -1)
+        else:
+            kpos = ring_positions(pos0, n)
+            agg = window_attention(qg, gather_ring(kp, ring_table),
+                                   gather_ring(vp, ring_table), kpos,
+                                   kpos >= 0, positions, spec.window,
+                                   scale, sink)
+    else:
+        if valid.ndim == 1:
+            valid = jnp.arange(S)[None, :] < valid[:, None]
+        rpos = ring_positions(pos0 - 1, n)
+        keys = jnp.concatenate([gather_ring(kp, ring_table),
+                                k_st.astype(kp.dtype)], axis=2)
+        vals = jnp.concatenate([gather_ring(vp, ring_table),
+                                v_st.astype(vp.dtype)], axis=2)
+        agg = window_attention(qg, keys, vals,
+                               jnp.concatenate([rpos, positions], 1),
+                               jnp.concatenate([rpos >= 0, valid], 1),
+                               positions, spec.window, scale, sink)
+        kp = append_ring(kp, ring_table, pos0, k_st, valid)
+        vp = append_ring(vp, ring_table, pos0, v_st, valid)
+    new_cache = dict(cache)
+    new_cache[kname], new_cache[vname] = kp, vp
+    y = _attn_out(p, agg.astype(x.dtype), cfg, Hkv, proj)
+    return y.astype(x.dtype), new_cache
+
+
 # ---------------------------------------------------------------------------
 # Attention layer (params + modes)
 # ---------------------------------------------------------------------------
@@ -395,18 +531,22 @@ def head_mask(cfg: ModelConfig) -> Optional[jnp.ndarray]:
     return mask.astype(jnp.float32)
 
 
-def init_attention(key, cfg: ModelConfig, dtype) -> Dict[str, jnp.ndarray]:
+def init_attention(key, cfg: ModelConfig, dtype,
+                   spec: Optional[AttnSpec] = None) -> Dict[str, jnp.ndarray]:
     """Init q/k/v/o projections (pad query heads zeroed, see
-    ``head_mask``)."""
-    D, Hkv, dh = cfg.d_model, cfg.n_kv_heads, cfg.d_head
+    ``head_mask``), and the per-head sink logits of a sink kind."""
+    spec = spec or cfg.attn_spec()
+    D, Hkv, dh, dv = cfg.d_model, spec.n_kv_heads, spec.d_qk, spec.d_v
     Hp = padded_heads(cfg)
     k1, k2, k3, k4 = jax.random.split(key, 4)
     p = {
         "wq": init_dense(k1, (D, Hp, dh), D, dtype),
         "wk": init_dense(k2, (D, Hkv, dh), D, dtype),
-        "wv": init_dense(k3, (D, Hkv, dh), D, dtype),
-        "wo": init_dense(k4, (Hp, dh, D), Hp * dh, dtype),
+        "wv": init_dense(k3, (D, Hkv, dv), D, dtype),
+        "wo": init_dense(k4, (Hp, dv, D), Hp * dv, dtype),
     }
+    if spec.sink:
+        p["sink"] = jnp.zeros((Hp,), jnp.float32)
     mask = head_mask(cfg)
     if mask is not None:
         p["wq"] = p["wq"] * mask[None, :, None].astype(dtype)
@@ -414,41 +554,60 @@ def init_attention(key, cfg: ModelConfig, dtype) -> Dict[str, jnp.ndarray]:
     return p
 
 
-def _qkv(p, x, cfg: ModelConfig, positions):
-    """Project + rope.  x: (B,S,D) -> q (B,H,S,dh), k/v (B,Hkv,S,dh)."""
+def _rope_part(x, positions, spec: AttnSpec):
+    """RoPE on the leading ``spec.rotary_dim`` dims of x (all of them
+    unless the kind rotates part of each head)."""
+    r = spec.rotary_dim
+    if r == x.shape[-1]:
+        return apply_rope(x, positions, spec.rope_theta)
+    return jnp.concatenate([apply_rope(x[..., :r], positions,
+                                       spec.rope_theta), x[..., r:]], -1)
+
+
+def _qkv(p, x, cfg: ModelConfig, positions, spec: Optional[AttnSpec] = None):
+    """Project + rope.  x: (B,S,D) -> q (B,H,S,dqk), k (B,Hkv,S,dqk),
+    v (B,Hkv,S,dv), the values times the kind's value scale."""
+    spec = spec or cfg.attn_spec()
     q = jnp.einsum("bsd,dhe->bhse", x, p["wq"])
     k = jnp.einsum("bsd,dhe->bhse", x, p["wk"])
     v = jnp.einsum("bsd,dhe->bhse", x, p["wv"])
-    q = apply_rope(q, positions, cfg.rope_theta)
-    k = apply_rope(k, positions, cfg.rope_theta)
+    if spec.value_scale != 1.0:
+        v = v * jnp.asarray(spec.value_scale, v.dtype)
+    q = _rope_part(q, positions, spec)
+    k = _rope_part(k, positions, spec)
     return q, k, v
 
 
-def attn_train(p, x, cfg: ModelConfig, pos0: int = 0) -> jnp.ndarray:
+def attn_train(p, x, cfg: ModelConfig, pos0: int = 0,
+               spec: Optional[AttnSpec] = None) -> jnp.ndarray:
     """Full-sequence causal attention (training / no-cache path)."""
+    spec = spec or cfg.attn_spec()
     S = x.shape[1]
     positions = jnp.arange(S) + pos0
-    q, k, v = _qkv(p, x, cfg, positions)
+    q, k, v = _qkv(p, x, cfg, positions, spec)
     out = blockwise_attention(
-        q, k, v, causal=True, window=cfg.sliding_window,
+        q, k, v, causal=True, window=spec.window,
         block_q=cfg.attn_block_q, block_k=cfg.attn_block_k,
-        packed=cfg.causal_block_skip)
+        packed=cfg.causal_block_skip, sink=p.get("sink"))
     mask = head_mask(cfg)
     if mask is not None:    # zero pad-head outputs => their grads stay 0
         out = out * mask[None, :, None, None].astype(out.dtype)
     return jnp.einsum("bhse,hed->bsd", out, p["wo"])
 
 
-def attn_calibrate(p, x, cfg: ModelConfig) -> Tuple[jnp.ndarray, Dict]:
+def attn_calibrate(p, x, cfg: ModelConfig,
+                   spec: Optional[AttnSpec] = None
+                   ) -> Tuple[jnp.ndarray, Dict]:
     """``attn_train`` plus captured q/k/v tensors for the KQ-SVD
     calibration pass (pad query heads excluded from the captures)."""
+    spec = spec or cfg.attn_spec()
     S = x.shape[1]
     positions = jnp.arange(S)
-    q, k, v = _qkv(p, x, cfg, positions)
+    q, k, v = _qkv(p, x, cfg, positions, spec)
     out = blockwise_attention(
-        q, k, v, causal=True, window=cfg.sliding_window,
+        q, k, v, causal=True, window=spec.window,
         block_q=cfg.attn_block_q, block_k=cfg.attn_block_k,
-        packed=cfg.causal_block_skip)
+        packed=cfg.causal_block_skip, sink=p.get("sink"))
     y = jnp.einsum("bhse,hed->bsd", out, p["wo"])
     if padded_heads(cfg) != cfg.n_heads:     # drop pad heads from stats
         Hkv = cfg.n_kv_heads
@@ -461,14 +620,15 @@ def attn_calibrate(p, x, cfg: ModelConfig) -> Tuple[jnp.ndarray, Dict]:
     return y, captures
 
 
-def group_output_weights(p, cfg: ModelConfig) -> np.ndarray:
-    """W^O stacked per kv group: (Hkv, dh, m*D) for the value-path solve.
+def group_output_weights(p, cfg: ModelConfig,
+                         spec: Optional[AttnSpec] = None) -> np.ndarray:
+    """W^O stacked per kv group: (Hkv, dv, m*D) for the value-path solve.
 
     Pad query heads (qhead_pad) are excluded: their weights are zero and
     their caches do not exist."""
-    wo = np.asarray(p["wo"], np.float64)                     # (Hp, dh, D)
+    wo = np.asarray(p["wo"], np.float64)                     # (Hp, dv, D)
     Hp, dh, D = wo.shape
-    Hkv = cfg.n_kv_heads
+    Hkv = (spec or cfg.attn_spec()).n_kv_heads
     m = cfg.n_heads // Hkv
     m_p = Hp // Hkv
     wo = wo.reshape(Hkv, m_p, dh, D)[:, :m]
@@ -477,7 +637,7 @@ def group_output_weights(p, cfg: ModelConfig) -> np.ndarray:
 
 def make_attn_cache(cfg: ModelConfig, batch: int, max_len: int,
                     proj_rank: Tuple[int, int] = (0, 0), dtype=jnp.bfloat16,
-                    paged: bool = False):
+                    paged: bool = False, spec: Optional[AttnSpec] = None):
     """Empty cache pytree for one attention layer.
 
     ``paged=True`` reinterprets (batch, max_len) as (pages, page_size)
@@ -485,9 +645,12 @@ def make_attn_cache(cfg: ModelConfig, batch: int, max_len: int,
     selects (DESIGN.md §page-layouts): fp data pages for ``FpLayout``
     (bit-identical to the dense leaf shapes), int8/packed data pages
     plus width-1 bf16 scale pools for the quantized layouts."""
-    W = cfg.sliding_window or 0
+    spec = spec or cfg.attn_spec()
+    # a paged window kind holds a ring of whole pages (DESIGN.md
+    # §window-rings), never a per-slot window lane
+    W = 0 if (paged and cfg.attn_kinds is not None) else spec.window
     T = min(max_len, W) if W else max_len
-    Hkv = cfg.n_kv_heads
+    Hkv = spec.n_kv_heads
     rk, rv = proj_rank
     if paged and rk:
         layout = get_layout(cfg)
@@ -506,8 +669,8 @@ def make_attn_cache(cfg: ModelConfig, batch: int, max_len: int,
             cache["kscale"] = jnp.zeros((batch, Hkv, T), jnp.bfloat16)
             cache["vscale"] = jnp.zeros((batch, Hkv, T), jnp.bfloat16)
     else:
-        cache = {"k": jnp.zeros((batch, Hkv, T, cfg.d_head), dtype),
-                 "v": jnp.zeros((batch, Hkv, T, cfg.d_head), dtype)}
+        cache = {"k": jnp.zeros((batch, Hkv, T, spec.d_qk), dtype),
+                 "v": jnp.zeros((batch, Hkv, T, spec.d_v), dtype)}
     if W:
         cache["slot_pos"] = jnp.full((batch, T), -1, jnp.int32)
     return cache
@@ -562,7 +725,7 @@ def attn_prefill(p, x, cfg: ModelConfig, max_len: int,
 
 def attn_prefill_chunk(p, x, cache: Dict, pos0, cfg: ModelConfig,
                        proj: Optional[Dict] = None, block_table=None,
-                       valid=None):
+                       valid=None, spec: Optional[AttnSpec] = None):
     """One bucket-padded prompt chunk straight into pages (DESIGN.md
     §prefill).
 
@@ -578,7 +741,9 @@ def attn_prefill_chunk(p, x, cache: Dict, pos0, cfg: ModelConfig,
     paged cache; the exact-length ``attn_prefill`` + dense staging is
     the parity oracle.  Padded queries produce garbage rows: isolated
     (attention rows are independent, MoE masks them via ``valid``) and
-    sliced away by the caller.
+    sliced away by the caller.  A windowed kind of a model with
+    per-layer kinds (``spec``) runs over its page ring instead
+    (``_window_step``).
     """
     if block_table is None:
         raise ValueError("attn_prefill_chunk requires a paged cache "
@@ -587,6 +752,7 @@ def attn_prefill_chunk(p, x, cache: Dict, pos0, cfg: ModelConfig,
         raise NotImplementedError(
             "chunked prefill supports full-attention stacks only "
             "(no sliding window)")
+    spec = spec or cfg.attn_spec()
     B, S, _ = x.shape
     # slot-axis sharding constraint (DESIGN.md §sharded-engine): a
     # no-op without an active mesh — the sharded engine dispatches via
@@ -594,22 +760,42 @@ def attn_prefill_chunk(p, x, cache: Dict, pos0, cfg: ModelConfig,
     # under an active data mesh (pjit serving flows) it pins the
     # chunk's batch axis in place so GSPMD cannot gather it.
     x = partition.shard(x, ("pod", "data"), None, None)
-    dh = cfg.d_head
+    dh = spec.d_qk
     scale = 1.0 / math.sqrt(dh)
     pos0 = batched_positions(pos0, B)
     if valid is None:
         valid = jnp.ones((B, S), bool)
+    ps = cache["kc" if proj is not None else "k"].shape[2]
+    block_table, ring_table = _split_table(cfg, block_table, ps)
+    if spec.window:
+        with jax.named_scope("attn_window"):
+            return _window_step(p, x, cache, pos0, cfg, spec, proj,
+                                ring_table, valid, decode=False)
+    if spec.sink:
+        raise NotImplementedError("a sink on a full-attention kind is "
+                                  "not served (the paged kernels have "
+                                  "no sink)")
+    with jax.named_scope("attn_full"):
+        return _full_chunk(p, x, cache, pos0, cfg, spec, proj,
+                           block_table, valid, scale)
+
+
+def _full_chunk(p, x, cache, pos0, cfg, spec, proj, block_table, valid,
+                scale):
+    """``attn_prefill_chunk`` of a full-attention layer."""
+    B, S, _ = x.shape
+    dh = spec.d_qk
     # cache writes take either form; the count form stays counts so
     # the paged-store primitive exercises its own truncation contract
     wvalid = valid
     if valid.ndim == 1:
         valid = jnp.arange(S)[None, :] < valid[:, None]      # (B, S)
     positions = pos0[:, None] + jnp.arange(S)[None, :]       # (B, S)
-    q, k_new, v_new = _qkv(p, x, cfg, positions[:, None, :])
+    q, k_new, v_new = _qkv(p, x, cfg, positions[:, None, :], spec)
     T = block_table.shape[1] * cache[
         "kc" if proj is not None else "k"].shape[2]
     lengths = pos0 + valid.sum(axis=1).astype(jnp.int32)
-    Hkv = cfg.n_kv_heads
+    Hkv = spec.n_kv_heads
     Hp = padded_heads(cfg)
     m_p = Hp // Hkv
     qg = q.reshape(B, Hkv, m_p, S, dh)
@@ -664,19 +850,13 @@ def attn_prefill_chunk(p, x, cache: Dict, pos0, cfg: ModelConfig,
         agg = chunk_decode_attention(qg, gather_pages(kc, block_table),
                                      gather_pages(vc, block_table),
                                      positions, scale)
-    if proj is not None:
-        m = cfg.n_heads // Hkv                  # real heads (c_v is real-m)
-        c_v = proj["c_v"].reshape(Hkv, -1, m, cfg.d_model)
-        y = jnp.einsum("bgmsr,grmd->bsd", agg[:, :, :m], c_v)
-    else:
-        out = agg.reshape(B, Hp, S, dh)
-        y = jnp.einsum("bhse,hed->bsd", out, p["wo"])
+    y = _attn_out(p, agg, cfg, Hkv, proj)
     return y.astype(x.dtype), new_cache
 
 
 def attn_decode(p, x, cache: Dict, pos, cfg: ModelConfig,
                 proj: Optional[Dict] = None, block_table=None,
-                num_splits: int = 1):
+                num_splits: int = 1, spec: Optional[AttnSpec] = None):
     """One-token decode.  x: (B,1,D); pos: (B,) per-sequence index of the
     new token (a scalar broadcasts — legacy lock-step batches).
 
@@ -690,17 +870,44 @@ def attn_decode(p, x, cache: Dict, pos, cfg: ModelConfig,
     ``num_splits`` (static, paged only) selects split-KV flash-decoding
     (DESIGN.md §split-kv): the Pallas path passes it to the paged
     kernel, the lax path routes through ``split_decode_attention``; 1
-    is the unsplit parity oracle."""
+    is the unsplit parity oracle.  A windowed kind of a model with
+    per-layer kinds (``spec``) decodes over its page ring
+    (``_window_step``)."""
     B = x.shape[0]
     # slot-axis sharding constraint (DESIGN.md §sharded-engine): no-op
     # without an active mesh; under one it keeps the decode batch axis
     # device-local (no gathers on the hot path)
     x = partition.shard(x, ("pod", "data"), None, None)
-    dh = cfg.d_head
-    scale = 1.0 / math.sqrt(dh)
+    spec = spec or cfg.attn_spec()
     pos = batched_positions(pos, B)
-    q, k_new, v_new = _qkv(p, x, cfg, pos[:, None, None])   # S=1
-    W = cfg.sliding_window or 0
+    paged = block_table is not None
+    if paged and cfg.attn_kinds is not None:
+        ps = cache["kc" if proj is not None else "k"].shape[2]
+        block_table, ring_table = _split_table(cfg, block_table, ps)
+        if spec.window:
+            with jax.named_scope("attn_window"):
+                return _window_step(p, x, cache, pos, cfg, spec, proj,
+                                    ring_table, jnp.ones((B, 1), bool),
+                                    decode=True)
+        if spec.sink:
+            raise NotImplementedError("a sink on a full-attention kind "
+                                      "is not served")
+        with jax.named_scope("attn_full"):
+            return _decode_core(p, x, cache, pos, cfg, proj, block_table,
+                                num_splits, spec)
+    return _decode_core(p, x, cache, pos, cfg, proj, block_table,
+                        num_splits, spec)
+
+
+def _decode_core(p, x, cache, pos, cfg, proj, block_table, num_splits,
+                 spec: AttnSpec):
+    """``attn_decode`` of a full-attention (or dense sliding-window)
+    layer."""
+    B = x.shape[0]
+    dh = spec.d_qk
+    scale = 1.0 / math.sqrt(dh)
+    q, k_new, v_new = _qkv(p, x, cfg, pos[:, None, None], spec)   # S=1
+    W = spec.window
     paged = block_table is not None
     layout = get_layout(cfg) if paged else None
     quant = paged and proj is not None and layout.kernel != "fp"
@@ -744,7 +951,7 @@ def attn_decode(p, x, cache: Dict, pos, cfg: ModelConfig,
             new_cache["vscale"] = scatter_time(
                 cache["vscale"], vs_new.astype(jnp.bfloat16), slot)
         # compress query with the group's B factor
-        Hkv = cfg.n_kv_heads
+        Hkv = spec.n_kv_heads
         Hp = padded_heads(cfg)
         m_p = Hp // Hkv
         qg = q.reshape(B, Hkv, m_p, dh)
@@ -769,7 +976,7 @@ def attn_decode(p, x, cache: Dict, pos, cfg: ModelConfig,
     else:
         valid = jnp.arange(T)[None, :] <= pos[:, None]      # (B, T)
     if proj is not None and cfg.cache_quant == "int8" and not paged:
-        Hkv = cfg.n_kv_heads
+        Hkv = spec.n_kv_heads
         m = padded_heads(cfg) // Hkv
         agg = int8_decode_attention(
             qq.reshape(B, Hkv, m, -1), keys, vals, new_cache["kscale"],
@@ -779,7 +986,7 @@ def attn_decode(p, x, cache: Dict, pos, cfg: ModelConfig,
         # dequantizes on the fly from int8 pages + scale pools (unsplit
         # and split-KV variants); the lax twin runs the same
         # dot-then-scale math on gathered pages
-        Hkv = cfg.n_kv_heads
+        Hkv = spec.n_kv_heads
         if kernels.use_kernels():
             agg = kq_decode_paged_attention(
                 qq.reshape(B, -1, qq.shape[-1]), keys, vals, pos + 1,
@@ -819,7 +1026,7 @@ def attn_decode(p, x, cache: Dict, pos, cfg: ModelConfig,
     elif paged and kernels.use_kernels():
         # TPU path, paged: the kernel dereferences the block table via
         # scalar prefetch — no page gather is materialized
-        Hkv = cfg.n_kv_heads
+        Hkv = spec.n_kv_heads
         agg = kq_decode_paged_attention(
             qq.reshape(B, -1, qq.shape[-1]), keys, vals, pos + 1,
             block_table, scale=scale, max_len=T,
@@ -839,19 +1046,13 @@ def attn_decode(p, x, cache: Dict, pos, cfg: ModelConfig,
     elif kernels.use_kernels() and not W:
         # TPU path, dense: the Pallas kernel streams the cache with
         # per-sequence lengths
-        Hkv = cfg.n_kv_heads
+        Hkv = spec.n_kv_heads
         agg = kq_decode_attention(
             qq.reshape(B, -1, qq.shape[-1]), keys, vals, pos + 1,
             scale=scale, max_len=T).reshape(B, Hkv, -1, vals.shape[-1])
     else:
         agg = decode_attention(qq, keys, vals, valid, scale)  # (B,Hkv,m,rv)
-    if proj is not None:
-        Hkv = cfg.n_kv_heads
-        m = cfg.n_heads // Hkv                  # real heads (c_v is real-m)
-        D = cfg.d_model
-        c_v = proj["c_v"].reshape(Hkv, -1, m, D)
-        y = jnp.einsum("bgmr,grmd->bd", agg[:, :, :m], c_v)[:, None, :]
-    else:
-        out = agg.reshape(B, padded_heads(cfg), dh)
-        y = jnp.einsum("bhe,hed->bd", out, p["wo"])[:, None, :]
+    Hkv = spec.n_kv_heads
+    y = _attn_out(p, agg.reshape(B, Hkv, -1, 1, agg.shape[-1]), cfg, Hkv,
+                  proj)
     return y.astype(x.dtype), new_cache

@@ -22,7 +22,7 @@ from repro.models import attention as attn_mod
 from repro.models import mla as mla_mod
 from repro.models import ssm as ssm_mod
 from repro.models.layers import init_rms, init_swiglu, rms_norm, swiglu
-from repro.models.moe import init_moe, moe_ffn
+from repro.models.moe import held_moe_ffn, init_moe, moe_ffn
 from repro.sharding.partition import shard
 
 
@@ -31,29 +31,50 @@ from repro.sharding.partition import shard
 # ---------------------------------------------------------------------------
 
 
+def _signature(cfg: ModelConfig, layer_idx: int):
+    """What makes two layers structurally identical (same pytree)."""
+    kind = cfg.layer_kinds()[layer_idx]
+    if cfg.attn_kinds is not None and kind == "attn":
+        kind = ("attn", cfg.attn_kinds.pattern[layer_idx])
+    return kind, cfg.ffn_kind(layer_idx)
+
+
 def step_layout(cfg: ModelConfig) -> Tuple[List[int], List[List[int]]]:
-    """(prefix_layer_ids, steps) where each step is a list of layer ids."""
-    prefix = []
-    if cfg.moe is not None and cfg.moe.first_k_dense:
-        prefix = list(range(cfg.moe.first_k_dense))
-    body = [i for i in range(cfg.n_layers) if i not in prefix]
-    period = cfg.hybrid.period if cfg.hybrid is not None else 1
-    assert len(body) % period == 0, (cfg.name, len(body), period)
-    steps = [body[i: i + period] for i in range(0, len(body), period)]
-    # every step must be structurally identical
-    sig0 = [(cfg.layer_kinds()[l], cfg.ffn_kind(l)) for l in steps[0]]
-    for st in steps[1:]:
-        sig = [(cfg.layer_kinds()[l], cfg.ffn_kind(l)) for l in st]
-        assert sig == sig0, f"inhomogeneous steps in {cfg.name}"
-    return prefix, steps
+    """(prefix_layer_ids, steps) where each step is a list of layer ids.
+
+    The unrolled prefix (at least the ``moe.first_k_dense`` layers) and
+    the period of the scanned steps are the shortest pair, fewest layers
+    traced (prefix plus one period), whose steps are structurally
+    identical: a plain stack scans every layer past its dense ones one
+    at a time, a hybrid its repeating period, and MiMo's irregular first
+    period (``F S S S S F``) runs unrolled before its ``S S S S S F``
+    periods are scanned.  A body of one step runs unrolled as well: a
+    scan over it buys nothing and would need its leaves stacked (the
+    12-layer MiMo benchmark cut)."""
+    first = (cfg.moe.first_k_dense
+             if cfg.moe is not None and cfg.moe.first_k_dense else 0)
+    n = cfg.n_layers
+    sig = [_signature(cfg, i) for i in range(n)]
+    best = None
+    for k in range(first, n):
+        body = n - k
+        for p in range(1, body + 1):
+            if body % p == 0 and all(sig[i] == sig[i + p]
+                                     for i in range(k, n - p)):
+                if best is None or k + p < sum(best):
+                    best = (k, p)
+                break
+    k, period = best
+    if n - k == period:
+        k = n
+    return list(range(k)), [list(range(i, i + period))
+                            for i in range(k, n, period)]
 
 
-def attn_sublayer_index(cfg: ModelConfig, step: List[int]) -> Optional[int]:
-    """Index within the step of its (single) attention-ish sublayer."""
-    idxs = [j for j, l in enumerate(step)
+def attn_sublayer_indices(cfg: ModelConfig, step: List[int]) -> List[int]:
+    """Indices within the step of its attention-ish sublayers."""
+    return [j for j, l in enumerate(step)
             if cfg.layer_kinds()[l] in ("attn", "mla")]
-    assert len(idxs) <= 1, "at most one attention layer per scan step"
-    return idxs[0] if idxs else None
 
 
 # ---------------------------------------------------------------------------
@@ -69,7 +90,8 @@ def init_layer(key, cfg: ModelConfig, layer_idx: int, dtype) -> Dict:
     k1, k2, k3 = jax.random.split(key, 3)
     p: Dict[str, Any] = {"ln1": init_rms(cfg.d_model, dtype)}
     if kind == "attn":
-        p["attn"] = attn_mod.init_attention(k1, cfg, dtype)
+        p["attn"] = attn_mod.init_attention(k1, cfg, dtype,
+                                            cfg.attn_spec(layer_idx))
     elif kind == "mla":
         p["attn"] = mla_mod.init_mla(k1, cfg, dtype)
     else:
@@ -78,7 +100,8 @@ def init_layer(key, cfg: ModelConfig, layer_idx: int, dtype) -> Dict:
     if has_ffn:
         p["ln2"] = init_rms(cfg.d_model, dtype)
         if fk == "moe":
-            p["ffn"] = init_moe(k2, cfg.d_model, cfg.moe, dtype)
+            p["ffn"] = init_moe(k2, cfg.d_model, cfg.moe, dtype,
+                                cfg.routing)
         else:
             ff = cfg.d_ff
             if cfg.moe is not None and layer_idx < cfg.moe.first_k_dense:
@@ -99,7 +122,10 @@ def _ffn_apply(p: Dict, x, cfg: ModelConfig, layer_idx: int, mode: str,
     if "ffn" not in p:
         return x, aux
     h = rms_norm(x, p["ln2"], cfg.rms_eps)
-    if cfg.ffn_kind(layer_idx) == "moe":
+    if cfg.ffn_kind(layer_idx) == "moe" and cfg.routing is not None:
+        y, aux = held_moe_ffn(p["ffn"], h, cfg.moe, cfg.routing,
+                              token_mask=token_mask)
+    elif cfg.ffn_kind(layer_idx) == "moe":
         y, aux = moe_ffn(p["ffn"], h, cfg.moe, mode,
                          token_mask=token_mask)
     else:
@@ -127,21 +153,27 @@ def apply_layer(p: Dict, x, cfg: ModelConfig, layer_idx: int, mode: str,
             f"paged cache / chunked prefill supports plain attention "
             f"layers only (got {kind})")
     if kind == "attn":
+        spec = cfg.attn_spec(layer_idx)
+        if (cfg.attn_kinds is not None and mode in ("prefill", "decode")
+                and block_table is None):
+            raise NotImplementedError(
+                "per-layer attention kinds are served from the paged "
+                "cache only (sinks and window rings have no dense path)")
         if mode == "train":
-            y = attn_mod.attn_train(p["attn"], h, cfg)
+            y = attn_mod.attn_train(p["attn"], h, cfg, spec=spec)
         elif mode == "calibrate":
-            y, captures = attn_mod.attn_calibrate(p["attn"], h, cfg)
+            y, captures = attn_mod.attn_calibrate(p["attn"], h, cfg, spec)
         elif mode == "prefill":
             y, new_cache = attn_mod.attn_prefill(p["attn"], h, cfg,
                                                  max_len, proj)
         elif mode == "chunk":
             y, new_cache = attn_mod.attn_prefill_chunk(
                 p["attn"], h, cache, pos, cfg, proj, block_table,
-                valid=token_mask)
+                valid=token_mask, spec=spec)
         else:
             y, new_cache = attn_mod.attn_decode(p["attn"], h, cache, pos,
                                                 cfg, proj, block_table,
-                                                num_splits)
+                                                num_splits, spec=spec)
     elif kind == "mla":
         if mode == "train":
             y = mla_mod.mla_train(p["attn"], h, cfg)
@@ -179,13 +211,20 @@ def apply_layer(p: Dict, x, cfg: ModelConfig, layer_idx: int, mode: str,
 
 def init_layer_cache(cfg: ModelConfig, layer_idx: int, batch: int,
                      max_len: int, ranks: Tuple[int, int], dtype,
-                     paged: bool = False):
+                     paged: bool = False, ring_slots: int = 0):
     """Empty decode-cache pytree for one layer (``paged``: pool leaves
-    built from the configured page layout; attention layers only)."""
+    built from the configured page layout; attention layers only).  A
+    windowed layer of a model with per-layer kinds holds a ring of
+    pages per slot instead (``ring_slots`` slots plus the garbage page;
+    DESIGN.md §window-rings), whatever the context."""
     kind = cfg.layer_kinds()[layer_idx]
     if kind == "attn":
+        spec = cfg.attn_spec(layer_idx)
+        if paged and cfg.attn_kinds is not None and spec.window:
+            batch = 1 + ring_slots * attn_mod.ring_pages(spec.window,
+                                                         max_len)
         return attn_mod.make_attn_cache(cfg, batch, max_len, ranks, dtype,
-                                        paged)
+                                        paged, spec)
     if kind == "mla":
         return mla_mod.make_mla_cache(cfg, batch, max_len, ranks, dtype)
     return ssm_mod.make_ssm_state(cfg.ssm, cfg.d_model, batch, dtype)

@@ -31,12 +31,16 @@ from repro.config import ModelConfig
 from repro.core.calibration import ModelProjections
 from repro.models import attention as attn_mod
 from repro.models import mla as mla_mod
-from repro.models.blocks import (apply_layer, attn_sublayer_index,
+from repro.models.blocks import (apply_layer, attn_sublayer_indices,
                                  init_layer, init_layer_cache, step_layout)
 from repro.models.layers import dtype_of, init_rms, rms_norm
 from repro.sharding.partition import shard
 
-AUX_KEYS = ("load_balance", "router_z", "dropped_frac")
+#: summed over layers; ``held_pairs`` counts (token, held expert) pairs
+#: of the held-experts MoE layers (``moe.held_moe_ffn``), and
+#: ``held_experts`` the held experts those pairs touch
+AUX_KEYS = ("load_balance", "router_z", "dropped_frac", "held_pairs",
+            "held_experts")
 
 
 def _zero_aux() -> Dict[str, jnp.ndarray]:
@@ -65,8 +69,11 @@ class LM:
         self.dtype = dtype_of(cfg.dtype)
         self.prefix, self.steps = step_layout(cfg)
         self.step_template = self.steps[0] if self.steps else []
-        self.attn_j = (attn_sublayer_index(cfg, self.step_template)
-                       if self.steps else None)
+        # attention sublayers of a step: with more than one (MiMo's
+        # S S S S S F) the step's projections are a tuple, one per
+        # sublayer, else the sublayer's own dict
+        self.attn_js = (attn_sublayer_indices(cfg, self.step_template)
+                        if self.steps else [])
         # ordered list of attention layer ids (for projections/calibration)
         self.attn_layers = [i for i in range(cfg.n_layers)
                             if cfg.layer_kinds()[i] in ("attn", "mla")]
@@ -131,22 +138,25 @@ class LM:
                     step_proj=None, max_len=0, block_table=None,
                     token_mask=None, num_splits=1):
         cfg = self.cfg
-        new_caches, captures, aux_t = [], None, _zero_aux()
+        new_caches, captures, aux_t = [], [], _zero_aux()
         for j, layer_idx in enumerate(self.step_template):
             lp = step_params["layers"][j]
             lc = step_cache["layers"][j] if step_cache is not None else None
-            lproj = step_proj if (j == self.attn_j and step_proj is not None
-                                  and len(step_proj)) else None
+            lproj = None
+            if (j in self.attn_js and step_proj is not None
+                    and len(step_proj)):
+                lproj = (step_proj[self.attn_js.index(j)]
+                         if len(self.attn_js) > 1 else step_proj)
             x, nc, caps, aux = apply_layer(
                 lp, x, cfg, layer_idx, mode, lc, pos, lproj, max_len,
                 block_table, token_mask, num_splits)
             new_caches.append(nc)
             if caps is not None:
-                captures = caps
+                captures.append(caps)
             aux_t = _add_aux(aux_t, aux)
         cache_out = ({"layers": tuple(new_caches)}
                      if mode in ("prefill", "decode", "chunk") else None)
-        return x, cache_out, captures, aux_t
+        return x, cache_out, tuple(captures) or None, aux_t
 
     # -- full stack ----------------------------------------------------------
 
@@ -191,7 +201,7 @@ class LM:
                         block_table, token_mask, num_splits)
                     outs.append(co)
                     if caps is not None:
-                        captures_list.append(caps)
+                        captures_list.extend(caps)
                     aux = jax.tree.map(lambda a, b: a + b, aux, sa)
                 if mode in ("prefill", "decode", "chunk"):
                     steps_cache_out = jax.tree.map(
@@ -203,7 +213,7 @@ class LM:
                 aux = jax.tree.map(lambda a, b: a + b, aux, s_aux)
                 if caps_stacked is not None:
                     for i in range(len(self.steps)):
-                        captures_list.append(jax.tree.map(
+                        captures_list.extend(jax.tree.map(
                             lambda a: a[i], caps_stacked))
 
         cache_out = None
@@ -301,7 +311,8 @@ class LM:
         return self._logits(params, x), cache
 
     def decode_step(self, params, cache, tokens, pos, proj=None,
-                    block_table=None, token_mask=None, num_splits=1):
+                    block_table=None, token_mask=None, num_splits=1,
+                    with_aux: bool = False):
         """tokens: (B, 1) int32; pos: per-sequence (B,) index of each new
         token (a scalar broadcasts — legacy lock-step decode).
 
@@ -311,16 +322,19 @@ class LM:
         from MoE capacity assignment.  ``num_splits`` (static Python
         int, paged only): split-KV flash-decoding fan-out for the
         attention read (DESIGN.md §split-kv); 1 is the unsplit parity
-        oracle."""
+        oracle.  ``with_aux`` also returns the summed layer statistics
+        (``AUX_KEYS``), e.g. the held-expert pairs of the step."""
         pos = attn_mod.batched_positions(pos, tokens.shape[0])
         x = self._embed(params, {"tokens": tokens})
         tm = token_mask[:, None] if token_mask is not None else None
-        x, cache, _, _ = self._run_stack(params, x, "decode", cache=cache,
-                                         pos=pos, proj=proj,
-                                         block_table=block_table,
-                                         token_mask=tm,
-                                         num_splits=num_splits)
+        x, cache, _, aux = self._run_stack(params, x, "decode", cache=cache,
+                                           pos=pos, proj=proj,
+                                           block_table=block_table,
+                                           token_mask=tm,
+                                           num_splits=num_splits)
         x = rms_norm(x, params["final_norm"], self.cfg.rms_eps)
+        if with_aux:
+            return self._logits(params, x), cache, aux
         return self._logits(params, x), cache
 
     def calibrate(self, params, tokens):
@@ -339,7 +353,8 @@ class LM:
             if cfg.layer_kinds()[layer_idx] == "mla":
                 out.append(mla_mod.mla_group_output_weights(lp["attn"], cfg))
             else:
-                out.append(attn_mod.group_output_weights(lp["attn"], cfg))
+                out.append(attn_mod.group_output_weights(
+                    lp["attn"], cfg, cfg.attn_spec(layer_idx)))
         return out
 
     def _layer_params(self, params, layer_idx: int):
@@ -355,19 +370,19 @@ class LM:
 
     def init_cache(self, batch: int, max_len: int,
                    ranks: Tuple[int, int] = (0, 0), dtype=None,
-                   paged: bool = False):
+                   paged: bool = False, ring_slots: int = 0):
         """Empty decode cache; ``paged=True`` builds page-pool leaves
         from the configured page layout (DESIGN.md §page-layouts)."""
         cfg = self.cfg
         dtype = dtype or self.dtype
         prefix = [init_layer_cache(cfg, i, batch, max_len, ranks, dtype,
-                                   paged)
+                                   paged, ring_slots)
                   for i in self.prefix]
         step_caches = []
         for st in (self.steps[:1] if cfg.scan_layers else self.steps):
             step_caches.append({"layers": tuple(
                 init_layer_cache(cfg, l, batch, max_len, ranks, dtype,
-                                 paged)
+                                 paged, ring_slots)
                 for l in st)})
         if self.steps:
             if cfg.scan_layers:
@@ -383,13 +398,17 @@ class LM:
         return {"prefix": prefix, "steps": steps}
 
     def init_paged_cache(self, n_phys_pages: int, page_size: int,
-                         ranks: Tuple[int, int] = (0, 0), dtype=None):
+                         ranks: Tuple[int, int] = (0, 0), dtype=None,
+                         ring_slots: int = 0):
         """Page-pool cache (DESIGN.md §paged-cache): same pytree layout
         as ``init_cache`` but every attention leaf is a pool
         ``(n_phys_pages, Hkv, page_size, R)`` indexed through a block
         table instead of per-slot ``(B, max_seq_len, R)`` lanes.  This
         is exactly ``init_cache`` with (batch, max_len) reinterpreted as
-        (pages, page_size) — restricted to plain-attention stacks."""
+        (pages, page_size) — restricted to plain-attention stacks.  The
+        windowed layers of a model with per-layer kinds hold
+        ``1 + ring_slots * ring_pages`` pages instead (DESIGN.md
+        §window-rings)."""
         cfg = self.cfg
         kinds = set(cfg.layer_kinds())
         if kinds != {"attn"}:
@@ -400,10 +419,13 @@ class LM:
             raise NotImplementedError(
                 "paged cache: sliding window not supported")
         return self.init_cache(n_phys_pages, page_size, ranks, dtype,
-                               paged=True)
+                               paged=True, ring_slots=ring_slots)
 
     def projections_pytree(self, mp: ModelProjections, dtype=None):
-        """Convert solved ModelProjections to the runtime pytree."""
+        """Convert solved ModelProjections to the runtime pytree: one
+        dict per prefix attention layer, and for the scanned steps the
+        dicts stacked over steps (a tuple of them, one per attention
+        sublayer, when a step holds several)."""
         dtype = dtype or self.dtype
         arrays = {"a_k": mp.a_k, "b_q": mp.b_q}
         if mp.a_v is not None:
@@ -417,8 +439,17 @@ class LM:
         n_pre = len(prefix_attn)
         pre = per_layer[:n_pre]
         body = per_layer[n_pre:]
-        steps = (jax.tree.map(lambda *xs: jnp.stack(xs), *body)
-                 if body else None)
+
+        def stack(layers):
+            return jax.tree.map(lambda *xs: jnp.stack(xs), *layers)
+
+        n_j = len(self.attn_js)
+        if not body:
+            steps = None
+        elif n_j > 1:
+            steps = tuple(stack(body[j::n_j]) for j in range(n_j))
+        else:
+            steps = stack(body)
         return {"prefix": pre, "steps": steps}
 
 

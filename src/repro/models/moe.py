@@ -22,6 +22,13 @@ Design notes (roofline-driven):
 Supports DeepSeek-style shared experts and Arctic's parallel dense
 residual.  Router aux losses (load-balance + z-loss) are returned for the
 trainer.
+
+``held_moe_ffn`` is the dropless layer of a device that holds a
+contiguous range of the experts (``RoutingConfig``, MiMo-V2-Flash): it
+routes over all of them and computes, with grouped matmuls
+(``jax.lax.ragged_dot``) whose work follows the routed pairs, only its
+held experts' part of the result.  No capacity: no token is dropped at
+any batch size or routing skew.
 """
 from __future__ import annotations
 
@@ -31,22 +38,31 @@ from typing import Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from repro.config import MoEConfig
+from repro.config import MoEConfig, RoutingConfig
 from repro.models.layers import init_dense, init_swiglu, swiglu
 from repro.sharding.partition import axis_size, shard
 
 
-def init_moe(key, d_model: int, mo: MoEConfig, dtype) -> Dict:
+def init_moe(key, d_model: int, mo: MoEConfig, dtype,
+             routing: Optional[RoutingConfig] = None) -> Dict:
     """Init router (fp32) + stacked expert SwiGLU weights, plus the
-    shared-expert params when configured."""
+    shared-expert params when configured.  With ``routing`` the router
+    keeps all ``n_experts`` outputs (and a selection bias) while only
+    the held experts' weights exist here."""
     keys = jax.random.split(key, 8)
     E, ff = mo.n_experts, mo.expert_ff
+    n_local = E
+    if routing is not None:
+        lo, hi = routing.held_range(E)
+        n_local = hi - lo
     p = {
         "router": init_dense(keys[0], (d_model, E), d_model, jnp.float32),
-        "wi_e": init_dense(keys[1], (E, d_model, ff), d_model, dtype),
-        "wg_e": init_dense(keys[2], (E, d_model, ff), d_model, dtype),
-        "wo_e": init_dense(keys[3], (E, ff, d_model), ff, dtype),
+        "wi_e": init_dense(keys[1], (n_local, d_model, ff), d_model, dtype),
+        "wg_e": init_dense(keys[2], (n_local, d_model, ff), d_model, dtype),
+        "wo_e": init_dense(keys[3], (n_local, ff, d_model), ff, dtype),
     }
+    if routing is not None:
+        p["bias"] = jnp.zeros((E,), jnp.float32)
     if mo.n_shared_experts:
         p["shared"] = init_swiglu(keys[4], d_model,
                                   mo.n_shared_experts * ff, dtype)
@@ -180,3 +196,65 @@ def moe_ffn(p: Dict, x: jnp.ndarray, mo: MoEConfig, mode: str = "train",
     if "residual" in p:
         y = y + swiglu(p["residual"], xf).reshape(B, S, D).astype(y.dtype)
     return y.astype(x.dtype), aux
+
+
+def route(p: Dict, x: jnp.ndarray, mo: MoEConfig):
+    """Top-k choice of a ``RoutingConfig`` router over all experts.
+    x: (N, D) -> (expert ids (N, K), weights (N, K) f32): ids by sigmoid
+    score plus selection bias, weights the chosen scores (bias left
+    out) normalised over the k chosen."""
+    logits = jnp.einsum("nd,de->ne", x.astype(jnp.float32), p["router"])
+    scores = jax.nn.sigmoid(logits)
+    _, idx = jax.lax.top_k(scores + p["bias"], mo.top_k)
+    w = jnp.take_along_axis(scores, idx, axis=1)
+    return idx, w / jnp.maximum(w.sum(-1, keepdims=True), 1e-20)
+
+
+def held_moe_ffn(p: Dict, x: jnp.ndarray, mo: MoEConfig, rt: RoutingConfig,
+                 token_mask: Optional[jnp.ndarray] = None
+                 ) -> Tuple[jnp.ndarray, Dict[str, jnp.ndarray]]:
+    """x: (B, S, D) -> (y, aux): the held experts' share of the layer.
+
+    Every (token, choice) pair whose expert lies in the held range is
+    sorted by expert, and three grouped matmuls (``ragged_dot``) run
+    each held expert over its own rows only; pairs outside the range, and
+    masked tokens (``token_mask`` (B, S) False), sort last and are
+    computed by none.  The outputs are weighted by the normalised router
+    weights and summed per token.  ``aux["held_pairs"]`` counts the
+    pairs computed, ``aux["held_experts"]`` the held experts given any
+    (the ones whose weights the grouped matmuls read)."""
+    B, S, D = x.shape
+    N, K = B * S, mo.top_k
+    lo, hi = rt.held_range(mo.n_experts)
+    n_local = hi - lo
+    xf = x.reshape(N, D)
+    with jax.named_scope("moe_route"):
+        idx, w = route(p, xf, mo)
+        local = idx - lo
+        held = (local >= 0) & (local < n_local)
+        if token_mask is not None:
+            held = held & token_mask.reshape(N, 1)
+        key = jnp.where(held, local, n_local).reshape(N * K)
+        order = jnp.argsort(key, stable=True)
+        sizes = jnp.zeros((n_local + 1,), jnp.int32).at[key].add(1)
+        n_held = N * K - sizes[n_local]
+        rows = xf[order // K]                                 # (N*K, D)
+        wts = jnp.where(held, w, 0.0).reshape(N * K)[order]
+    with jax.named_scope("moe_experts"):
+        gs = sizes[:n_local]
+        h = jax.lax.ragged_dot(rows, p["wi_e"], gs,
+                               preferred_element_type=jnp.float32)
+        g = jax.lax.ragged_dot(rows, p["wg_e"], gs,
+                               preferred_element_type=jnp.float32)
+        a = (jax.nn.silu(g) * h).astype(x.dtype)
+        o = jax.lax.ragged_dot(a, p["wo_e"], gs,
+                               preferred_element_type=jnp.float32)
+        # rows past the held pairs belong to no group: their output is
+        # not defined, so it is dropped, not weighted by zero
+        o = jnp.where((jnp.arange(N * K) < n_held)[:, None],
+                      o * wts[:, None], 0.0)
+        y = o[jnp.argsort(order)].reshape(N, K, D).sum(1)
+    aux = {"held_pairs": n_held.astype(jnp.float32),
+           "held_experts": (gs > 0).sum().astype(jnp.float32)}
+    return y.reshape(B, S, D).astype(x.dtype), aux
+

@@ -121,6 +121,7 @@ sequences (``capacity_gain``) — the serving-level payoff of the paper.
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import functools
 import time
@@ -141,8 +142,8 @@ from repro.serving.faults import FaultInjector, SwapFailed, checksum
 from repro.serving.page_layouts import FpLayout, get_layout
 from repro.serving.paged_cache import (GARBAGE_PAGE, BlockTables, PagePool,
                                        PagePoolExhausted, PrefixIndex,
-                                       copy_page, pages_needed, swap_in,
-                                       swap_out)
+                                       copy_page, pages_needed,
+                                       ring_pages, swap_in, swap_out)
 from repro.sharding import partition
 from repro.models.model import build_model
 
@@ -382,7 +383,7 @@ class ServingEngine:
 
     def _validate_paged(self) -> None:
         """Fail fast at construction, not mid-serve."""
-        cfg = self.cfg
+        cfg, sc = self.cfg, self.sc
         kinds = set(cfg.layer_kinds())
         if kinds != {"attn"}:
             raise NotImplementedError(
@@ -390,7 +391,25 @@ class ServingEngine:
                 f"(layer kinds: {sorted(kinds)})")
         if cfg.sliding_window:
             raise NotImplementedError(
-                "paged serving: sliding window not supported")
+                "paged serving: sliding window not supported (windowed "
+                "kinds of ModelConfig.attn_kinds are, on page rings)")
+        if cfg.ring_window or cfg.routing is not None:
+            # window rings (DESIGN.md §window-rings) are written by
+            # chunked prefill only and hold no shareable, swappable or
+            # shardable pages; the held-expert count rides the token
+            # stream of the single-device decode scan
+            unsupported = {"chunked_prefill=False": not sc.chunked_prefill,
+                           "share_prefix": sc.share_prefix,
+                           "preempt_mode='swap'": sc.preempt_mode == "swap",
+                           "shards > 1": sc.shards > 1,
+                           f"cache_quant={sc.cache_quant!r}":
+                               sc.cache_quant != "none"}
+            bad = [k for k, v in unsupported.items() if v]
+            if bad:
+                raise NotImplementedError(
+                    f"{cfg.name}: window rings and held-expert layers "
+                    f"are served on bf16 pages with chunked prefill, "
+                    f"unshared and unsharded; not with {', '.join(bad)}")
         if cfg.cache_quant != "none" and self.sc.cache_quant == "none":
             raise NotImplementedError(
                 "paged serving selects its page layout via "
@@ -552,6 +571,11 @@ class ServingEngine:
         T = self.sc.max_seq_len
         temp = self.sc.temperature
         eos = self.sc.eos_token
+        # held-expert layers count their (token, expert) pairs and the
+        # experts those touch; both counts and whether the step ran
+        # ride the token stream as three extra columns (``_harvest``
+        # reads them, no extra fetch)
+        counts = self.cfg.routing is not None
 
         def _decode(cache, tokens, fpos, live):
             kw: Dict[str, Any] = {"block_table": block_table,
@@ -561,7 +585,7 @@ class ServingEngine:
             if block_table is not None:
                 kw["num_splits"] = num_splits
             return self.model.decode_step(params, cache, tokens, fpos,
-                                          **kw)
+                                          with_aux=counts, **kw)
 
         def _body(carry, _):
             logits, cache, pos, emitted, done, trunc, rng = carry
@@ -586,17 +610,23 @@ class ServingEngine:
             # a concurrent chunked prefill is filling)
 
             def _step(ops):
-                lg, new_cache = _decode(ops[0], ops[1][:, None], ops[2],
-                                       ops[3])
-                return lg[:, 0], new_cache
+                out = _decode(ops[0], ops[1][:, None], ops[2], ops[3])
+                held = (jnp.stack([out[2]["held_pairs"],
+                                   out[2]["held_experts"]]).astype(
+                                       jnp.int32)
+                        if counts else jnp.zeros((2,), jnp.int32))
+                return out[0][:, 0], out[1], held
 
             def _skip(ops):
-                return logits, ops[0]
+                return logits, ops[0], jnp.zeros((2,), jnp.int32)
 
-            new_logits, cache = jax.lax.cond(
-                jnp.any(active), _step, _skip, (cache, nxt, feed_pos,
-                                              active))
+            ran = jnp.any(active)
+            new_logits, cache, held = jax.lax.cond(
+                ran, _step, _skip, (cache, nxt, feed_pos, active))
             pos = jnp.where(active, pos + 1, pos)
+            if counts:
+                out_tok = jnp.concatenate(
+                    [out_tok, held, ran.astype(jnp.int32)[None]])
             return ((new_logits, cache, pos, emitted, done, trunc, rng),
                     (out_tok, emit))
 
@@ -696,9 +726,11 @@ class ServingEngine:
             self.pool = PagePool(n_phys, sc.watermark_high,
                                  sc.watermark_low)
             self.pool.faults = self.faults
-            self._btabs = BlockTables(B, sc.pages_per_seq)
+            ring = (ring_pages(self.cfg.ring_window, sc.page_size)
+                    if self.cfg.ring_window else 0)
+            self._btabs = BlockTables(B, sc.pages_per_seq, ring)
             self._cache = self.model.init_paged_cache(
-                n_phys + 1, sc.page_size, self.ranks)
+                n_phys + 1, sc.page_size, self.ranks, ring_slots=B)
             if sc.share_prefix:
                 # per-batch prefix index (DESIGN.md §prefix-sharing):
                 # reset with the pool, since its entries pin pool pages
@@ -741,6 +773,12 @@ class ServingEngine:
         self.n_reclaimed = 0       # index entries dropped under pressure
         self.n_prefill_chunks = 0
         self.peak_used_pages = 0
+        # held-expert layers (DESIGN.md §held-experts): (token, held
+        # expert) pairs routed in decode steps, the held experts they
+        # touched (summed over layers and steps), and decode steps run
+        self.n_held_pairs = 0
+        self.n_held_experts = 0
+        self.n_decode_steps = 0
         # token-budget scheduler bookkeeping (DESIGN.md §scheduler)
         self.budget_log: List[Dict[str, Any]] = []
         self.n_fused_steps = 0         # prefill chunk rode the decode scan
@@ -1749,23 +1787,37 @@ class ServingEngine:
         trunc_np = _to_host(self._trunc, "trunc")
         now = time.perf_counter()
         freed = False
-        for b in range(sc.max_batch):
-            if not live[b]:
-                continue
-            r = self._slot_req[b]
-            r.out_tokens.extend(
-                int(toks_np[t, b]) for t in range(sc.decode_chunk)
-                if emits_np[t, b])
-            if r.t_first_token is None and r.out_tokens:
-                r.t_first_token = now
-            if done_np[b]:
-                r.done = True
-                r.truncated = bool(trunc_np[b])
-                self._release(b, finished=True)
-                self._retry.pop(id(r), None)
-                self._pf_best.pop(id(r), None)
-                self.n_completed += 1
-                freed = True
+        span = contextlib.nullcontext()
+        if toks_np.shape[1] > sc.max_batch:
+            # held-expert pairs of the chunk's decode steps, the held
+            # experts they touched, and how many steps ran (DESIGN.md
+            # §held-experts); the span's name carries them to a trace
+            # reader
+            pairs, touched, ran = (
+                int(x) for x in toks_np[:, sc.max_batch:].sum(axis=0))
+            self.n_held_pairs += pairs
+            self.n_held_experts += touched
+            self.n_decode_steps += ran
+            span = TraceAnnotation(
+                f"engine.held_pairs={pairs}/{ran}/{touched}")
+        with span:
+            for b in range(sc.max_batch):
+                if not live[b]:
+                    continue
+                r = self._slot_req[b]
+                r.out_tokens.extend(
+                    int(toks_np[t, b]) for t in range(sc.decode_chunk)
+                    if emits_np[t, b])
+                if r.t_first_token is None and r.out_tokens:
+                    r.t_first_token = now
+                if done_np[b]:
+                    r.done = True
+                    r.truncated = bool(trunc_np[b])
+                    self._release(b, finished=True)
+                    self._retry.pop(id(r), None)
+                    self._pf_best.pop(id(r), None)
+                    self.n_completed += 1
+                    freed = True
         return freed
 
     def _step_inner_budget(self) -> bool:

@@ -139,10 +139,19 @@ def _audit_pool(eng, bad: List[str]) -> None:
 
 def _audit_block_tables(eng, bad: List[str]) -> None:
     btabs = eng._btabs
+    ring = btabs.ring
     for b in range(eng.sc.max_batch):
         owned = btabs.slot_pages[b]
-        row = btabs.rows[b]
+        row = btabs.rows[b][:btabs.rows.shape[1] - ring]
         k = len(owned)
+        if ring:
+            # the window ring's columns (DESIGN.md §window-rings): the
+            # slot's fixed ring pages while it holds pages, else garbage
+            want = (1 + b * ring + np.arange(ring) if k
+                    else np.full(ring, GARBAGE_PAGE))
+            if np.any(btabs.rows[b][-ring:] != want):
+                bad.append(f"slot {b}: ring columns "
+                           f"{list(btabs.rows[b][-ring:])} != {list(want)}")
         if list(row[:k]) != list(owned):
             bad.append(f"slot {b}: rows[:{k}] {list(row[:k])} != "
                        f"slot_pages {owned}")
@@ -211,17 +220,24 @@ def _audit_layout(eng, bad: List[str]) -> None:
         for name, width, dtype in layout.leaves(side, rank):
             expect[name] = (width, dtype)
     n_rows = eng.pool.n_pages + 1    # + the garbage page
+    # window layers hold a ring of pages per slot (DESIGN.md
+    # §window-rings), whatever the pool
+    n_ring = 1 + eng.sc.max_batch * eng._btabs.ring
 
-    def _check(tag: str, leaves, lead: int) -> None:
+    def _check(tag: str, leaves, lead: int, layer: int) -> None:
+        rows = n_rows
+        if eng.cfg.attn_kinds is not None and eng.cfg.attn_spec(
+                layer).window:
+            rows = n_ring
         if set(leaves) != set(expect):
             bad.append(f"{tag}: cache leaves {sorted(leaves)} != "
                        f"layout {layout.name!r} schema {sorted(expect)}")
             return
         for name, arr in leaves.items():
             width, dtype = expect[name]
-            if arr.shape[lead] != n_rows:
+            if arr.shape[lead] != rows:
                 bad.append(f"{tag}/{name}: page axis {arr.shape[lead]} "
-                           f"!= pool size {n_rows}")
+                           f"!= pool size {rows}")
             if arr.shape[-1] != width:
                 bad.append(f"{tag}/{name}: width {arr.shape[-1]} != "
                            f"layout width {width}")
@@ -229,13 +245,15 @@ def _audit_layout(eng, bad: List[str]) -> None:
                 bad.append(f"{tag}/{name}: dtype {arr.dtype} != "
                            f"layout dtype {dtype}")
 
+    model = eng.model
     for i, layer in enumerate(eng._cache["prefix"]):
-        _check(f"prefix layer {i}", layer, 0)
+        _check(f"prefix layer {i}", layer, 0, model.prefix[i])
     steps = eng._cache["steps"]
     if steps is not None:
         # stacked scan steps: leaves carry a leading (n_steps,) axis
         for j, layer in enumerate(steps["layers"]):
-            _check(f"steps sublayer {j}", layer, 1)
+            _check(f"steps sublayer {j}", layer, 1,
+                   model.step_template[j])
 
 
 def _audit_swapped(eng, bad: List[str]) -> None:

@@ -321,11 +321,16 @@ class BlockTables:
     """Per-slot block tables: host numpy state + device export.
 
     ``rows[b, j]`` is the physical page holding logical page ``j`` of
-    slot ``b``; unallocated entries point at the garbage page.
+    slot ``b``; unallocated entries point at the garbage page.  With
+    ``ring`` > 0 each row ends in ``ring`` more columns: the slot's
+    window ring (DESIGN.md §window-rings), pages ``1 + b * ring + i`` of
+    the windowed layers' own pools, fixed per slot and set while the
+    slot holds a request.
     """
 
-    def __init__(self, n_slots: int, pages_per_seq: int):
-        self.rows = np.zeros((n_slots, pages_per_seq), np.int32)
+    def __init__(self, n_slots: int, pages_per_seq: int, ring: int = 0):
+        self.ring = ring
+        self.rows = np.zeros((n_slots, pages_per_seq + ring), np.int32)
         self.slot_pages: List[List[int]] = [[] for _ in range(n_slots)]
         # cached device export: (live-mask key, array).  Every step()
         # re-exports the rows; between assign/release/COW-fork they are
@@ -340,6 +345,9 @@ class BlockTables:
         assert start == len(self.slot_pages[slot])
         self.rows[slot, start: start + len(pages)] = pages
         self.slot_pages[slot].extend(pages)
+        if self.ring:
+            self.rows[slot, -self.ring:] = (1 + slot * self.ring
+                                            + np.arange(self.ring))
         self._dev_cache = None
 
     def set_page(self, slot: int, logical: int, page: int) -> None:
@@ -498,3 +506,62 @@ def gather_pages(pool: jnp.ndarray, block_table: jnp.ndarray
     g = pool[block_table]                                   # (B,n,Hkv,ps,R)
     B, n, Hkv, ps, R = g.shape
     return g.transpose(0, 2, 1, 3, 4).reshape(B, Hkv, n * ps, R)
+
+
+# ---------------------------------------------------------------------------
+# Window rings (DESIGN.md §window-rings)
+# ---------------------------------------------------------------------------
+# A windowed layer reads only the last ``window`` positions, so a slot's
+# state there is a ring of ``ring_pages`` whole pages, reached through
+# ring columns at the end of the slot's block-table row: logical page j
+# of the sequence lives in ring column ``j % ring_pages``.  The ring
+# holds at least the last ``(ring_pages - 1) * page_size >= window``
+# positions whatever the context, and its position-to-entry map is
+# fixed, so no per-entry positions are stored.
+
+
+def ring_pages(window: int, page_size: int) -> int:
+    """Pages in a slot's ring: a window starting mid-page spans
+    ``ceil(window / page_size) + 1`` pages."""
+    return -(-window // page_size) + 1
+
+
+def ring_positions(last: jnp.ndarray, n: int) -> jnp.ndarray:
+    """(B, n) position held by each of a ring's ``n`` entries when
+    ``last`` (B,) is the newest position written: entry r holds the
+    newest position p <= last with p % n == r (negative: never
+    written)."""
+    r = jnp.arange(n, dtype=jnp.int32)[None, :]
+    return last[:, None] - jnp.mod(last[:, None] - r, n)
+
+
+def gather_ring(pool: jnp.ndarray, ring_table: jnp.ndarray) -> jnp.ndarray:
+    """(B, Hkv, ring * ps, R) ring entries of each slot, in entry order
+    (``ring_positions`` gives their positions)."""
+    return gather_pages(pool, ring_table)
+
+
+def append_ring(pool: jnp.ndarray, ring_table: jnp.ndarray,
+                pos0: jnp.ndarray, vals: jnp.ndarray,
+                valid: jnp.ndarray) -> jnp.ndarray:
+    """Write ``vals`` (B, Hkv, S, R) at positions ``pos0 + s`` into the
+    rings of ``ring_table`` (B, ring).  ``valid``: (B, S) bool or a (B,)
+    count of leading real entries.  Of a chunk longer than the ring only
+    the newest ``ring * ps`` entries are written (the rest would be
+    overwritten in the same scatter); the others, and padding, go to the
+    garbage page."""
+    ps = pool.shape[2]
+    B, Hkv, S, R = vals.shape
+    ring = ring_table.shape[1]
+    if valid.ndim == 1:
+        valid = jnp.arange(S)[None, :] < valid[:, None]
+    n_valid = valid.sum(axis=1)
+    s = jnp.arange(S)[None, :]
+    keep = valid & (s >= n_valid[:, None] - ring * ps)
+    pos = pos0[:, None] + s                                 # (B, S)
+    b = jnp.arange(B)[:, None]
+    phys = jnp.where(keep, ring_table[b, (pos // ps) % ring], GARBAGE_PAGE)
+    flat_vals = vals.transpose(0, 2, 1, 3).reshape(B * S, Hkv, R)
+    return pool.at[phys.reshape(-1), :, (pos % ps).reshape(-1)].set(
+        flat_vals.astype(pool.dtype))
+

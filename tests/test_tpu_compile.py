@@ -227,3 +227,65 @@ def test_engine_programs_carry_kernel_names(engine_programs, program,
     assert len(calls) == len(kernels), calls
     for name in kernels:
         assert any(_named(name) in ln for ln in calls), (name, calls)
+
+
+# mimov2f-mooncake (MiMo-V2-Flash, bench/configs/mimo-v2-flash-12l.json):
+# 24 slots over 3072 pages, 64 query heads over 8 KV heads (window
+# layers, group 8) or 4 (full layers, group 16), KQ-SVD ranks 96/64
+# lane-padded to 128 on every call as the engine serves them, 64-token
+# pages, a 3-page ring per slot for the 128-token window, 512-token
+# prefill chunks over a 16384-token context, 8 of 256 experts held
+# (width 2048, d_model 4096)
+_MB, _MH, _RK, _RV, _MPS, _MT = 24, 64, 96, 64, 64, 16384
+
+
+@pytest.mark.parametrize("hkv", [8, 4], ids=["group8", "group16"])
+def test_mimo_window_kernel_compiles_for_v5e(one_chip, hkv):
+    from repro.kernels.kq_decode.paged import kq_window_paged_attention
+    pool = 1 + _MB * 3
+
+    def fn(q, kc, vc, lens, ring, sink):
+        return kq_window_paged_attention(q, kc, vc, lens, ring, window=128,
+                                         scale=192 ** -0.5, sink=sink,
+                                         interpret=False)
+    text = _compile(fn, one_chip, ((_MB, _MH, _RK), _BF),
+                    ((pool, hkv, _MPS, _RK), _BF),
+                    ((pool, hkv, _MPS, _RV), _BF), ((_MB,), _I32),
+                    ((_MB, 3), _I32), ((_MH,), jnp.float32))
+    assert _named("kq_window_paged_attention") in text
+
+
+@pytest.mark.parametrize("hkv", [8, 4], ids=["group8", "group16"])
+def test_mimo_full_decode_kernel_compiles_for_v5e(one_chip, hkv):
+    npp, pool = _MT // _MPS, 3073
+    _compile(functools.partial(kq_decode_paged_attention, scale=SCALE,
+                               interpret=False, max_len=_MT), one_chip,
+             ((_MB, _MH, _RK), _BF), ((pool, hkv, _MPS, _RK), _BF),
+             ((pool, hkv, _MPS, _RV), _BF), ((_MB,), _I32),
+             ((_MB, npp), _I32))
+
+
+def test_mimo_full_prefill_kernel_compiles_for_v5e(one_chip):
+    """Group 16: 16 x 512 query rows run as two 4096-row calls."""
+    npp, pool = _MT // _MPS, 3073
+    text = _compile(functools.partial(kq_prefill_paged_attention,
+                                      scale=SCALE, interpret=False,
+                                      max_len=_MT),
+                    one_chip, ((1, _MH, 512, _RK), _BF),
+                    ((pool, 4, _MPS, _RK), _BF), ((pool, 4, _MPS, _RV), _BF),
+                    ((1,), _I32), ((1,), _I32), ((1, npp), _I32))
+    calls = [ln for ln in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in ln]
+    assert len(calls) == 2
+    assert all(_named("kq_prefill_paged_attention") in ln for ln in calls)
+
+
+@pytest.mark.parametrize("rows", [_MB * 8, 512 * 8], ids=["decode",
+                                                          "prefill"])
+def test_mimo_expert_grouped_matmul_compiles_for_v5e(one_chip, rows):
+    def fn(x, w, gs):
+        return jax.lax.ragged_dot(x, w, gs,
+                                  preferred_element_type=jnp.float32)
+    text = _compile(fn, one_chip, ((rows, 4096), _BF),
+                    ((8, 4096, 2048), _BF), ((8,), _I32))
+    assert "ragged-dot" in text
