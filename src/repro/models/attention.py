@@ -37,7 +37,8 @@ from repro.models.layers import apply_rope, init_dense
 from repro.serving.page_layouts import get_layout, quantize_int8  # noqa: F401
 from repro.serving.paged_cache import (append_chunk, append_ring,
                                        append_token, gather_pages,
-                                       gather_ring, ring_pages,
+                                       gather_ring, layer_array,
+                                       layer_set, ring_pages,
                                        ring_positions)
 from repro.sharding import partition
 
@@ -61,12 +62,14 @@ def scatter_time(cache: jnp.ndarray, val: jnp.ndarray, slot: jnp.ndarray,
                  axis: int = 1) -> jnp.ndarray:
     """Write one new time-slot per sequence.
 
-    cache: (B, ...); val: same with the time axis of size 1; slot: (B,)
-    per-sequence destination index; ``axis`` is the time axis *within a
-    batch element* (1 for (B, Hkv, T, R) caches, 0 for (B, T, R))."""
-    return jax.vmap(
-        lambda c, u, s: jax.lax.dynamic_update_slice_in_dim(
-            c, u.astype(c.dtype), s, axis))(cache, val, slot)
+    cache: (B, ...), or a ``LayerPool`` of such (written in place in its
+    stack); val: same with the time axis of size 1; slot: (B,)
+    per-sequence destination index, clamped into the cache as a dynamic
+    update slice clamps it; ``axis`` is the time axis *within a batch
+    element* (1 for (B, Hkv, T, R) caches, 0 for (B, T, R))."""
+    slot = jnp.clip(slot, 0, cache.shape[axis + 1] - 1)
+    idx = (jnp.arange(slot.shape[0]),) + (slice(None),) * axis + (slot,)
+    return layer_set(cache, idx, jnp.squeeze(val, axis + 1))
 
 
 def int8_decode_attention(qg, k8, v8, kscale, vscale, valid, scale):
@@ -475,7 +478,8 @@ def _window_step(p, x, cache, pos0, cfg: ModelConfig, spec: AttnSpec,
         vp = append_ring(vp, ring_table, pos0, v_st, valid)
         if kernels.use_kernels():
             agg = kq_window_paged_attention(
-                qg.reshape(B, Hkv * m_p, -1), kp, vp, pos0 + 1,
+                qg.reshape(B, Hkv * m_p, -1), layer_array(kp),
+                layer_array(vp), pos0 + 1,
                 ring_table, window=spec.window, scale=scale,
                 sink=sink).reshape(B, Hkv, m_p, 1, -1)
         else:
@@ -841,7 +845,8 @@ def _full_chunk(p, x, cache, pos0, cfg, spec, proj, block_table, valid,
         # TPU path: the prefill-append kernel streams the written pages
         # in place via the block table
         agg = kq_prefill_paged_attention(
-            qg.reshape(B, Hp, S, -1), kc, vc, lengths, pos0,
+            qg.reshape(B, Hp, S, -1), layer_array(kc), layer_array(vc),
+            lengths, pos0,
             block_table, scale=scale,
             max_len=T).reshape(B, Hkv, m_p, S, -1)
     else:
@@ -957,7 +962,8 @@ def _decode_core(p, x, cache, pos, cfg, proj, block_table, num_splits,
         qg = q.reshape(B, Hkv, m_p, dh)
         qc = jnp.einsum("bgmd,gdr->bgmr", qg, proj["b_q"]).reshape(
             B, Hp, 1, -1)
-        keys, vals = new_cache["kc"], new_cache["vc"]
+        keys = layer_array(new_cache["kc"])
+        vals = layer_array(new_cache["vc"])
         qq = qc
     else:
         if paged:
@@ -967,11 +973,12 @@ def _decode_core(p, x, cache, pos, cfg, proj, block_table, num_splits,
             kk = scatter_time(cache["k"], k_new, slot)
             vv = scatter_time(cache["v"], v_new, slot)
         new_cache = dict(cache, k=kk, v=vv)
-        keys, vals = kk, vv
+        keys, vals = layer_array(kk), layer_array(vv)
         qq = q
     if W:
-        slot_pos = cache["slot_pos"].at[jnp.arange(B), slot].set(pos)
-        new_cache["slot_pos"] = slot_pos                    # (B, T)
+        new_cache["slot_pos"] = layer_set(cache["slot_pos"],
+                                          (jnp.arange(B), slot), pos)
+        slot_pos = layer_array(new_cache["slot_pos"])       # (B, T)
         valid = (slot_pos >= 0) & (slot_pos > pos[:, None] - W)
     else:
         valid = jnp.arange(T)[None, :] <= pos[:, None]      # (B, T)
@@ -979,8 +986,9 @@ def _decode_core(p, x, cache, pos, cfg, proj, block_table, num_splits,
         Hkv = spec.n_kv_heads
         m = padded_heads(cfg) // Hkv
         agg = int8_decode_attention(
-            qq.reshape(B, Hkv, m, -1), keys, vals, new_cache["kscale"],
-            new_cache["vscale"], valid, scale)
+            qq.reshape(B, Hkv, m, -1), keys, vals,
+            layer_array(new_cache["kscale"]),
+            layer_array(new_cache["vscale"]), valid, scale)
     elif quant and layout.kernel == "int8":
         # paged int8 (DESIGN.md §page-layouts): the pallas kernel
         # dequantizes on the fly from int8 pages + scale pools (unsplit
@@ -991,8 +999,9 @@ def _decode_core(p, x, cache, pos, cfg, proj, block_table, num_splits,
             agg = kq_decode_paged_attention(
                 qq.reshape(B, -1, qq.shape[-1]), keys, vals, pos + 1,
                 block_table, scale=scale, max_len=T,
-                num_splits=num_splits, kscale=new_cache["kscale"],
-                vscale=new_cache["vscale"]).reshape(
+                num_splits=num_splits,
+                kscale=layer_array(new_cache["kscale"]),
+                vscale=layer_array(new_cache["vscale"])).reshape(
                     B, Hkv, -1, vals.shape[-1])
         else:
             m_p2 = padded_heads(cfg) // Hkv

@@ -23,6 +23,7 @@ from repro.models import mla as mla_mod
 from repro.models import ssm as ssm_mod
 from repro.models.layers import init_rms, init_swiglu, rms_norm, swiglu
 from repro.models.moe import held_moe_ffn, init_moe, moe_ffn
+from repro.serving.paged_cache import is_layer_pool, layer_array
 from repro.sharding.partition import shard
 
 
@@ -192,7 +193,10 @@ def apply_layer(p: Dict, x, cfg: ModelConfig, layer_idx: int, mode: str,
             y, new_cache = ssm_mod.ssm_forward(p["ssm"], h, cfg.ssm,
                                                return_state=True)
         else:
-            y, new_cache = ssm_mod.ssm_decode(p["ssm"], h, cache, cfg.ssm)
+            # the state is rewritten whole: read the layer's, return a
+            # new one for the step to store at the layer
+            state = jax.tree.map(layer_array, cache, is_leaf=is_layer_pool)
+            y, new_cache = ssm_mod.ssm_decode(p["ssm"], h, state, cfg.ssm)
     x = x + y
     ffn_mask = token_mask if mode in ("decode", "chunk") else None
     if ffn_mask is not None and mode == "chunk" and ffn_mask.ndim == 1:

@@ -31,6 +31,7 @@ import functools
 from repro.models.attention import (NEG_INF, batched_positions,
                                     blockwise_attention, scatter_time)
 from repro.models.layers import apply_rope, init_dense
+from repro.serving.paged_cache import layer_array
 
 # MLA caches are (B, T, R): the time axis within a batch element is 0
 _scatter_seq = functools.partial(scatter_time, axis=0)
@@ -168,7 +169,8 @@ def mla_decode(p, x, cache: Dict, pos, cfg: ModelConfig,
     pos = batched_positions(pos, B)
     q_nope, q_rope, c_new, kr_new = _project(p, x, cfg, pos[:, None, None])
     q_abs = jnp.einsum("bhse,lhe->bhl", q_nope[:, :, :1], p["wuk"])
-    kr = _scatter_seq(cache["kr"], kr_new[:, 0], pos)
+    kr_l = _scatter_seq(cache["kr"], kr_new[:, 0], pos)
+    kr = layer_array(kr_l)
     T = kr.shape[1]
     valid = jnp.arange(T)[None, :] <= pos[:, None]           # (B, T)
     s_rope = jnp.einsum("bhse,bte->bht", q_rope, kr,
@@ -176,9 +178,10 @@ def mla_decode(p, x, cache: Dict, pos, cfg: ModelConfig,
     if proj is not None:
         cc_new = jnp.einsum("bsl,lr->bsr", c_new, proj["a_k"][0])
         ccv_new = jnp.einsum("bsl,lr->bsr", c_new, proj["a_v"][0])
-        cc = _scatter_seq(cache["cc"], cc_new, pos)
-        ccv = _scatter_seq(cache["ccv"], ccv_new, pos)
-        new_cache = dict(cache, cc=cc, ccv=ccv, kr=kr)
+        new_cache = dict(cache, cc=_scatter_seq(cache["cc"], cc_new, pos),
+                         ccv=_scatter_seq(cache["ccv"], ccv_new, pos),
+                         kr=kr_l)
+        cc, ccv = layer_array(new_cache["cc"]), layer_array(new_cache["ccv"])
         q_c = jnp.einsum("bhl,lr->bhr", q_abs, proj["b_q"][0])
         s_nope = jnp.einsum("bhr,btr->bht", q_c, cc,
                             preferred_element_type=jnp.float32)
@@ -189,8 +192,9 @@ def mla_decode(p, x, cache: Dict, pos, cfg: ModelConfig,
         c_v = proj["c_v"][0].reshape(-1, H, cfg.d_model)     # (Rv,H,D)
         y = jnp.einsum("bhr,rhd->bd", agg, c_v)[:, None]
     else:
-        cc = _scatter_seq(cache["c"], c_new, pos)
-        new_cache = dict(cache, c=cc, kr=kr)
+        new_cache = dict(cache, c=_scatter_seq(cache["c"], c_new, pos),
+                         kr=kr_l)
+        cc = layer_array(new_cache["c"])
         s_nope = jnp.einsum("bhl,btl->bht", q_abs, cc,
                             preferred_element_type=jnp.float32)
         s = (s_nope + s_rope) * scale

@@ -34,6 +34,7 @@ from repro.models import mla as mla_mod
 from repro.models.blocks import (apply_layer, attn_sublayer_indices,
                                  init_layer, init_layer_cache, step_layout)
 from repro.models.layers import dtype_of, init_rms, rms_norm
+from repro.serving.paged_cache import LayerPool, is_layer_pool
 from repro.sharding.partition import shard
 
 #: summed over layers; ``held_pairs`` counts (token, held expert) pairs
@@ -134,14 +135,22 @@ class LM:
 
     # -- step application ----------------------------------------------------
 
-    def _apply_step(self, step_params, x, mode, step_cache=None, pos=None,
+    def _apply_step(self, step_params, x, mode, steps_cache=None, pos=None,
                     step_proj=None, max_len=0, block_table=None,
-                    token_mask=None, num_splits=1):
+                    token_mask=None, num_splits=1, step=None):
+        """One step's layers.  With a cache (decode, chunk), ``steps_cache``
+        is the whole stack of step caches and ``step`` this step's index
+        in it: each layer sees its leaves as ``LayerPool``s, writes its
+        new entries into the stack in place, and the updated stack comes
+        back (DESIGN.md §paged-cache)."""
         cfg = self.cfg
+        views = None
+        if steps_cache is not None:
+            views = jax.tree.map(lambda a: LayerPool(a, step), steps_cache)
         new_caches, captures, aux_t = [], [], _zero_aux()
         for j, layer_idx in enumerate(self.step_template):
             lp = step_params["layers"][j]
-            lc = step_cache["layers"][j] if step_cache is not None else None
+            lc = views["layers"][j] if views is not None else None
             lproj = None
             if (j in self.attn_js and step_proj is not None
                     and len(step_proj)):
@@ -154,8 +163,17 @@ class LM:
             if caps is not None:
                 captures.append(caps)
             aux_t = _add_aux(aux_t, aux)
-        cache_out = ({"layers": tuple(new_caches)}
-                     if mode in ("prefill", "decode", "chunk") else None)
+        cache_out = None
+        if steps_cache is not None:
+            # a leaf a layer wrote in place comes back as its stack; a
+            # state it rewrote whole is stored at the step
+            cache_out = jax.tree.map(
+                lambda a, new: (new.stack if is_layer_pool(new) else
+                                jax.lax.dynamic_update_index_in_dim(
+                                    a, new.astype(a.dtype), step, 0)),
+                steps_cache, {"layers": tuple(new_caches)})
+        elif mode == "prefill":
+            cache_out = {"layers": tuple(new_caches)}
         return x, cache_out, tuple(captures) or None, aux_t
 
     # -- full stack ----------------------------------------------------------
@@ -190,20 +208,23 @@ class LM:
             step_proj = proj["steps"] if proj is not None else None
             if not cfg.scan_layers:
                 outs = []
+                carried = cache["steps"] if cache is not None else None
                 for i in range(len(self.steps)):
                     sp = jax.tree.map(lambda a: a[i], params["steps"])
-                    sc = (jax.tree.map(lambda a: a[i], cache["steps"])
-                          if cache is not None else None)
                     spj = (jax.tree.map(lambda a: a[i], step_proj)
                            if step_proj is not None else None)
                     x, co, caps, sa = self._apply_step(
-                        sp, x, mode, sc, pos, spj, max_len,
-                        block_table, token_mask, num_splits)
-                    outs.append(co)
+                        sp, x, mode, carried, pos, spj, max_len,
+                        block_table, token_mask, num_splits, step=i)
+                    if carried is not None:
+                        carried = co
+                    else:
+                        outs.append(co)
                     if caps is not None:
                         captures_list.extend(caps)
                     aux = jax.tree.map(lambda a, b: a + b, aux, sa)
-                if mode in ("prefill", "decode", "chunk"):
+                steps_cache_out = carried
+                if mode == "prefill":
                     steps_cache_out = jax.tree.map(
                         lambda *xs: jnp.stack(xs), *outs)
             else:
@@ -226,25 +247,27 @@ class LM:
                     max_len, block_table=None, token_mask=None,
                     num_splits=1):
         cfg = self.cfg
-        has_cache_in = mode in ("decode", "chunk")
-        emit_cache = mode in ("prefill", "decode", "chunk")
+        # decode and chunk carry the stacked caches (written in place
+        # at each step's index); prefill emits each step's new cache
+        emit_cache = mode == "prefill"
         emit_caps = mode == "calibrate"
 
         def body(carry, xs):
-            x, aux = carry
-            sp = xs[0]
-            sc = xs[1] if has_cache_in else None
+            x, aux, sc = carry
+            i, sp = xs[0], xs[1]
             spj = xs[-1] if step_proj is not None else None
             x, co, caps, sa = self._apply_step(sp, x, mode, sc, pos, spj,
                                                max_len, block_table,
-                                               token_mask, num_splits)
+                                               token_mask, num_splits,
+                                               step=i)
             aux = jax.tree.map(lambda a, b: a + b, aux, sa)
             ys = []
             if emit_cache:
                 ys.append(co)
             if emit_caps:
                 ys.append(caps)
-            return (x, aux), tuple(ys) if ys else None
+            return (x, aux, co if sc is not None else None), (
+                tuple(ys) if ys else None)
 
         if mode == "train" and cfg.remat_policy != "none":
             policy = {"nothing": jax.checkpoint_policies.nothing_saveable,
@@ -253,13 +276,13 @@ class LM:
                             jax.checkpoint_policies.nothing_saveable)
             body = jax.checkpoint(body, policy=policy)
 
-        xs = [steps_params]
-        if has_cache_in:
-            xs.append(cache["steps"])
+        xs = [jnp.arange(len(self.steps)), steps_params]
         if step_proj is not None:
             xs.append(step_proj)
-        (x, aux), ys = jax.lax.scan(body, (x, _zero_aux()), tuple(xs))
-        cache_out = caps_out = None
+        carried = cache["steps"] if cache is not None else None
+        (x, aux, cache_out), ys = jax.lax.scan(
+            body, (x, _zero_aux(), carried), tuple(xs))
+        caps_out = None
         if ys:
             ys = list(ys)
             if emit_cache:

@@ -140,7 +140,8 @@ from repro.kernels.kq_decode import default_decode_splits
 from repro.serving import invariants
 from repro.serving.faults import FaultInjector, SwapFailed, checksum
 from repro.serving.page_layouts import FpLayout, get_layout
-from repro.serving.paged_cache import (GARBAGE_PAGE, BlockTables, PagePool,
+from repro.serving.paged_cache import (GARBAGE_PAGE, BlockTables,
+                                       LayerPool, PagePool,
                                        PagePoolExhausted, PrefixIndex,
                                        copy_page, pages_needed,
                                        ring_pages, swap_in, swap_out)
@@ -343,14 +344,19 @@ class ServingEngine:
             else:
                 self._dynamic_splits = True
         self._prefill = jax.jit(self._prefill_impl)
-        self._insert = jax.jit(self._insert_impl)
-        self._paged_insert = jax.jit(self._paged_insert_impl)
-        self._prefill_chunk = jax.jit(self._prefill_chunk_impl)
-        self._decode_chunk = jax.jit(self._decode_chunk_impl,
-                                     static_argnames=("num_splits",))
-        self._fused_step = jax.jit(self._fused_step_impl,
-                                   static_argnames=("num_splits",))
-        self._fork_page = jax.jit(self._fork_page_impl)
+        # every program that takes the cache donates it: its pools are
+        # updated in place and the caller's old cache is deleted
+        # (DESIGN.md §paged-cache)
+        donate = functools.partial(jax.jit, donate_argnames=("cache",))
+        self._insert = donate(self._insert_impl)
+        self._paged_insert = donate(self._paged_insert_impl)
+        self._prefill_chunk = donate(self._prefill_chunk_impl)
+        self._decode_chunk = donate(self._decode_chunk_impl,
+                                    static_argnames=("num_splits",))
+        self._fused_step = donate(self._fused_step_impl,
+                                  static_argnames=("num_splits",))
+        self._fork_page = donate(self._fork_page_impl)
+        self._swap_in = donate(self._swap_in_impl)
         self.rng = jax.random.PRNGKey(sc.seed)
         # distinct chunk shapes traced so far — the compile-count bound
         # is len(sc.buckets) per engine lifetime (tests assert on it)
@@ -1513,21 +1519,25 @@ class ServingEngine:
     def _swap_in_slot(self, b: int, bufs: Dict[str, Any]) -> None:
         """Restore a swapped-out cache through slot ``b``'s (fresh)
         block-table row — byte-exact, so generations resume unchanged."""
-        row = self._btabs.rows[b].copy()
+        self._cache = self._swap_in(self._cache, self._btabs.rows[b].copy(),
+                                    bufs)
 
+    def _swap_in_impl(self, cache, row, bufs):
+        """Write swapped-out entries (host buffers, one per layer)
+        through ``row`` into the pools, in place.  Compiles once per
+        swapped length."""
         def _in0(pool, vals):
             return swap_in(pool, row, vals)
 
-        def _in1(pools, vals):
-            return jnp.stack([swap_in(pools[i], row, vals[i])
-                              for i in range(pools.shape[0])])
+        def _in1(pools, vals):               # scanned steps: (n_steps, P, ...)
+            for i in range(pools.shape[0]):
+                pools = swap_in(LayerPool(pools, i), row, vals[i]).stack
+            return pools
 
-        cache = {"prefix": jax.tree.map(_in0, self._cache["prefix"],
-                                        bufs["prefix"])}
-        cache["steps"] = (jax.tree.map(_in1, self._cache["steps"],
-                                       bufs["steps"])
-                          if self._cache["steps"] is not None else None)
-        self._cache = cache
+        out = {"prefix": jax.tree.map(_in0, cache["prefix"], bufs["prefix"])}
+        out["steps"] = (jax.tree.map(_in1, cache["steps"], bufs["steps"])
+                        if cache["steps"] is not None else None)
+        return out
 
     def _corrupt_swap(self, bufs: Dict[str, Any]) -> Dict[str, Any]:
         """Deterministically bit-flip one byte of the first leaf of a
@@ -2192,9 +2202,11 @@ class ShardedServingEngine(ServingEngine):
         for w in self.workers[1:]:
             w._fork_page = self.workers[0]._fork_page
         self._local_phys = self.workers[0]._pool_pages()
-        self._sharded_prefill = jax.jit(self._sharded_prefill_impl)
+        self._sharded_prefill = jax.jit(self._sharded_prefill_impl,
+                                        donate_argnames=("cache",))
         self._sharded_decode = jax.jit(self._sharded_decode_impl,
-                                       static_argnames=("num_splits",))
+                                       static_argnames=("num_splits",),
+                                       donate_argnames=("cache",))
 
     #: scheduler counters transparently summed over the shard workers
     #: on read (each worker counts its own slots; the aggregate is the

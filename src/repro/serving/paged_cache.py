@@ -32,13 +32,18 @@ Pool invariants (enforced by ``PagePool``):
 The device-side primitives (``append_token``, ``append_chunk``,
 ``copy_page``, ``gather_pages``) are pure jnp and jit-safe; the
 allocator and the prefix index are plain numpy/Python host state.
+They take a layer's pool either as an array or as a ``LayerPool``, one
+layer of a pool stacked over the scanned layers: a write then stores
+its entries into the stack in place, so a program that carries the
+stack through the layer scan and donates it never rebuilds a pool.
 """
 from __future__ import annotations
 
 import hashlib
 from collections import OrderedDict
-from typing import List, Optional, Sequence, Tuple
+from typing import List, NamedTuple, Optional, Sequence, Tuple
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -402,6 +407,83 @@ class BlockTables:
 # ---------------------------------------------------------------------------
 
 
+class LayerPool(NamedTuple):
+    """Layer ``layer`` of a cache leaf stacked over the scanned layers:
+    ``stack`` is (L, ...) and ``layer`` a (traced) index.  ``shape`` and
+    ``dtype`` are the layer's; ``layer_set`` writes into ``stack`` at
+    the layer and ``layer_array`` reads the layer out (DESIGN.md
+    §paged-cache)."""
+    stack: jnp.ndarray
+    layer: jnp.ndarray
+
+    @property
+    def shape(self) -> Tuple[int, ...]:
+        """The layer's shape (the stack's, less its layer axis)."""
+        return self.stack.shape[1:]
+
+    @property
+    def dtype(self):
+        """The stack's dtype."""
+        return self.stack.dtype
+
+
+def is_layer_pool(x) -> bool:
+    """``is_leaf`` for tree maps over caches that hold ``LayerPool``s."""
+    return isinstance(x, LayerPool)
+
+
+def layer_array(pool) -> jnp.ndarray:
+    """A layer's cache leaf as an array (its slice of a stack)."""
+    if isinstance(pool, LayerPool):
+        return jax.lax.dynamic_index_in_dim(pool.stack, pool.layer, 0,
+                                            keepdims=False)
+    return pool
+
+
+def _layer_get(pool, idx: tuple) -> jnp.ndarray:
+    """``pool[idx]`` of a layer's leaf, read straight from its stack."""
+    if isinstance(pool, LayerPool):
+        return pool.stack[(pool.layer,) + idx]
+    return pool[idx]
+
+
+def layer_set(pool, idx: tuple, val):
+    """``pool.at[idx].set(val)`` for a layer's leaf; a ``LayerPool``
+    stores into its stack at its layer, in place."""
+    if isinstance(pool, LayerPool):
+        return pool._replace(stack=pool.stack.at[(pool.layer,) + idx].set(
+            val.astype(pool.dtype)))
+    return pool.at[idx].set(val.astype(pool.dtype))
+
+
+def _store_entries(pool, pages, slot, off, vals):
+    """Store cache entries by rewriting whole pages.
+
+    pages: (B, n) physical pages row b may write (its own real pages, or
+    the garbage page); slot, off: (B, S) which of the row's pages, and
+    where in it, entry s goes; vals: (B, Hkv, S, R).  Each page that
+    takes an entry is read, its entries set and the page stored back:
+    a page is the window of the pool's write, so the pool keeps the
+    layout it has at rest whatever the order of its minor axes (a
+    window of one entry, (Hkv, R), makes XLA relayout the pool around
+    the write).  A column no entry goes to is not stored (its index is
+    pointed past the pool, where the write drops it)."""
+    n = pages.shape[1]
+    hit = jnp.any(slot[:, :, None] == jnp.arange(n)[None, None, :], 1)
+    pages = jnp.where(hit, pages, pool.shape[0])
+    buf = _layer_get(pool, (pages,))                        # (B,n,Hkv,ps,R)
+    b = jnp.arange(pages.shape[0])[:, None]
+    buf = buf.at[b, slot, :, off].set(
+        vals.transpose(0, 2, 1, 3).astype(buf.dtype))
+    return layer_set(pool, (pages,), buf)
+
+
+def _garbage_column(pages: jnp.ndarray) -> jnp.ndarray:
+    """(B, n) pages and a last column of the garbage page, where the
+    entries that write no real page go."""
+    return jnp.pad(pages, ((0, 0), (0, 1)), constant_values=GARBAGE_PAGE)
+
+
 def append_token(pool: jnp.ndarray, block_table: jnp.ndarray,
                  pos: jnp.ndarray, val: jnp.ndarray) -> jnp.ndarray:
     """Write one new cache entry per sequence through the block table.
@@ -414,7 +496,9 @@ def append_token(pool: jnp.ndarray, block_table: jnp.ndarray,
     ps = pool.shape[2]
     b = jnp.arange(pos.shape[0])
     phys = block_table[b, pos // ps]                        # (B,)
-    return pool.at[phys, :, pos % ps].set(val.astype(pool.dtype))
+    zero = jnp.zeros((pos.shape[0], 1), jnp.int32)
+    return _store_entries(pool, phys[:, None], zero, (pos % ps)[:, None],
+                          val[:, :, None])
 
 
 def append_chunk(pool: jnp.ndarray, block_table: jnp.ndarray,
@@ -434,21 +518,22 @@ def append_chunk(pool: jnp.ndarray, block_table: jnp.ndarray,
     past the block table's logical capacity are clamped before the
     dereference; only padding can reach them, so the clamped rows are
     garbage-routed anyway.
+    The pages are written whole (``_store_entries``): those the chunk's
+    real entries touch, and the garbage page for its padding.
     """
-    ps = pool.shape[2]
-    B, Hkv, S, R = vals.shape
+    ps, S = pool.shape[2], vals.shape[2]
     if valid.ndim == 1:                 # per-row count -> prefix mask
         valid = jnp.arange(S)[None, :] < valid[:, None]
     n_pages = block_table.shape[1]
+    n = (S + ps - 2) // ps + 1          # most pages S positions span
     pos = pos0[:, None] + jnp.arange(S)[None, :]            # (B, S)
-    logical = jnp.minimum(pos // ps, n_pages - 1)
-    b = jnp.arange(B)[:, None]
-    phys = jnp.where(valid, block_table[b, logical], GARBAGE_PAGE)
-    flat_phys = phys.reshape(-1)                            # (B*S,)
-    flat_off = (pos % ps).reshape(-1)
-    flat_vals = vals.transpose(0, 2, 1, 3).reshape(B * S, Hkv, R)
-    return pool.at[flat_phys, :, flat_off].set(
-        flat_vals.astype(pool.dtype))
+    first = pos0 // ps
+    logical = jnp.minimum(first[:, None] + jnp.arange(n)[None, :],
+                          n_pages - 1)
+    pages = jnp.take_along_axis(block_table, logical, 1)    # (B, n)
+    slot = jnp.where(valid, pos // ps - first[:, None], n)
+    return _store_entries(pool, _garbage_column(pages), slot, pos % ps,
+                          vals)
 
 
 def copy_page(pool: jnp.ndarray, src, dst) -> jnp.ndarray:
@@ -503,7 +588,7 @@ def gather_pages(pool: jnp.ndarray, block_table: jnp.ndarray
     path (and test oracle); the Pallas paged kernel reads the same
     pages in place via the block table instead of materializing.
     """
-    g = pool[block_table]                                   # (B,n,Hkv,ps,R)
+    g = _layer_get(pool, (block_table,))                    # (B,n,Hkv,ps,R)
     B, n, Hkv, ps, R = g.shape
     return g.transpose(0, 2, 1, 3, 4).reshape(B, Hkv, n * ps, R)
 
@@ -550,8 +635,7 @@ def append_ring(pool: jnp.ndarray, ring_table: jnp.ndarray,
     the newest ``ring * ps`` entries are written (the rest would be
     overwritten in the same scatter); the others, and padding, go to the
     garbage page."""
-    ps = pool.shape[2]
-    B, Hkv, S, R = vals.shape
+    ps, S = pool.shape[2], vals.shape[2]
     ring = ring_table.shape[1]
     if valid.ndim == 1:
         valid = jnp.arange(S)[None, :] < valid[:, None]
@@ -559,9 +643,7 @@ def append_ring(pool: jnp.ndarray, ring_table: jnp.ndarray,
     s = jnp.arange(S)[None, :]
     keep = valid & (s >= n_valid[:, None] - ring * ps)
     pos = pos0[:, None] + s                                 # (B, S)
-    b = jnp.arange(B)[:, None]
-    phys = jnp.where(keep, ring_table[b, (pos // ps) % ring], GARBAGE_PAGE)
-    flat_vals = vals.transpose(0, 2, 1, 3).reshape(B * S, Hkv, R)
-    return pool.at[phys.reshape(-1), :, (pos % ps).reshape(-1)].set(
-        flat_vals.astype(pool.dtype))
+    return _store_entries(pool, _garbage_column(ring_table),
+                          jnp.where(keep, (pos // ps) % ring, ring),
+                          pos % ps, vals)
 

@@ -303,9 +303,11 @@ def test_cow_fork_copies_scale_pools_byte_exact(setup):
                         projections=proj)
     eng.generate(_reqs(cfg, [9, 7]))
     src, dst = np.int32(1), np.int32(eng.pool.n_pages)
+    # the fork donates the cache: compare against a host copy
+    before = jax.tree.map(np.asarray, eng._cache)
     forked = eng._fork_page(eng._cache, src, dst)
     leaves = [("prefix", lf, name, arr)
-              for lf, layer in enumerate(eng._cache["prefix"])
+              for lf, layer in enumerate(before["prefix"])
               for name, arr in layer.items()]
     saw_scale = False
     for where, lf, name, arr in leaves:
@@ -313,8 +315,8 @@ def test_cow_fork_copies_scale_pools_byte_exact(setup):
         saw_scale |= name.endswith("scale")
         assert np.array_equal(np.asarray(new[dst]), np.asarray(arr[src])), \
             (where, lf, name)
-    if eng._cache["steps"] is not None:
-        for j, layer in enumerate(eng._cache["steps"]["layers"]):
+    if before["steps"] is not None:
+        for j, layer in enumerate(before["steps"]["layers"]):
             for name, arr in layer.items():
                 new = forked["steps"]["layers"][j][name]
                 saw_scale |= name.endswith("scale")

@@ -11,6 +11,7 @@ same tests and only the worker running this file loads the TPU library.
 """
 import functools
 import math
+import re
 
 import jax
 import jax.numpy as jnp
@@ -174,14 +175,38 @@ def test_compiled_kernel_carries_its_name(one_chip, name):
     assert calls and all(_named(name) in ln for ln in calls), calls
 
 
+def _abstract_engine(eng, one_chip, bucket):
+    """A started engine's program state and a prefill chunk's operands
+    as abstract values on the described chip."""
+    import numpy as np
+    state = jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(np.shape(x), x.dtype,
+                                       sharding=one_chip),
+        (eng.params, eng.proj, eng._cache, eng._logits, eng._pos,
+         eng._emitted, eng._max_new, eng._done, eng._trunc, eng.rng,
+         eng._btabs.device()))
+    chunk = [jax.ShapeDtypeStruct(s, _I32, sharding=one_chip)
+             for s in ((1, bucket), (1,), (1,), eng._btabs.rows[:1].shape)]
+    return eng, state, chunk
+
+
 @pytest.fixture(scope="module")
-def engine_programs(one_chip):
+def kernels_on_path():
+    """The Pallas kernels on the model's path, compiled (not
+    interpreted), as on a TPU."""
+    import repro.kernels
+    import repro.kernels.kq_decode.paged as paged
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(repro.kernels, "use_kernels", lambda: True)
+        mp.setattr(paged, "default_interpret", lambda: False)
+        yield
+
+
+@pytest.fixture(scope="module")
+def engine_programs(one_chip, kernels_on_path):
     """A started paged engine (reduced TinyLlama) with its Pallas
     kernels on the path as on a TPU, and its state as abstract values
     on the described chip."""
-    import numpy as np
-    import repro.kernels
-    import repro.kernels.kq_decode.paged as paged
     from repro.config import ServeConfig
     from repro.configs import get_config
     from repro.models import build_model
@@ -192,18 +217,46 @@ def engine_programs(one_chip):
         max_seq_len=64, max_batch=4, paged=True, page_size=16,
         chunked_prefill=True, prefill_chunk=32))
     eng.start([])
-    state = jax.tree.map(
-        lambda x: jax.ShapeDtypeStruct(np.shape(x), x.dtype,
-                                       sharding=one_chip),
-        (eng.params, eng.proj, eng._cache, eng._logits, eng._pos,
-         eng._emitted, eng._max_new, eng._done, eng._trunc, eng.rng,
-         eng._btabs.device()))
-    chunk = [jax.ShapeDtypeStruct(s, _I32, sharding=one_chip)
-             for s in ((1, 32), (1,), (1,), eng._btabs.rows[:1].shape)]
-    with pytest.MonkeyPatch.context() as mp:
-        mp.setattr(repro.kernels, "use_kernels", lambda: True)
-        mp.setattr(paged, "default_interpret", lambda: False)
-        yield eng, state, chunk
+    return _abstract_engine(eng, one_chip, 32)
+
+
+@pytest.fixture(scope="module")
+def phi3_programs(one_chip, kernels_on_path):
+    """A started paged engine at ``phi3v-azconv``'s pool widths (32 KV
+    heads of 96, KQ-SVD rank 48, 64-token pages, 48 pages at batch 3
+    over 3072 tokens) with a narrow residual stream, its kernels on the
+    path; state abstract on the described chip.  16 layers make each
+    stacked pool (154 MB) larger than the chip's vector memory, as the
+    cell's are, so the compiler cannot stage a whole stack there."""
+    import numpy as np
+    from repro.config import ModelConfig, ServeConfig
+    from repro.core.calibration import ModelProjections
+    from repro.models import build_model
+    from repro.serving import ServingEngine
+    L, hkv, dh, r, d = 16, 32, 96, 48, 256
+    cfg = ModelConfig(name="phi3-pools", family="dense", n_layers=L,
+                      d_model=d, n_heads=hkv, n_kv_heads=hkv, d_head=dh,
+                      d_ff=512, vocab_size=512)
+    params = build_model(cfg).init(jax.random.PRNGKey(0))
+    qk = np.zeros((L, hkv, dh, r), np.float32)
+    proj = ModelProjections(a_k=qk, b_q=qk, a_v=qk,
+                            c_v=np.zeros((L, hkv, r, d), np.float32),
+                            ranks_k=[r] * L, ranks_v=[r] * L)
+    eng = ServingEngine(cfg, params, ServeConfig(
+        max_seq_len=3072, max_batch=3, paged=True, chunked_prefill=True,
+        n_pages=48), projections=proj)
+    eng.start([])
+    return _abstract_engine(eng, one_chip, 512)
+
+
+def _lower(programs, program):
+    eng, state, chunk = programs
+    if program == "decode_chunk":
+        return eng._decode_chunk.lower(*state, num_splits=1)
+    if program == "prefill_chunk":
+        return eng._prefill_chunk.lower(*state[:3], *chunk)
+    return eng._fused_step.lower(*state[:3], *chunk, *state[3:],
+                                 num_splits=1)
 
 
 @pytest.mark.parametrize("program, kernels", [
@@ -213,20 +266,167 @@ def engine_programs(one_chip):
                     "kq_decode_paged_attention"])])
 def test_engine_programs_carry_kernel_names(engine_programs, program,
                                             kernels):
-    eng, state, chunk = engine_programs
-    if program == "decode_chunk":
-        low = eng._decode_chunk.lower(*state, num_splits=1)
-    elif program == "prefill_chunk":
-        low = eng._prefill_chunk.lower(*state[:3], *chunk)
-    else:
-        low = eng._fused_step.lower(*state[:3], *chunk, *state[3:],
-                                    num_splits=1)
-    text = low.compile().as_text()
+    text = _lower(engine_programs, program).compile().as_text()
     calls = [ln for ln in text.splitlines()
              if 'custom_call_target="tpu_custom_call"' in ln]
     assert len(calls) == len(kernels), calls
     for name in kernels:
         assert any(_named(name) in ln for ln in calls), (name, calls)
+
+
+class _Hlo:
+    """The instructions of a compiled HLO text: opcode, result shape,
+    operands and computation by name, and each computation's root."""
+
+    _LINE = re.compile(r"\s*(ROOT )?%([\w.\-]+) = (.*?) ([a-z][\w\-]*)\((.*)")
+
+    def __init__(self, text: str):
+        self.header = text.splitlines()[0]
+        self.ops, self.root, self.params = {}, {}, {}
+        comp = self.entry = None
+        for ln in text.splitlines():
+            head = re.match(r"(ENTRY )?%([\w.\-]+) .*\{$", ln)
+            if head:
+                comp = head.group(2)
+                if head.group(1):
+                    self.entry = comp
+                continue
+            m = self._LINE.match(ln)
+            if not m:
+                continue
+            root, name, shape, op, rest = m.groups()
+            depth, args = 1, ""
+            for ch in rest:
+                depth += (ch == "(") - (ch == ")")
+                if not depth:
+                    break
+                args += ch
+            dims = re.match(r"\w+\[([\d,]*)\]", shape)
+            self.ops[name] = dict(
+                op=op, comp=comp, line=ln,
+                dims=tuple(int(x) for x in dims.group(1).split(",") if x)
+                if dims else None,
+                args=re.findall(r"%([\w.\-]+)", args))
+            if root:
+                self.root[comp] = op
+            if op == "parameter" and ln.startswith("  %cache"):
+                self.params[name] = int(re.search(r"parameter\((\d+)\)",
+                                                  ln).group(1))
+        self.users = {}
+        for name, o in self.ops.items():
+            for a in o["args"]:
+                self.users.setdefault(a, []).append(name)
+
+    def called_root(self, name: str) -> str:
+        """The root opcode of the computation a fusion calls."""
+        called = re.search(r"calls=%([\w.\-]+)", self.ops[name]["line"])
+        return self.root.get(called.group(1)) if called else None
+
+
+def _pool_dims(eng):
+    """Stacked pool shapes (L, P, Hkv, ps, R), and a layer's: (P, Hkv,
+    ps, R), (1, P, Hkv, ps, R) and lane-padded (P, Hkv, ps, R128)."""
+    stacks = {tuple(x.shape) for x in jax.tree.leaves(eng._cache["steps"])}
+    layers = set()
+    for st in stacks:
+        lane = -(-st[-1] // 128) * 128
+        layers |= {st[1:], (1,) + st[1:], st[1:-1] + (lane,)}
+    return stacks, layers
+
+
+_IN_PLACE = [pytest.param(f, p, id=f"{f}-{p}")
+             for f in ("engine_programs", "phi3_programs")
+             for p in ("decode_chunk", "prefill_chunk", "fused_step")]
+
+
+@pytest.fixture(scope="module")
+def compiled_texts():
+    """Compiled program texts, one compile per (engine, program)."""
+    return {}
+
+
+def _compiled(request, compiled_texts, fixture, program):
+    key = (fixture, program)
+    if key not in compiled_texts:
+        programs = request.getfixturevalue(fixture)
+        compiled_texts[key] = (programs[0], _Hlo(
+            _lower(programs, program).compile().as_text()))
+    return compiled_texts[key]
+
+
+@pytest.mark.parametrize("fixture, program", _IN_PLACE)
+def test_engine_programs_alias_every_pool(request, compiled_texts,
+                                          fixture, program):
+    """The cache is donated: every pool leaf enters and leaves the
+    program as one buffer (``input_output_alias``)."""
+    eng, hlo = _compiled(request, compiled_texts, fixture, program)
+    aliased = {int(p) for p in re.findall(r"\(\s*(\d+),\s*\{[^}]*\},\s*"
+                                          r"(?:may|must)-alias", hlo.header)}
+    assert len(hlo.params) == len(jax.tree.leaves(eng._cache))
+    assert set(hlo.params.values()) <= aliased, (hlo.params, aliased)
+
+
+@pytest.mark.parametrize("fixture, program", _IN_PLACE)
+def test_engine_programs_never_copy_a_whole_pool(request, compiled_texts,
+                                                 fixture, program):
+    """No op rebuilds or writes back a stacked pool, and none copies one
+    inside the layer or token loops: the only ops there with a stacked
+    pool's shape are the in-place writes of whole pages (scatters, fused
+    or not).  At phi-3's widths the pools keep the layout they have at
+    rest, with the page offset minor, and nothing copies them at all.
+    The reduced TinyLlama's pools (17 pages of 16 x 16, f32) rest with
+    the page axis minor, as deepseek-4l's 385 pages do, and no write of
+    a page keeps that: they are relaid out once as the program starts
+    and once as it ends, in the entry computation.  Being small, they
+    may also be staged whole into the chip's vector memory (async
+    copies and slices the compiler schedules)."""
+    eng, hlo = _compiled(request, compiled_texts, fixture, program)
+    stacks, _ = _pool_dims(eng)
+    tiny = fixture == "engine_programs"
+    staged = ("copy-start", "copy-done", "slice-start", "slice-done")
+
+    def allowed(n, o):
+        if o["op"] in ("parameter", "get-tuple-element", "bitcast", "tuple",
+                       "while", "conditional", "scatter"):
+            return True
+        if o["op"] == "fusion":
+            return hlo.called_root(n) == "scatter"
+        if not tiny:
+            return False
+        return (o["op"] in staged or "ConcatBitcast" in o["line"]
+                or (o["op"] == "copy" and o["comp"] == hlo.entry))
+
+    bad = [(n, o["op"]) for n, o in hlo.ops.items()
+           if o["dims"] in stacks and not allowed(n, o)]
+    assert not bad, bad
+
+
+@pytest.mark.parametrize("fixture, program", _IN_PLACE)
+def test_engine_programs_read_a_layer_only_for_the_kernel(
+        request, compiled_texts, fixture, program):
+    """A layer's pool is read only to build the kernel's lane-padded
+    operand: every op that outputs a layer's pool (or its padded copy)
+    feeds nothing but the next op of that chain or the kernel, so no
+    layer's pool is copied to be written back."""
+    eng, hlo = _compiled(request, compiled_texts, fixture, program)
+    _, layers = _pool_dims(eng)
+    skip = ("parameter", "get-tuple-element", "tuple", "while",
+            "conditional")
+    fused = {c for n, o in hlo.ops.items() if o["op"] == "fusion"
+             for c in re.findall(r"calls=%([\w.\-]+)", o["line"])}
+
+    def feeds_kernel(name):
+        users = hlo.users.get(name, [])
+        return bool(users) and all(
+            'custom_call_target="tpu_custom_call"' in hlo.ops[u]["line"]
+            or (hlo.ops[u]["dims"] in layers and feeds_kernel(u))
+            for u in users)
+
+    chains = [n for n, o in hlo.ops.items()
+              if o["dims"] in layers and o["op"] not in skip
+              and o["comp"] not in fused]
+    bad = [(n, hlo.ops[n]["op"]) for n in chains if not feeds_kernel(n)]
+    assert chains and not bad, bad
 
 
 # mimov2f-mooncake (MiMo-V2-Flash, bench/configs/mimo-v2-flash-12l.json):
