@@ -45,6 +45,8 @@ FMT_PATHS := benchmarks/check_regression.py \
 verify: lint test check-regression
 	$(MAKE) bench-quick
 
+# the default CI leg (REPRO_ENGINE unset = paged): the serving tests on
+# the engine's one path, the paged cache with chunked prefill
 test:
 	$(PY) -m pytest -x -q
 

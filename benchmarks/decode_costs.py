@@ -409,7 +409,7 @@ def _preemption_rows() -> List[Row]:
                 for i, L in enumerate(lens)]
 
     base = dict(max_seq_len=T, max_batch=4, temperature=0.0,
-                decode_chunk=4, paged=True, page_size=ps)
+                decode_chunk=4, page_size=ps)
     scs = {
         "decode_reserve": ServeConfig(**base),          # ample: full pool
         "decode_preempt_recompute": ServeConfig(
@@ -488,8 +488,7 @@ def _shared_prefix_rows() -> List[Row]:
                 for i, k in enumerate(tails)]
 
     base = dict(max_seq_len=T, max_batch=4, temperature=0.0,
-                decode_chunk=4, paged=True, page_size=ps,
-                chunked_prefill=True, prefill_chunk=ps)
+                decode_chunk=4, page_size=ps, prefill_chunk=ps)
     off = ServingEngine(cfg, params, ServeConfig(**base))
     off.generate(mk_reqs())
     eng = ServingEngine(cfg, params, ServeConfig(**base,
@@ -548,8 +547,7 @@ def mk_reqs():
 
 
 base = dict(max_seq_len=T, max_batch=B, temperature=0.0, decode_chunk=4,
-            paged=True, page_size=ps, chunked_prefill=True,
-            prefill_chunk=8, n_pages=64)
+            page_size=ps, prefill_chunk=8, n_pages=64)
 rows = []
 for name, shards in (("decode_sharded_base", 1),
                      ("decode_sharded_pool", 2),

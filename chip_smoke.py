@@ -83,8 +83,7 @@ def serve_config(plan: Plan, **kw):
     T = plan.max_prompt + plan.max_new
     T = -(-T // plan.page_size) * plan.page_size
     return ServeConfig(max_seq_len=T, max_batch=plan.max_batch,
-                       paged=True, page_size=plan.page_size,
-                       chunked_prefill=True,
+                       page_size=plan.page_size,
                        prefill_chunk=plan.prefill_chunk,
                        prefill_buckets=plan.prefill_buckets, **kw)
 

@@ -50,7 +50,8 @@ print(f"cache bytes/token/layer: {fp.full_bytes} -> "
 prompt = np.arange(12, dtype=np.int32) % cfg.vocab_size
 for label, p in [("full cache ", None), ("kqsvd cache", proj)]:
     eng = ServingEngine(cfg, params, ServeConfig(max_seq_len=64,
-                                                 max_batch=2),
+                                                 max_batch=2,
+                                                 page_size=16),
                         projections=p)
     reqs = [Request(rid=0, prompt=prompt, max_new_tokens=8)]
     eng.generate(reqs)

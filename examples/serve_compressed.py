@@ -29,7 +29,7 @@ for i in range(4):
 w_out = model.group_output_weights(params)
 
 prompt = (np.arange(12) * 5 % cfg.vocab_size).astype(np.int32)
-sc = ServeConfig(max_seq_len=48, max_batch=2)
+sc = ServeConfig(max_seq_len=48, max_batch=2, page_size=16)
 
 ref_eng = ServingEngine(cfg, params, sc)
 ref = [Request(rid=0, prompt=prompt, max_new_tokens=8)]

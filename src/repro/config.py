@@ -481,20 +481,15 @@ class ServeConfig:
     eos_token: Optional[int] = None  # stop generation on this token id
     seed: int = 0
     # paged KV cache (DESIGN.md §paged-cache): fixed-size pages + a
-    # per-slot block table instead of dense (max_batch, max_seq_len)
-    # slots.  n_pages = 0 derives full capacity (no oversubscription);
-    # smaller values oversubscribe HBM and rely on admission
-    # backpressure + freed-page reuse.
-    paged: bool = False
+    # per-slot block table.  n_pages = 0 derives full capacity (no
+    # oversubscription); smaller values oversubscribe HBM and rely on
+    # admission backpressure + freed-page reuse.
     page_size: int = 64             # tokens per page (kernel time block)
     n_pages: int = 0                # allocatable pages; 0 => derive
     # chunked prefill (DESIGN.md §prefill): admission splits prompts
     # into prefill_chunk-sized chunks, pads each to a bucket length
     # (bounding XLA compiles to len(buckets)) and writes the compressed
     # cache straight into pages, interleaved with decode iterations.
-    # Requires paged=True; the exact-length dense-staging path
-    # (chunked_prefill=False) stays as the parity oracle.
-    chunked_prefill: bool = False
     prefill_buckets: Tuple[int, ...] = ()  # () => derive by doubling
     # prefill chunks advanced per engine step(), round-robin, at most
     # one per mid-prefill slot — bounds the latency a decode iteration
@@ -509,9 +504,7 @@ class ServeConfig:
     # slots reach the budget, and one prefill chunk fuses into the
     # decode dispatch (a single device call per step).  Per-step cost
     # is then bounded by max_num_batched_tokens regardless of the
-    # prefill:decode mix.  Requires chunked_prefill (budget truncation
-    # needs chunk-granular prefill; the exact-length and legacy chunked
-    # paths stay the parity oracles).
+    # prefill:decode mix.
     max_num_batched_tokens: int = 0
     # admission policy for the paged pool (DESIGN.md §preemption):
     # "reserve" (PR 2, the parity oracle) admits only when a request's
@@ -540,10 +533,7 @@ class ServeConfig:
     # token chunks (hash-chained over the whole prefix) to physical
     # pages, so admission maps a cached prefix into the block table by
     # reference instead of recomputing prefill; writes into shared
-    # pages copy-on-write fork them.  Requires chunked_prefill (the
-    # shared/unshared boundary must be a chunk start; the exact-length
-    # path always recomputes the whole prompt and stays the parity
-    # oracle).
+    # pages copy-on-write fork them.
     share_prefix: bool = False
     # bound on live prefix-index entries (each pins one page until
     # reclaimed); LRU-evicted beyond this
@@ -589,7 +579,7 @@ class ServeConfig:
     # count *per step* from the live maximum sequence length
     # (kernels.kq_decode.default_decode_splits), snapped down to
     # {1, 2, 4, 8} so the decode dispatch compiles at most four split
-    # variants.  Requires paged=True.
+    # variants.
     decode_splits: int = 1
     # data-axis shards for the serving engine (DESIGN.md
     # §sharded-engine): 1 runs the single-device engine untouched (the
@@ -598,9 +588,9 @@ class ServeConfig:
     # tables, prefix index and sampling key on its own device of a
     # ("data",) mesh, with decode/prefill dispatched as one shard_map
     # computation and a thin global router feeding per-shard
-    # schedulers.  Requires paged chunked prefill on the legacy
-    # scheduler (max_num_batched_tokens == 0), max_batch divisible by
-    # shards, and total_pages divisible by shards.  CPU CI forces
+    # schedulers.  Requires the legacy scheduler
+    # (max_num_batched_tokens == 0), max_batch divisible by shards,
+    # and total_pages divisible by shards.  CPU CI forces
     # devices via XLA_FLAGS=--xla_force_host_platform_device_count=N.
     shards: int = 1
     # page byte format (DESIGN.md §page-layouts): "none" keeps fp pages
@@ -608,9 +598,7 @@ class ServeConfig:
     # "int8" stores int8 data pages plus per-token bf16 scale pools;
     # "svdq" adds per-rank bit allocation on the key side (8/4/2 bits
     # packed into one uint8 stride).  Quantized layouts require
-    # paged=True and compression projections; "svdq" additionally
-    # requires chunked_prefill=True (the exact-length dense staging
-    # path has no packed-page writer).
+    # compression projections.
     cache_quant: str = "none"
 
     def __post_init__(self) -> None:
@@ -618,10 +606,6 @@ class ServeConfig:
             raise ValueError(f"unknown admission policy {self.admission!r}")
         if self.preempt_mode not in ("recompute", "swap"):
             raise ValueError(f"unknown preempt_mode {self.preempt_mode!r}")
-        if self.admission == "optimistic" and not self.paged:
-            raise ValueError(
-                "optimistic admission preempts pages and requires "
-                "paged=True (the dense layout has no pool to run dry)")
         if not 0.0 < self.watermark_high <= 1.0:
             raise ValueError("watermark_high must be in (0, 1]")
         if not 0.0 <= self.watermark_low < 1.0:
@@ -634,50 +618,30 @@ class ServeConfig:
             raise ValueError("admission_retries must be >= 0")
         if not 0.0 <= self.chaos_rate <= 1.0:
             raise ValueError("chaos_rate must be in [0, 1]")
-        if self.share_prefix:
-            if not self.chunked_prefill:
-                raise ValueError(
-                    "share_prefix maps cached prefix pages into the "
-                    "block table and prefills only the unshared tail, "
-                    "which needs chunked_prefill=True (the exact-length "
-                    "path recomputes whole prompts and stays the parity "
-                    "oracle)")
-            if self.prefix_index_capacity < 1:
-                raise ValueError("prefix_index_capacity must be positive")
-        if self.paged:
-            if self.page_size <= 0:
-                raise ValueError("page_size must be positive")
-            if self.max_seq_len % self.page_size:
-                raise ValueError(
-                    f"max_seq_len {self.max_seq_len} must be a multiple of"
-                    f" page_size {self.page_size}")
-        if self.chunked_prefill:
-            if not self.paged:
-                raise ValueError(
-                    "chunked_prefill writes straight into pages and "
-                    "requires paged=True (the dense exact-length path is "
-                    "the parity oracle)")
-            if self.prefill_chunk <= 0:
-                raise ValueError("prefill_chunk must be positive")
-            if self.prefill_chunks_per_step <= 0:
-                raise ValueError("prefill_chunks_per_step must be positive")
-            b = self.buckets
-            if b[-1] != self.prefill_chunk:
-                raise ValueError(
-                    f"largest prefill bucket {b[-1]} must equal "
-                    f"prefill_chunk {self.prefill_chunk} (full chunks "
-                    f"compile at that shape)")
-            if b[0] <= 0:
-                raise ValueError("prefill buckets must be positive")
+        if self.share_prefix and self.prefix_index_capacity < 1:
+            raise ValueError("prefix_index_capacity must be positive")
+        if self.page_size <= 0:
+            raise ValueError("page_size must be positive")
+        if self.max_seq_len % self.page_size:
+            raise ValueError(
+                f"max_seq_len {self.max_seq_len} must be a multiple of"
+                f" page_size {self.page_size}")
+        if self.prefill_chunk <= 0:
+            raise ValueError("prefill_chunk must be positive")
+        if self.prefill_chunks_per_step <= 0:
+            raise ValueError("prefill_chunks_per_step must be positive")
+        b = self.buckets
+        if b[-1] != self.prefill_chunk:
+            raise ValueError(
+                f"largest prefill bucket {b[-1]} must equal "
+                f"prefill_chunk {self.prefill_chunk} (full chunks "
+                f"compile at that shape)")
+        if b[0] <= 0:
+            raise ValueError("prefill buckets must be positive")
         if self.max_num_batched_tokens < 0:
             raise ValueError(
                 "max_num_batched_tokens must be >= 0 (0 disables the "
                 "token-budget scheduler)")
-        if self.max_num_batched_tokens and not self.chunked_prefill:
-            raise ValueError(
-                "max_num_batched_tokens schedules prefill at chunk "
-                "granularity (truncating the last chunk to the residual "
-                "budget) and requires chunked_prefill=True")
         if self.audit_every < 1:
             raise ValueError(
                 "audit_every must be >= 1 (1 audits every step)")
@@ -685,35 +649,13 @@ class ServeConfig:
             raise ValueError(
                 "decode_splits must be >= 0 (0 derives the heuristic, "
                 "1 is the unsplit kernel)")
-        if self.decode_splits != 1 and not self.paged:
-            raise ValueError(
-                "decode_splits splits the paged decode kernel's page "
-                "chain and requires paged=True (the dense path has no "
-                "page chain to split)")
         if self.cache_quant not in ("none", "int8", "svdq"):
             raise ValueError(
                 f"unknown cache_quant {self.cache_quant!r} "
                 f"(none | int8 | svdq)")
-        if self.cache_quant != "none" and not self.paged:
-            raise ValueError(
-                "cache_quant selects a paged page layout "
-                "(DESIGN.md §page-layouts) and requires paged=True; "
-                "dense int8 is selected on the ModelConfig instead")
-        if self.cache_quant == "svdq" and not self.chunked_prefill:
-            raise ValueError(
-                "cache_quant='svdq' packs sub-byte ranks at page-write "
-                "time and requires chunked_prefill=True (the "
-                "exact-length dense staging path has no packed-page "
-                "writer)")
         if self.shards < 1:
             raise ValueError("shards must be >= 1 (1 = unsharded oracle)")
         if self.shards > 1:
-            if not (self.paged and self.chunked_prefill):
-                raise ValueError(
-                    "shards > 1 partitions the paged slot/page axes over "
-                    "a data mesh and requires paged=True and "
-                    "chunked_prefill=True (the dense and exact-length "
-                    "paths stay single-device parity oracles)")
             if self.max_num_batched_tokens:
                 raise ValueError(
                     "shards > 1 runs the legacy per-request scheduler "

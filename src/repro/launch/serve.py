@@ -99,11 +99,9 @@ def main() -> None:
                     help="tokens per fused on-device decode scan")
     ap.add_argument("--calib-seqs", type=int, default=8)
     ap.add_argument("--calib-len", type=int, default=64)
-    ap.add_argument("--paged", action="store_true",
-                    help="paged KV cache: page pool + block tables "
-                         "(DESIGN.md §paged-cache)")
     ap.add_argument("--page-size", type=int, default=16,
-                    help="tokens per page (with --paged)")
+                    help="tokens per page of the paged KV cache "
+                         "(DESIGN.md §paged-cache)")
     ap.add_argument("--n-pages", type=int, default=0,
                     help="pool size; 0 derives full capacity, smaller "
                          "oversubscribes with admission backpressure")
@@ -115,27 +113,24 @@ def main() -> None:
                          "serves the whole batch.  Needs >= shards "
                          "devices (CPU: XLA_FLAGS=--xla_force_host_"
                          "platform_device_count=N before launch).  "
-                         "Implies --paged and chunked prefill.  1 = "
-                         "unsharded parity oracle.")
+                         "1 = unsharded parity oracle.")
     ap.add_argument("--cache-quant", default="none",
                     choices=["none", "int8", "svdq"],
                     help="paged page layout (DESIGN.md §page-layouts): "
                          "int8 = int8 pages + per-page scale pools with "
                          "dequantize-on-the-fly decode; svdq = per-rank "
                          "key bits allocated from the calibrated "
-                         "spectrum, packed sub-byte.  Implies --paged "
-                         "(svdq also chunked prefill); needs a "
+                         "spectrum, packed sub-byte.  Needs a "
                          "compressed --method to take effect.")
     ap.add_argument("--decode-splits", type=int, default=1,
                     help="split-KV flash-decoding fan-out (DESIGN.md "
                          "§split-kv): >1 = fixed, 0 = re-derived per "
                          "step from the live max length (snapped to "
-                         "{1,2,4,8}), 1 = unsplit oracle.  Implies "
-                         "--paged.")
-    ap.add_argument("--prefill-chunk", type=int, default=0,
-                    help="chunked prefill straight into pages (DESIGN.md "
-                         "§prefill): chunk size in tokens; 0 keeps the "
-                         "exact-length parity path.  Implies --paged.")
+                         "{1,2,4,8}), 1 = unsplit oracle.")
+    ap.add_argument("--prefill-chunk", type=int,
+                    default=ServeConfig.prefill_chunk,
+                    help="tokens per prefill chunk written straight "
+                         "into pages (DESIGN.md §prefill)")
     ap.add_argument("--prefill-buckets", default="",
                     help="comma-separated padded chunk lengths (largest "
                          "must equal --prefill-chunk); empty derives by "
@@ -146,16 +141,14 @@ def main() -> None:
                          "token, prefill chunks fill the remainder "
                          "(the last chunk truncated to it) and one "
                          "chunk fuses into the decode dispatch.  0 = "
-                         "legacy per-request scheduling.  Implies "
-                         "chunked prefill (and so --paged).")
+                         "legacy per-request scheduling.")
     ap.add_argument("--admission", default="reserve",
                     choices=["reserve", "optimistic"],
-                    help="paged admission policy (DESIGN.md §preemption):"
-                         " reserve = worst-case page reservation (the "
+                    help="admission policy (DESIGN.md §preemption): "
+                         "reserve = worst-case page reservation (the "
                          "parity oracle); optimistic = admit on the "
                          "prompt footprint and preempt-and-requeue LIFO "
-                         "victims when the pool runs dry.  Implies "
-                         "--paged.")
+                         "victims when the pool runs dry.")
     ap.add_argument("--preempt-mode", default="recompute",
                     choices=["recompute", "swap"],
                     help="victim handling under --admission optimistic: "
@@ -174,8 +167,7 @@ def main() -> None:
                     help="cross-request prefix sharing with copy-on-"
                          "write (DESIGN.md §prefix-sharing): admission "
                          "maps cached prefix pages into the block table "
-                         "by reference instead of re-prefilling them.  "
-                         "Implies --paged and chunked prefill.")
+                         "by reference instead of re-prefilling them.")
     ap.add_argument("--prefix-index-capacity", type=int, default=512,
                     help="max live prefix-index entries (each pins one "
                          "page until reclaimed; LRU beyond this)")
@@ -205,45 +197,11 @@ def main() -> None:
                     help="per-hit fault probability under --chaos-seed")
     args = ap.parse_args()
     enable_compile_cache()
-    if args.shards > 1 and not args.prefill_chunk:
-        print("--shards shards the chunked-prefill dispatch: enabling "
-              "chunked prefill (--prefill-chunk 8)")
-        args.prefill_chunk = 8
     if args.shards > 1 and args.n_pages % args.shards:
         n = -(-args.n_pages // args.shards) * args.shards
         print(f"--shards needs equal per-shard pools: rounding "
               f"--n-pages {args.n_pages} up to {n}")
         args.n_pages = n
-    if args.max_batched_tokens and not args.prefill_chunk:
-        print("--max-batched-tokens schedules prefill at chunk "
-              "granularity: enabling chunked prefill "
-              "(--prefill-chunk 8)")
-        args.prefill_chunk = 8
-    if args.share_prefix and not args.prefill_chunk:
-        print("--share-prefix prefills only the unshared tail: enabling "
-              "chunked prefill (--prefill-chunk 8)")
-        args.prefill_chunk = 8
-    if args.prefill_buckets and not args.prefill_chunk:
-        ap.error("--prefill-buckets requires --prefill-chunk")
-    if args.cache_quant == "svdq" and not args.prefill_chunk:
-        print("--cache-quant svdq packs sub-byte ranks at page-write "
-              "time: enabling chunked prefill (--prefill-chunk 8)")
-        args.prefill_chunk = 8
-    if args.cache_quant != "none" and not args.paged:
-        print("--cache-quant selects a paged page layout: enabling "
-              "--paged")
-        args.paged = True
-    if args.decode_splits != 1 and not args.paged:
-        print("--decode-splits splits the paged page chain: enabling "
-              "--paged")
-        args.paged = True
-    if args.prefill_chunk and not args.paged:
-        print("--prefill-chunk writes straight into pages: enabling "
-              "--paged")
-        args.paged = True
-    if args.admission == "optimistic" and not args.paged:
-        print("--admission optimistic preempts pages: enabling --paged")
-        args.paged = True
 
     cfg = get_config(args.arch)
     if args.reduced:
@@ -259,15 +217,13 @@ def main() -> None:
               f"v={proj.ranks_v}; cache ratio {fp.ratio:.3f}")
 
     T = args.prompt_len + args.max_new_tokens + 8
-    if args.paged:   # logical capacity must be whole pages
-        T = -(-T // args.page_size) * args.page_size
+    T = -(-T // args.page_size) * args.page_size   # whole pages
     buckets = tuple(int(x) for x in args.prefill_buckets.split(",")
                     if x.strip())
     sc = ServeConfig(max_seq_len=T, max_batch=8,
-                     decode_chunk=args.decode_chunk, paged=args.paged,
+                     decode_chunk=args.decode_chunk,
                      page_size=args.page_size, n_pages=args.n_pages,
-                     chunked_prefill=bool(args.prefill_chunk),
-                     prefill_chunk=args.prefill_chunk or 512,
+                     prefill_chunk=args.prefill_chunk,
                      prefill_buckets=buckets,
                      admission=args.admission,
                      preempt_mode=args.preempt_mode,
@@ -316,54 +272,52 @@ def main() -> None:
               f"{list(fired) or 'no points'}; "
               f"retries={eng.n_retried}, "
               f"swap fallbacks={eng.n_swap_fallbacks}")
-    if args.paged:
-        pool = eng.pool
-        print(f"page pool: {pool.n_pages} x {args.page_size}-token "
-              f"pages, {pool.free_count} free after drain")
-        if args.shards > 1:
-            # pooled capacity across the data mesh: shards x the
-            # per-shard physical pool (already scaled by the layout's
-            # resident-capacity multiplier, DESIGN.md §sharded-engine)
-            print(f"sharded: {args.shards} shard(s) x "
-                  f"{eng._local_phys} physical page(s) = "
-                  f"{pool.n_pages} pooled "
-                  f"(x{eng.workers[0].capacity_x:.2f} resident "
-                  f"capacity multiplier); per-shard occupancy: "
-                  + ", ".join(
-                      f"s{w._shard}={w.pool.used_count}"
-                      f"/{w.pool.n_pages}" for w in eng.workers))
-        print(f"admission={args.admission}: preemptions="
-              f"{eng.n_preempted} (swap out/in {eng.n_swapped_out}/"
-              f"{eng.n_swapped_in}), failed={eng.n_failed}")
-        if args.cache_quant != "none":
-            # page-layout capacity story (DESIGN.md §page-layouts):
-            # packed vs fp bytes per cached token at the served ranks
-            from repro.serving.page_layouts import FpLayout, get_layout
-            lay = get_layout(eng.cfg)
-            rk, rv = eng.ranks
-            if eng.cfg.cache_quant == "none":
-                print(f"cache quant {args.cache_quant}: inert "
-                      f"(no compression projections; fp pages served)")
-            else:
-                fp = FpLayout()
-                packed = (lay.token_bytes("k", rk)
-                          + lay.token_bytes("v", rv))
-                full = (fp.token_bytes("k", rk)
-                        + fp.token_bytes("v", rv))
-                print(f"cache quant {args.cache_quant}: "
-                      f"{packed} packed vs {full} fp byte(s)/token "
-                      f"-> {full / packed:.2f}x resident capacity")
-        if args.share_prefix:
-            print(f"prefix sharing: {eng.n_shared_pages} page(s) / "
-                  f"{eng.n_shared_tokens} token(s) shared, "
-                  f"{eng.n_full_hits} whole-prompt hit(s), "
-                  f"{eng.n_cow_forks} COW fork(s), "
-                  f"{eng.n_reclaimed} index entr(ies) reclaimed; "
-                  f"peak pool occupancy {eng.peak_used_pages} page(s)")
-    if args.prefill_chunk:
-        print(f"prefill compiles: {len(eng.prefill_chunk_shapes)} chunk "
-              f"shape(s) {sorted(eng.prefill_chunk_shapes)} of "
-              f"{len(sc.buckets)} bucket(s) {list(sc.buckets)}")
+    pool = eng.pool
+    print(f"page pool: {pool.n_pages} x {args.page_size}-token "
+          f"pages, {pool.free_count} free after drain")
+    if args.shards > 1:
+        # pooled capacity across the data mesh: shards x the
+        # per-shard physical pool (already scaled by the layout's
+        # resident-capacity multiplier, DESIGN.md §sharded-engine)
+        print(f"sharded: {args.shards} shard(s) x "
+              f"{eng._local_phys} physical page(s) = "
+              f"{pool.n_pages} pooled "
+              f"(x{eng.workers[0].capacity_x:.2f} resident "
+              f"capacity multiplier); per-shard occupancy: "
+              + ", ".join(
+                  f"s{w._shard}={w.pool.used_count}"
+                  f"/{w.pool.n_pages}" for w in eng.workers))
+    print(f"admission={args.admission}: preemptions="
+          f"{eng.n_preempted} (swap out/in {eng.n_swapped_out}/"
+          f"{eng.n_swapped_in}), failed={eng.n_failed}")
+    if args.cache_quant != "none":
+        # page-layout capacity story (DESIGN.md §page-layouts):
+        # packed vs fp bytes per cached token at the served ranks
+        from repro.serving.page_layouts import FpLayout, get_layout
+        lay = get_layout(eng.cfg)
+        rk, rv = eng.ranks
+        if eng.cfg.cache_quant == "none":
+            print(f"cache quant {args.cache_quant}: inert "
+                  f"(no compression projections; fp pages served)")
+        else:
+            fp = FpLayout()
+            packed = (lay.token_bytes("k", rk)
+                      + lay.token_bytes("v", rv))
+            full = (fp.token_bytes("k", rk)
+                    + fp.token_bytes("v", rv))
+            print(f"cache quant {args.cache_quant}: "
+                  f"{packed} packed vs {full} fp byte(s)/token "
+                  f"-> {full / packed:.2f}x resident capacity")
+    if args.share_prefix:
+        print(f"prefix sharing: {eng.n_shared_pages} page(s) / "
+              f"{eng.n_shared_tokens} token(s) shared, "
+              f"{eng.n_full_hits} whole-prompt hit(s), "
+              f"{eng.n_cow_forks} COW fork(s), "
+              f"{eng.n_reclaimed} index entr(ies) reclaimed; "
+              f"peak pool occupancy {eng.peak_used_pages} page(s)")
+    print(f"prefill compiles: {len(eng.prefill_chunk_shapes)} chunk "
+          f"shape(s) {sorted(eng.prefill_chunk_shapes)} of "
+          f"{len(sc.buckets)} bucket(s) {list(sc.buckets)}")
     if args.max_batched_tokens:
         # per-step budget accounting (DESIGN.md §scheduler): how the
         # global token budget split between decode charges and prefill

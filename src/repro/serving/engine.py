@@ -14,20 +14,25 @@ scheduled as explicit ``step()`` iterations (sarathi-style):
 * slots whose request finished are refilled from the pending queue at
   the next chunk boundary while the other slots keep decoding.
 
-Two cache layouts (``ServeConfig.paged``):
+The cache is paged and prompts prefill in chunks (DESIGN.md
+§paged-cache, §prefill).  Each layer's cache is a pool of fixed-size
+pages shared by all slots through a block table.  Admission allocates
+``ceil(prompt/page_size)`` pages on demand (with backpressure when the
+pool is short), ``decode_chunk`` headroom is allocated at each chunk
+boundary so sequences grow page-by-page, and finished slots return
+their pages to the pool without draining the batch — HBM scales with
+*occupied pages*, not ``max_batch * max_seq_len``.  Prompts split into
+``prefill_chunk``-sized chunks padded to a small set of bucket lengths
+(at most ``len(buckets)`` prefill compiles per engine lifetime) that
+write the compressed ``R_k/R_v`` entries straight into pages, a few
+chunks per ``step()``, so other slots keep decoding while a long
+prompt prefills.  Partially-prefilled slots hold their pages and join
+decode only when complete; their block-table rows export as the
+garbage page to the decode scan, so its masked writes cannot touch
+pages the prefill is filling.  The model's own ``prefill`` /
+``decode_step`` over a dense cache is the tests' reference for both.
 
-* **dense** (default, the parity reference): every slot owns a
-  ``max_seq_len`` lane, so HBM scales with the worst-case request;
-* **paged** (DESIGN.md §paged-cache): each layer's cache is a pool of
-  fixed-size pages shared by all slots through a block table.
-  Admission allocates ``ceil(prompt/page_size)`` pages on demand (with
-  backpressure when the pool is short), ``decode_chunk`` headroom is
-  allocated at each chunk boundary so sequences grow page-by-page, and
-  finished slots return their pages to the pool without draining the
-  batch — HBM scales with *occupied pages*, not
-  ``max_batch * max_seq_len``.
-
-Two paged admission policies (``ServeConfig.admission``, DESIGN.md
+Two admission policies (``ServeConfig.admission``, DESIGN.md
 §preemption):
 
 * **reserve** (default, the parity oracle): admission reserves the
@@ -51,25 +56,8 @@ and the rest of the batch keeps serving (no mid-serve raise), and
 ``_admit`` scans a bounded ``admit_window`` of the pending queue so a
 small request is not head-of-line blocked behind a big one.
 
-Two prefill paths (``ServeConfig.chunked_prefill``, DESIGN.md §prefill):
-
-* **exact-length** (default, the parity oracle): each request prefills
-  alone at its exact prompt length — one XLA compile per distinct
-  length — and (paged) stages the cache through a dense
-  ``(1, max_seq_len)`` buffer before repaging;
-* **chunked** (requires paged): prompts split into
-  ``prefill_chunk``-sized chunks padded to a small set of bucket
-  lengths (at most ``len(buckets)`` prefill compiles per engine
-  lifetime) that write the compressed ``R_k/R_v`` entries straight
-  into pages — no staging buffer — and are scheduled a few chunks per
-  ``step()`` so other slots keep decoding while a long prompt
-  prefills.  Partially-prefilled slots hold their pages and join
-  decode only when complete; their block-table rows export as the
-  garbage page to the decode scan, so its masked writes cannot touch
-  pages the prefill is filling.
-
 Cross-request prefix sharing (``ServeConfig.share_prefix``, DESIGN.md
-§prefix-sharing, requires chunked+paged): pages are refcounted and a
+§prefix-sharing): pages are refcounted and a
 host-side prefix index maps chained hashes of page-aligned token
 chunks to the physical pages already holding their (compressed) cache
 entries.  Admission maps the longest cached prefix into the new slot's
@@ -301,8 +289,8 @@ class ServingEngine:
                  faults: Optional[FaultInjector] = None):
         # the serve config owns the paged page layout (DESIGN.md
         # §page-layouts): fold it into the model config before
-        # build_model so every attention path — prefill staging,
-        # chunked prefill, decode — resolves the same layout.
+        # build_model so chunked prefill and decode resolve the same
+        # layout.
         # Quantized layouts compress the projected R_k/R_v page
         # entries (the paper's setting); a full-cache engine (no
         # projections) has none, so it keeps serving fp pages and the
@@ -329,27 +317,18 @@ class ServingEngine:
         # pool itself are all sized from the physical count — fp
         # layouts keep capacity_x == 1.0 and stay bitwise unchanged.
         self.capacity_x = self._capacity_multiplier()
-        if sc.paged:
-            self._validate_paged()
+        self._validate_paged()
         # split-KV flash-decoding fan-out (DESIGN.md §split-kv): a
         # fixed positive count is resolved once at construction; 0
         # re-derives the count per step from the live maximum sequence
         # length, snapped down to {1, 2, 4, 8} so the decode dispatch
         # compiles at most four split variants
-        self._decode_splits = 1
-        self._dynamic_splits = False
-        if sc.paged:
-            if sc.decode_splits:
-                self._decode_splits = sc.decode_splits
-            else:
-                self._dynamic_splits = True
-        self._prefill = jax.jit(self._prefill_impl)
+        self._decode_splits = sc.decode_splits or 1
+        self._dynamic_splits = not sc.decode_splits
         # every program that takes the cache donates it: its pools are
         # updated in place and the caller's old cache is deleted
         # (DESIGN.md §paged-cache)
         donate = functools.partial(jax.jit, donate_argnames=("cache",))
-        self._insert = donate(self._insert_impl)
-        self._paged_insert = donate(self._paged_insert_impl)
         self._prefill_chunk = donate(self._prefill_chunk_impl)
         self._decode_chunk = donate(self._decode_chunk_impl,
                                     static_argnames=("num_splits",))
@@ -369,8 +348,7 @@ class ServingEngine:
         The ratio of fp token bytes to the layout's token bytes at the
         engine's ranks (page_layouts ``token_bytes``); 1.0 for fp pages
         or when serving without projections (no quantized layout)."""
-        if not self.sc.paged or self.ranks[0] == 0 \
-                or self.sc.cache_quant == "none":
+        if self.ranks[0] == 0 or self.sc.cache_quant == "none":
             return 1.0
         layout = get_layout(self.cfg)
         rk, rv = self.ranks
@@ -400,12 +378,11 @@ class ServingEngine:
                 "paged serving: sliding window not supported (windowed "
                 "kinds of ModelConfig.attn_kinds are, on page rings)")
         if cfg.ring_window or cfg.routing is not None:
-            # window rings (DESIGN.md §window-rings) are written by
-            # chunked prefill only and hold no shareable, swappable or
-            # shardable pages; the held-expert count rides the token
-            # stream of the single-device decode scan
-            unsupported = {"chunked_prefill=False": not sc.chunked_prefill,
-                           "share_prefix": sc.share_prefix,
+            # window rings (DESIGN.md §window-rings) hold no
+            # shareable, swappable or shardable pages; the held-expert
+            # count rides the token stream of the single-device decode
+            # scan
+            unsupported = {"share_prefix": sc.share_prefix,
                            "preempt_mode='swap'": sc.preempt_mode == "swap",
                            "shards > 1": sc.shards > 1,
                            f"cache_quant={sc.cache_quant!r}":
@@ -414,14 +391,14 @@ class ServingEngine:
             if bad:
                 raise NotImplementedError(
                     f"{cfg.name}: window rings and held-expert layers "
-                    f"are served on bf16 pages with chunked prefill, "
-                    f"unshared and unsharded; not with {', '.join(bad)}")
+                    f"are served on bf16 pages, unshared and "
+                    f"unsharded; not with {', '.join(bad)}")
         if cfg.cache_quant != "none" and self.sc.cache_quant == "none":
             raise NotImplementedError(
                 "paged serving selects its page layout via "
                 "ServeConfig.cache_quant (DESIGN.md §page-layouts); "
-                "ModelConfig.cache_quant alone configures the *dense* "
-                "int8 cache only")
+                "ModelConfig.cache_quant alone configures the model's "
+                "dense int8 cache only")
 
     def _splits_for_step(self, live_max: int) -> int:
         """Static split count for one decode dispatch.
@@ -445,21 +422,13 @@ class ServingEngine:
         """Split count for the chunk about to dispatch: the live slots'
         deepest position plus the chunk's growth is the most cache the
         scan can touch."""
-        if not self.sc.paged or not self._dynamic_splits:
+        if not self._dynamic_splits:
             return self._decode_splits
         pos_np = _to_host(self._pos, "pos")
         live_max = int(pos_np[live].max()) if live.any() else 1
         return self._splits_for_step(live_max + self.sc.decode_chunk)
 
     # -- jitted internals ---------------------------------------------------
-
-    def _prefill_impl(self, params, proj, tokens):
-        """One request at its exact prompt length -> (logits, slot cache)."""
-        batch = {"tokens": tokens}
-        if self.proj is not None:
-            return self.model.prefill(params, batch, self.sc.max_seq_len,
-                                      proj=proj)
-        return self.model.prefill(params, batch, self.sc.max_seq_len)
 
     def _prefill_chunk_impl(self, params, proj, cache, tokens, pos0,
                             n_valid, btab_row):
@@ -485,68 +454,6 @@ class ServingEngine:
                 logits, (n_valid - 1)[:, None, None], axis=1)[:, 0]
         return last, cache
 
-    def _insert_impl(self, cache, slot_cache, slot):
-        """Write a single-sequence cache into batch slot ``slot``."""
-        def _at_batch0(big, small):
-            return jax.lax.dynamic_update_slice_in_dim(
-                big, small.astype(big.dtype), slot, 0)
-
-        def _at_batch1(big, small):          # scanned steps: (n_steps, B, ...)
-            return jax.lax.dynamic_update_slice_in_dim(
-                big, small.astype(big.dtype), slot, 1)
-
-        out = {"prefix": jax.tree.map(_at_batch0, cache["prefix"],
-                                      slot_cache["prefix"])}
-        out["steps"] = (jax.tree.map(_at_batch1, cache["steps"],
-                                     slot_cache["steps"])
-                        if cache["steps"] is not None else None)
-        return out
-
-    def _paged_insert_impl(self, cache, slot_cache, phys):
-        """Scatter a prefilled slot cache into the page pools.
-
-        ``slot_cache`` leaves are dense (1, Hkv, T, R) (the exact-length
-        prefill contract is unchanged); they are cut into
-        (T / page_size) pages and the first ``len(phys)`` — the pages
-        the prompt occupies — are written at the allocated physical
-        ids.  Int8-layout staging additionally carries (1, Hkv, T)
-        scale leaves (the dense int8 prefill contract), repaged into
-        the (P, Hkv, ps, 1) scale pools in lockstep with their data
-        pages.  Compiles once per distinct page count, same as prefill
-        per distinct length.  The chunked path writes pages directly
-        and never builds this staging buffer."""
-        ps = self.sc.page_size
-        n = phys.shape[0]
-
-        def _repage0(pool, dense):           # dense (1, Hkv, T[, R])
-            if dense.ndim == 3:              # scale leaf: (1, Hkv, T)
-                hkv, t = dense.shape[1:]
-                pages = dense[0].reshape(hkv, t // ps, ps).transpose(
-                    1, 0, 2)[..., None]
-                return pool.at[phys].set(pages[:n].astype(pool.dtype))
-            hkv, t, r = dense.shape[1:]
-            pages = dense[0].reshape(hkv, t // ps, ps, r).transpose(
-                1, 0, 2, 3)
-            return pool.at[phys].set(pages[:n].astype(pool.dtype))
-
-        def _repage1(pool, dense):           # (n_steps, 1, Hkv, T[, R])
-            if dense.ndim == 4:              # scale leaf
-                nl, _, hkv, t = dense.shape
-                pages = dense[:, 0].reshape(nl, hkv, t // ps, ps).transpose(
-                    0, 2, 1, 3)[..., None]
-                return pool.at[:, phys].set(pages[:, :n].astype(pool.dtype))
-            nl, _, hkv, t, r = dense.shape
-            pages = dense[:, 0].reshape(nl, hkv, t // ps, ps, r).transpose(
-                0, 2, 1, 3, 4)
-            return pool.at[:, phys].set(pages[:, :n].astype(pool.dtype))
-
-        out = {"prefix": jax.tree.map(_repage0, cache["prefix"],
-                                      slot_cache["prefix"])}
-        out["steps"] = (jax.tree.map(_repage1, cache["steps"],
-                                     slot_cache["steps"])
-                        if cache["steps"] is not None else None)
-        return out
-
     def _fork_page_impl(self, cache, src, dst):
         """Copy physical page ``src`` to ``dst`` in every layer's pools
         (the device half of a copy-on-write fork; the host half
@@ -571,8 +478,7 @@ class ServingEngine:
         logits: (B, V) next-token logits per slot; pos: (B,) index where
         each slot's next token will be written (== live length); the
         sampled-token / emit-mask streams come back (N, B).
-        ``block_table`` is None for the dense cache.  ``num_splits``
-        (static) selects split-KV flash-decoding in the paged path —
+        ``num_splits`` (static) selects split-KV flash-decoding —
         ``_splits_for_step`` resolves it per dispatch."""
         T = self.sc.max_seq_len
         temp = self.sc.temperature
@@ -585,11 +491,10 @@ class ServingEngine:
 
         def _decode(cache, tokens, fpos, live):
             kw: Dict[str, Any] = {"block_table": block_table,
-                                  "token_mask": live}
+                                  "token_mask": live,
+                                  "num_splits": num_splits}
             if self.proj is not None:
                 kw["proj"] = proj
-            if block_table is not None:
-                kw["num_splits"] = num_splits
             return self.model.decode_step(params, cache, tokens, fpos,
                                           with_aux=counts, **kw)
 
@@ -610,7 +515,7 @@ class ServingEngine:
             done = done | full
             active = ~done
             feed_pos = jnp.minimum(pos, T - 1)  # done slots: harmless write
-            # (paged: a freed or mid-prefill slot's block-table row
+            # (a freed or mid-prefill slot's block-table row
             # points at the garbage page, so the masked write cannot
             # touch pages that were recycled to other sequences or that
             # a concurrent chunked prefill is filling)
@@ -682,7 +587,7 @@ class ServingEngine:
     def start(self, requests: List[Request]) -> None:
         """Initialize serving state for a batch of requests.
 
-        Allocates the (dense or paged) cache and the per-slot decode
+        Allocates the paged cache and the per-slot decode
         state; ``step()`` then advances admission / prefill / decode one
         scheduling iteration at a time (``generate`` is the drain
         loop)."""
@@ -720,29 +625,22 @@ class ServingEngine:
         #                            prefix pages are charged to nobody
         #                            (they exist once, whoever shares them)
         self._private = [0] * B    # pages currently allocated (not shared)
-        self.pool = None           # introspection (tests/bench)
-        self._btabs = None
-        self._pindex = None
-        if sc.paged:
-            # pool and cache are sized in *physical* pages: the fp-unit
-            # HBM budget times the layout's capacity multiplier, so
-            # watermarks and worst-case reservation stop under-admitting
-            # quantized pools (satellite of DESIGN.md §page-layouts)
-            n_phys = self._pool_pages()
-            self.pool = PagePool(n_phys, sc.watermark_high,
-                                 sc.watermark_low)
-            self.pool.faults = self.faults
-            ring = (ring_pages(self.cfg.ring_window, sc.page_size)
-                    if self.cfg.ring_window else 0)
-            self._btabs = BlockTables(B, sc.pages_per_seq, ring)
-            self._cache = self.model.init_paged_cache(
-                n_phys + 1, sc.page_size, self.ranks, ring_slots=B)
-            if sc.share_prefix:
-                # per-batch prefix index (DESIGN.md §prefix-sharing):
-                # reset with the pool, since its entries pin pool pages
-                self._pindex = PrefixIndex(sc.prefix_index_capacity)
-        else:
-            self._cache = self.model.init_cache(B, T, self.ranks)
+        # pool and cache are sized in *physical* pages: the fp-unit
+        # HBM budget times the layout's capacity multiplier, so
+        # watermarks and worst-case reservation stop under-admitting
+        # quantized pools (satellite of DESIGN.md §page-layouts)
+        n_phys = self._pool_pages()
+        self.pool = PagePool(n_phys, sc.watermark_high, sc.watermark_low)
+        self.pool.faults = self.faults
+        ring = (ring_pages(self.cfg.ring_window, sc.page_size)
+                if self.cfg.ring_window else 0)
+        self._btabs = BlockTables(B, sc.pages_per_seq, ring)
+        self._cache = self.model.init_paged_cache(
+            n_phys + 1, sc.page_size, self.ranks, ring_slots=B)
+        # per-batch prefix index (DESIGN.md §prefix-sharing): reset
+        # with the pool, since its entries pin pool pages
+        self._pindex = (PrefixIndex(sc.prefix_index_capacity)
+                        if sc.share_prefix else None)
         # preemption bookkeeping (DESIGN.md §preemption)
         self._stamp = [0] * B      # admission order per slot (LIFO victims)
         self._admit_seq = 0
@@ -800,8 +698,8 @@ class ServingEngine:
         # plus — for a recompute-preempted victim — the tokens it had
         # already generated, carried as prompt suffix
         self._slot_prompt: List[Optional[np.ndarray]] = [None] * B
-        # chunked prefill: prompt tokens already written per slot
-        # (None = slot empty or fully prefilled)
+        # prompt tokens already written per slot (None = slot empty
+        # or fully prefilled)
         self._prefilled: List[Optional[int]] = [None] * B
         self._pf_next = 0          # round-robin cursor over prefill slots
         # budget scheduler only: while set, _activate defers into this
@@ -1095,7 +993,7 @@ class ServingEngine:
                                        self._prompt_logits[b])
 
     def _release(self, b: int, finished: bool = False) -> None:
-        if self.sc.paged and finished and self._pindex is not None:
+        if finished and self._pindex is not None:
             self._index_terminal(b)
         self._slot_req[b] = None
         self._slot_prompt[b] = None
@@ -1103,14 +1001,13 @@ class ServingEngine:
         self._prompt_logits[b] = None
         self._chain_key[b] = PrefixIndex.ROOT
         self._indexed_upto[b] = 0
-        if self.sc.paged:
-            # page references drop without draining the batch (shared
-            # pages survive via their other sharers / the index); the
-            # row resets to the garbage page
-            self._btabs.release(b, self.pool)
-            self._reserved[b] = 0
-            self._charged[b] = 0
-            self._private[b] = 0
+        # page references drop without draining the batch (shared pages
+        # survive via their other sharers / the index); the row resets
+        # to the garbage page
+        self._btabs.release(b, self.pool)
+        self._reserved[b] = 0
+        self._charged[b] = 0
+        self._private[b] = 0
 
     def _fits_now(self, r: Request, worst_private: int,
                   shared: tuple) -> bool:
@@ -1189,22 +1086,21 @@ class ServingEngine:
                     f"prompt length {len(r.prompt)} exceeds "
                     f"max_seq_len {sc.max_seq_len}")
                 continue
-            if sc.paged:
-                worst = self._worst_case_pages(r)
-                if worst > self.pool.n_pages:
-                    # infeasible even alone: its distinct pages (shared
-                    # or not) can never fit the pool simultaneously
-                    self._fail_request(
-                        r, "oversize",
-                        f"worst case {worst} pages exceeds the "
-                        f"{self.pool.n_pages}-page pool")
-                    continue
-                shared = self._probe_share(r)
-                worst_private = worst - shared[0] + shared[2]
-                if not self._fits_now(r, worst_private, shared):
-                    i += 1
-                    scanned += 1
-                    continue
+            worst = self._worst_case_pages(r)
+            if worst > self.pool.n_pages:
+                # infeasible even alone: its distinct pages (shared or
+                # not) can never fit the pool simultaneously
+                self._fail_request(
+                    r, "oversize",
+                    f"worst case {worst} pages exceeds the "
+                    f"{self.pool.n_pages}-page pool")
+                continue
+            shared = self._probe_share(r)
+            worst_private = worst - shared[0] + shared[2]
+            if not self._fits_now(r, worst_private, shared):
+                i += 1
+                scanned += 1
+                continue
             return self._pending.pop(i)
         return None
 
@@ -1215,9 +1111,7 @@ class ServingEngine:
         scheduler admits only while total occupancy stays within the
         per-step token budget; None = every free slot).
 
-        Exact-length path: prefill the whole (effective) prompt now
-        (one compile per distinct length) and insert.  Chunked path:
-        match the longest cached prefix in the index (those pages map
+        Match the longest cached prefix in the index (those pages map
         into the block table by reference — no recompute), allocate
         only the private tail's pages, and queue the slot for
         chunk-by-chunk prefill from the first unshared token —
@@ -1244,114 +1138,101 @@ class ServingEngine:
             self._slot_prompt[b] = prompt
             self._stamp[b] = self._admit_seq
             self._admit_seq += 1
+            ps = sc.page_size
+            L = len(prompt)
+            shared: List[int] = []
+            shared_tokens = full_tokens = 0
+            chain = PrefixIndex.ROOT
+            fork = 0
             slog = None
-            if sc.paged:
-                ps = sc.page_size
-                L = len(prompt)
-                shared: List[int] = []
-                shared_tokens = full_tokens = 0
-                chain = PrefixIndex.ROOT
-                fork = 0
-                if self._pindex is not None and id(r) not in self._swapped:
-                    hits, chain, full_tokens, slog = self._pindex.walk(
-                        prompt, ps)
-                    # same cap/fork rule the admission probe used, so
-                    # the charge matches what _fits_now checked
-                    kept, shared_tokens, fork, slog = self._cap_share(
-                        L, hits, slog)
-                    shared = [p for _, p, _ in kept]
-                    if shared:
-                        self._pindex.touch([k for k, _, _ in kept])
-                        self.pool.share(shared)
-                self._reserved[b] = self._worst_case_pages(r)
-                self._charged[b] = self._reserved[b] - len(shared) + fork
-                n_priv = pages_needed(L, ps) - len(shared)
-                try:
-                    phys = self._alloc(n_priv)
-                except PagePoolExhausted:
-                    # accounting said it fit but the pool disagrees
-                    # (another admission this pass consumed the
-                    # headroom, or an injected alloc fault): roll the
-                    # admission back and retry with exponential
-                    # backoff; a request whose retry budget is spent
-                    # fails terminally (pool_exhausted) instead of
-                    # waiting forever
-                    if shared:
-                        self.pool.free(shared)
-                    self._slot_req[b] = None
-                    self._slot_prompt[b] = None
-                    self._reserved[b] = 0
-                    self._charged[b] = 0
-                    n_tries, _ = self._retry.get(id(r), (0, 0))
-                    if n_tries >= sc.admission_retries:
-                        self._fail_request(
-                            r, "pool_exhausted",
-                            f"admission allocation failed "
-                            f"{n_tries + 1} times (backoff spent)")
-                        continue
-                    self._retry[id(r)] = (
-                        n_tries + 1,
-                        self._step_count + min(1 << n_tries, 32))
-                    self.n_retried += 1
-                    self._pending.insert(0, r)
-                    break
-                self._retry.pop(id(r), None)     # clean slate on success
-                self.n_shared_pages += len(shared)
-                self.n_shared_tokens += shared_tokens
-                self._private[b] = n_priv
-                self._btabs.assign(b, shared + phys)
-                # chain state for indexing this slot's own chunks:
-                # _chain_key is the digest at token _indexed_upto
-                # (pages up to there are already in the index)
-                self._chain_key[b] = chain
-                self._indexed_upto[b] = full_tokens
-                if id(r) in self._swapped:
-                    st = self._swapped.pop(id(r))
-                    detail = ""
-                    if self._fires("swap_in"):
-                        detail = "injected swap_in fault"
-                    elif checksum(st["bufs"]) != st["crc"]:
-                        detail = "swap buffer failed checksum " \
-                                 "verification"
-                    if not detail:
-                        self._swap_in_slot(b, st["bufs"])
-                        self._activate(b, r, jnp.asarray(st["logits"]))
-                        self.n_swapped_in += 1
-                        continue
-                    if not sc.swap_fallback:
-                        self._release(b)
-                        self._fail_request(r, "swap_failed", detail)
-                        continue
-                    # degrade to recompute: the pages just assigned
-                    # already cover the effective prompt (generated
-                    # tokens ride as prompt suffix), so fall through
-                    # to the normal prefill path below — greedy
-                    # outputs are unchanged, only latency is paid
-                    self.n_swap_fallbacks += 1
+            if self._pindex is not None and id(r) not in self._swapped:
+                hits, chain, full_tokens, slog = self._pindex.walk(
+                    prompt, ps)
+                # same cap/fork rule the admission probe used, so
+                # the charge matches what _fits_now checked
+                kept, shared_tokens, fork, slog = self._cap_share(
+                    L, hits, slog)
+                shared = [p for _, p, _ in kept]
+                if shared:
+                    self._pindex.touch([k for k, _, _ in kept])
+                    self.pool.share(shared)
+            self._reserved[b] = self._worst_case_pages(r)
+            self._charged[b] = self._reserved[b] - len(shared) + fork
+            n_priv = pages_needed(L, ps) - len(shared)
+            try:
+                phys = self._alloc(n_priv)
+            except PagePoolExhausted:
+                # accounting said it fit but the pool disagrees
+                # (another admission this pass consumed the
+                # headroom, or an injected alloc fault): roll the
+                # admission back and retry with exponential
+                # backoff; a request whose retry budget is spent
+                # fails terminally (pool_exhausted) instead of
+                # waiting forever
+                if shared:
+                    self.pool.free(shared)
+                self._slot_req[b] = None
+                self._slot_prompt[b] = None
+                self._reserved[b] = 0
+                self._charged[b] = 0
+                n_tries, _ = self._retry.get(id(r), (0, 0))
+                if n_tries >= sc.admission_retries:
+                    self._fail_request(
+                        r, "pool_exhausted",
+                        f"admission allocation failed "
+                        f"{n_tries + 1} times (backoff spent)")
+                    continue
+                self._retry[id(r)] = (
+                    n_tries + 1,
+                    self._step_count + min(1 << n_tries, 32))
+                self.n_retried += 1
+                self._pending.insert(0, r)
+                break
+            self._retry.pop(id(r), None)     # clean slate on success
+            self.n_shared_pages += len(shared)
+            self.n_shared_tokens += shared_tokens
+            self._private[b] = n_priv
+            self._btabs.assign(b, shared + phys)
+            # chain state for indexing this slot's own chunks:
+            # _chain_key is the digest at token _indexed_upto
+            # (pages up to there are already in the index)
+            self._chain_key[b] = chain
+            self._indexed_upto[b] = full_tokens
+            if id(r) in self._swapped:
+                st = self._swapped.pop(id(r))
+                detail = ""
+                if self._fires("swap_in"):
+                    detail = "injected swap_in fault"
+                elif checksum(st["bufs"]) != st["crc"]:
+                    detail = "swap buffer failed checksum " \
+                             "verification"
+                if not detail:
+                    self._swap_in_slot(b, st["bufs"])
+                    self._activate(b, r, jnp.asarray(st["logits"]))
+                    self.n_swapped_in += 1
+                    continue
+                if not sc.swap_fallback:
+                    self._release(b)
+                    self._fail_request(r, "swap_failed", detail)
+                    continue
+                # degrade to recompute: the pages just assigned
+                # already cover the effective prompt (generated
+                # tokens ride as prompt suffix), so fall through
+                # to the normal prefill path below — greedy
+                # outputs are unchanged, only latency is paid
+                self.n_swap_fallbacks += 1
             if r.t_admitted is None:
                 r.t_admitted = time.perf_counter()
-            if sc.chunked_prefill:
-                if slog is not None:
-                    # whole prompt served from the index, next-token
-                    # logits included: no prefill chunk at all
-                    self._prefilled[b] = None
-                    self.n_full_hits += 1
-                    self._activate(b, r, jnp.asarray(slog))
-                    continue
-                # chunks run in _prefill_step, starting past the
-                # shared prefix
-                self._prefilled[b] = (shared_tokens if sc.paged else 0)
+            if slog is not None:
+                # whole prompt served from the index, next-token logits
+                # included: no prefill chunk at all
+                self._prefilled[b] = None
+                self.n_full_hits += 1
+                self._activate(b, r, jnp.asarray(slog))
                 continue
-            plogits, slot_cache = self._prefill(
-                self.params, self.proj, jnp.asarray(prompt)[None])
-            if sc.paged:
-                self._cache = self._paged_insert(
-                    self._cache, slot_cache,
-                    jnp.asarray(self._btabs.slot_pages[b], jnp.int32))
-            else:
-                self._cache = self._insert(self._cache, slot_cache,
-                                           np.int32(b))
-            self._activate(b, r, plogits[0, -1])
+            # chunks run in _prefill_step, starting past the shared
+            # prefix
+            self._prefilled[b] = shared_tokens
         return _occupied() - occ0
 
     def _prep_chunk(self, b: int, cap: Optional[int] = None):
@@ -1730,12 +1611,9 @@ class ServingEngine:
             return self._step_inner_budget()
         B = sc.max_batch
         self._admit()
-        if sc.paged:
-            self.peak_used_pages = max(self.peak_used_pages,
-                                       self.pool.used_count)
-        pf_budget = 0
-        if sc.chunked_prefill:
-            pf_budget = self._prefill_step()
+        self.peak_used_pages = max(self.peak_used_pages,
+                                   self.pool.used_count)
+        pf_budget = self._prefill_step()
         # decodable = admitted and fully prefilled; mid-prefill slots
         # hold their pages and join decode only when complete
         live = np.array([self._slot_req[b] is not None
@@ -1743,19 +1621,17 @@ class ServingEngine:
                          for b in range(B)])
         if not live.any():
             return self._busy()
-        btab_dev = None
-        if sc.paged:
-            # may preempt LIFO victims (optimistic admission) when the
-            # chunk's growth would exhaust the pool — mutates ``live``
-            self._ensure_chunk_headroom(live)
-            if not live.any():
-                return self._busy()
-            # mid-prefill / evicted rows export as garbage so the
-            # scan's masked writes cannot touch pages a prefill is
-            # filling or that were recycled
-            btab_dev = self._btabs.device(live=live)
-            self.peak_used_pages = max(self.peak_used_pages,
-                                       self.pool.used_count)
+        # may preempt LIFO victims (optimistic admission) when the
+        # chunk's growth would exhaust the pool — mutates ``live``
+        self._ensure_chunk_headroom(live)
+        if not live.any():
+            return self._busy()
+        # mid-prefill / evicted rows export as garbage so the scan's
+        # masked writes cannot touch pages a prefill is filling or that
+        # were recycled
+        btab_dev = self._btabs.device(live=live)
+        self.peak_used_pages = max(self.peak_used_pages,
+                                   self.pool.used_count)
         num_splits = self._live_splits(live)
         with TraceAnnotation("engine.dispatch", live=int(live.sum())):
             carry, toks, emits = self._decode_chunk(
@@ -1771,7 +1647,7 @@ class ServingEngine:
             # this very step instead of sitting idle for one chunk
             # (within the step's remaining prefill-chunk budget)
             self._admit()
-            if sc.chunked_prefill and pf_budget:
+            if pf_budget:
                 self._prefill_step(pf_budget)
         return self._busy()
 
@@ -1963,7 +1839,7 @@ class ServingEngine:
         started engine's state shapes — for compile checks such as
         whether a TPU build carries its Pallas kernels."""
         assert self._started, "call start(requests) first"
-        btab = self._btabs.device() if self.sc.paged else None
+        btab = self._btabs.device()
         return self._decode_chunk.lower(
             self.params, self.proj, self._cache, self._logits, self._pos,
             self._emitted, self._max_new, self._done, self._trunc,

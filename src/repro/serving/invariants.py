@@ -66,20 +66,18 @@ def scheduler_dump(eng) -> str:
     lines = [f"step={getattr(eng, '_step_count', '?')} "
              f"pending={[r.rid for r in eng._pending]} "
              f"swapped={len(eng._swapped)}"]
-    if eng.pool is not None:
-        lines.append(
-            f"pool: {eng.pool.used_count}/{eng.pool.n_pages} used, "
-            f"{eng.pool.free_count} free"
-            + (f", index pins={eng._pindex.n_pinned}"
-               if eng._pindex is not None else ""))
+    lines.append(
+        f"pool: {eng.pool.used_count}/{eng.pool.n_pages} used, "
+        f"{eng.pool.free_count} free"
+        + (f", index pins={eng._pindex.n_pinned}"
+           if eng._pindex is not None else ""))
     pos = np.asarray(eng._pos)
     done = np.asarray(eng._done)
     for b in range(eng.sc.max_batch):
         r = eng._slot_req[b]
         if r is None:
             continue
-        owned = (len(eng._btabs.slot_pages[b]) if eng._btabs is not None
-                 else "-")
+        owned = len(eng._btabs.slot_pages[b])
         lines.append(
             f"slot {b}: rid={r.rid} pos={int(pos[b])} "
             f"prefilled={eng._prefilled[b]} done={bool(done[b])} "
@@ -166,14 +164,13 @@ def _audit_slots(eng, bad: List[str]) -> None:
     done = np.asarray(eng._done)
     for b in range(sc.max_batch):
         r = eng._slot_req[b]
-        owned = len(eng._btabs.slot_pages[b]) if eng._btabs else 0
+        owned = len(eng._btabs.slot_pages[b])
         if r is None:
             if owned:
                 bad.append(f"slot {b}: empty but owns {owned} pages")
             if eng._prefilled[b] is not None:
                 bad.append(f"slot {b}: empty but mid-prefill")
-            if sc.paged and (eng._reserved[b] or eng._charged[b]
-                             or eng._private[b]):
+            if eng._reserved[b] or eng._charged[b] or eng._private[b]:
                 bad.append(f"slot {b}: empty but reserved/charged/"
                            f"private = {eng._reserved[b]}/"
                            f"{eng._charged[b]}/{eng._private[b]}")
@@ -181,8 +178,6 @@ def _audit_slots(eng, bad: List[str]) -> None:
         if r.done:
             bad.append(f"slot {b}: rid {r.rid} already done but "
                        f"still occupies the slot")
-        if not sc.paged:
-            continue
         if owned > eng._reserved[b]:
             bad.append(f"slot {b}: owns {owned} pages past its "
                        f"reserved cap {eng._reserved[b]}")
@@ -271,11 +266,10 @@ def audit(eng) -> None:
     ``step()``; with ``ServeConfig.audit=True`` the engine calls it
     itself at the end of every step."""
     bad: List[str] = []
-    if eng.sc.paged and eng.pool is not None:
-        _audit_pool(eng, bad)
-        _audit_block_tables(eng, bad)
-        _audit_layout(eng, bad)
-        _audit_swapped(eng, bad)
+    _audit_pool(eng, bad)
+    _audit_block_tables(eng, bad)
+    _audit_layout(eng, bad)
+    _audit_swapped(eng, bad)
     _audit_slots(eng, bad)
     if bad:
         raise InvariantViolation(bad, scheduler_dump(eng))

@@ -6,16 +6,17 @@ import dataclasses
 import os
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from repro.config import ServeConfig
 
-# CI engine matrix (.github/workflows/ci.yml): REPRO_ENGINE=paged runs
-# the serving tests against the paged cache + chunked prefill path;
-# paged-preempt additionally switches to optimistic admission over a
-# deliberately small pool so preempt-and-requeue actually fires under
-# pytest; paged-prefix turns on cross-request prefix sharing with
+# CI engine matrix (.github/workflows/ci.yml): the default (paged)
+# runs the serving tests on the engine's one path, the paged cache with
+# chunked prefill; paged-preempt additionally switches to optimistic
+# admission over a deliberately small pool so preempt-and-requeue
+# actually fires under pytest; paged-prefix turns on cross-request prefix sharing with
 # copy-on-write (refcounted pages + prefix index); paged-chaos layers
 # a seeded FaultInjector (recoverable points only — greedy outputs
 # stay token-for-token intact) plus per-step invariant auditing on top
@@ -31,19 +32,20 @@ from repro.config import ServeConfig
 # per-step dynamic split derivation (decode_splits=0); paged-sharded
 # runs the chaos stack on a multi-shard data mesh (ServeConfig.shards,
 # DESIGN.md §sharded-engine) over forced host devices — greedy outputs
-# must match the 1-shard legs token-for-token; the default (dense)
-# keeps the exact-length parity oracle.
-ENGINE = os.environ.get("REPRO_ENGINE", "dense")
+# must match the 1-shard legs token-for-token.  The parity reference
+# for every leg is ``manual_greedy`` below: the model's own prefill and
+# decode over a dense cache.
+ENGINE = os.environ.get("REPRO_ENGINE", "paged")
 
 
 def serve_config(**kw) -> ServeConfig:
     """ServeConfig honoring the CI engine matrix.
 
-    Tests that pin a specific layout construct ServeConfig directly;
-    everything routed through here runs dense by default and
-    paged+chunked under REPRO_ENGINE=paged (page_size 4 divides every
-    max_seq_len the serving tests use; prefill_chunk 8 forces
-    multi-chunk prompts).  REPRO_ENGINE=paged-preempt shrinks the pool
+    Tests that pin specific settings construct ServeConfig directly;
+    everything routed through here runs with page_size 4 (it divides
+    every max_seq_len the serving tests use) and prefill_chunk 8 (it
+    forces multi-chunk prompts) — the REPRO_ENGINE=paged leg, the
+    default.  REPRO_ENGINE=paged-preempt shrinks the pool
     to one worst-case sequence (max_seq_len / page_size pages — the
     smallest size at which no single request can fail admission) and
     turns on optimistic admission, so multi-slot tests oversubscribe
@@ -61,7 +63,7 @@ def serve_config(**kw) -> ServeConfig:
     additionally turns on the token-budget scheduler with a small
     per-step budget, so decode charges, residual-truncated prefill
     chunks, and fused iterations all fire under every serving test —
-    greedy outputs still must match the dense leg token-for-token.
+    greedy outputs still must match the paged leg token-for-token.
     REPRO_ENGINE=paged-longctx runs the paged stack with split-KV
     flash-decoding (decode_splits=3 — odd, so the tests' page chains
     split into uneven spans and boundary cases fire); greedy outputs
@@ -81,13 +83,8 @@ def serve_config(**kw) -> ServeConfig:
     (DESIGN.md §sharded-engine); shards adapts to the test's
     max_batch so every slot slice stays equal-width, and single-slot
     tests fall back to the unsharded oracle."""
-    if ENGINE in ("paged", "paged-preempt", "paged-prefix",
-                  "paged-chaos", "paged-budget", "paged-longctx",
-                  "paged-quant", "paged-sharded"):
-        kw.setdefault("paged", True)
-        kw.setdefault("page_size", 4)
-        kw.setdefault("chunked_prefill", True)
-        kw.setdefault("prefill_chunk", 8)
+    kw.setdefault("page_size", 4)
+    kw.setdefault("prefill_chunk", 8)
     if ENGINE == "paged-longctx":
         kw.setdefault("decode_splits", 3)
     if ENGINE in ("paged-preempt", "paged-chaos", "paged-budget",
@@ -125,12 +122,39 @@ def serve_config(**kw) -> ServeConfig:
         # admission actually happen under the tests' max_batch=4
         kw.setdefault("max_num_batched_tokens", 6)
     if ENGINE == "paged-quant":
-        if kw.get("paged"):
-            kw.setdefault("cache_quant", "int8")
-            # per-step split derivation from the live max length,
-            # snapped to {1, 2, 4, 8} (bounded-compile satellite)
-            kw.setdefault("decode_splits", 0)
+        kw.setdefault("cache_quant", "int8")
+        # per-step split derivation from the live max length, snapped
+        # to {1, 2, 4, 8} (bounded-compile satellite)
+        kw.setdefault("decode_splits", 0)
     return ServeConfig(**kw)
+
+
+def manual_greedy(model, params, prompt, n, max_seq_len=64, proj=None):
+    """Reference greedy decoding of one request: the model's own
+    ``prefill`` / ``decode_step`` over a dense ``max_seq_len`` cache,
+    token by token, with no engine in the way.
+
+    ``proj`` (solved ``ModelProjections``) serves the compressed cache.
+    Truncation follows the engine's: the token sampled once the cache
+    holds ``max_seq_len`` positions is still emitted, but nothing is
+    decoded from it, so at most ``max_seq_len - len(prompt) + 1``
+    tokens come back."""
+    kw = {} if proj is None else {"proj": model.projections_pytree(proj)}
+    toks = jnp.asarray(prompt, jnp.int32)[None]
+    logits, cache = model.prefill(params, {"tokens": toks}, max_seq_len,
+                                  **kw)
+    pos = toks.shape[1]
+    n = min(n, max_seq_len - pos + 1)
+    out = []
+    while True:
+        nxt = int(jnp.argmax(logits[0, -1]))
+        out.append(nxt)
+        if len(out) == n:
+            return out
+        logits, cache = model.decode_step(
+            params, cache, jnp.asarray([[nxt]], jnp.int32), jnp.int32(pos),
+            **kw)
+        pos += 1
 
 
 @pytest.fixture(scope="session")

@@ -22,8 +22,7 @@ from repro.models import build_model
 from repro.serving import Request, ServingEngine
 
 BASE = dict(max_seq_len=32, max_batch=4, temperature=0.0,
-            decode_chunk=4, paged=True, page_size=4,
-            chunked_prefill=True, prefill_chunk=8)
+            decode_chunk=4, page_size=4, prefill_chunk=8)
 LENS = (18, 3, 12, 9, 26, 5, 14)        # mixed multi/sub-chunk prompts
 
 
@@ -57,9 +56,6 @@ def check_accounting(eng):
 def test_config_validation():
     with pytest.raises(ValueError, match="max_num_batched_tokens"):
         ServeConfig(**BASE, max_num_batched_tokens=-1)
-    with pytest.raises(ValueError, match="chunked_prefill"):
-        ServeConfig(max_seq_len=32, max_batch=4,
-                    max_num_batched_tokens=8)
 
 
 @pytest.mark.parametrize("budget", [1, 6, 16])

@@ -1,9 +1,10 @@
 """Chunked, bucketed prefill into pages (DESIGN.md §prefill).
 
 Parity contract: the chunked+paged prefill path produces token-for-token
-identical generations to the exact-length dense-staging path, with at
-most ``len(buckets)`` prefill compiles per engine lifetime, and decode
-of other slots is unaffected while a slot is mid-prefill.
+identical generations to the model-level reference (exact-length
+``prefill`` plus ``decode_step`` over a dense cache, ``manual_greedy``),
+with at most ``len(buckets)`` prefill compiles per engine lifetime, and
+decode of other slots is unaffected while a slot is mid-prefill.
 """
 import pytest
 
@@ -11,7 +12,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from conftest import dropless
+from conftest import dropless, manual_greedy
 from repro.config import ServeConfig
 from repro.configs import get_config
 from repro.kernels.kq_decode import (kq_prefill_paged_attention_op,
@@ -22,6 +23,7 @@ from repro.serving.paged_cache import (GARBAGE_PAGE, append_chunk,
                                        gather_pages)
 
 CHUNK = 4
+T = 64
 
 
 def _setup():
@@ -31,20 +33,21 @@ def _setup():
     return cfg, model, params
 
 
-def _sc(**kw) -> ServeConfig:
-    base = dict(max_seq_len=64, max_batch=4, temperature=0.0,
-                decode_chunk=4)
+def _chunked_sc(**kw) -> ServeConfig:
+    base = dict(max_seq_len=T, max_batch=4, temperature=0.0,
+                decode_chunk=4, page_size=4, prefill_chunk=CHUNK,
+                prefill_buckets=(2, CHUNK))
     base.update(kw)
     return ServeConfig(**base)
 
 
-def _chunked_sc(**kw) -> ServeConfig:
-    return _sc(paged=True, page_size=4, chunked_prefill=True,
-               prefill_chunk=CHUNK, prefill_buckets=(2, CHUNK), **kw)
+def _reference(model, params, prompts, n=6, proj=None):
+    return [manual_greedy(model, params, p, n, T, proj=proj)
+            for p in prompts]
 
 
-def _generate(cfg, params, sc, prompts, n=6):
-    eng = ServingEngine(cfg, params, sc)
+def _generate(cfg, params, sc, prompts, n=6, proj=None):
+    eng = ServingEngine(cfg, params, sc, projections=proj)
     reqs = [Request(rid=i, prompt=p, max_new_tokens=n)
             for i, p in enumerate(prompts)]
     eng.generate(reqs)
@@ -64,9 +67,8 @@ def test_chunked_matches_exact_at_chunk_boundaries(rem):
     L = 2 * CHUNK + rem
     rng = np.random.default_rng(7 + rem)
     prompts = [rng.integers(0, cfg.vocab_size, L).astype(np.int32)]
-    exact, _ = _generate(cfg, params, _sc(), prompts)
     chunked, _ = _generate(cfg, params, _chunked_sc(), prompts)
-    assert exact == chunked
+    assert chunked == _reference(model, params, prompts)
 
 
 def test_chunked_mixed_lengths_match_exact():
@@ -76,9 +78,8 @@ def test_chunked_mixed_lengths_match_exact():
     lens = [3, 9, 6, 12, 5, 8]                 # > max_batch: forces refill
     prompts = [rng.integers(0, cfg.vocab_size, L).astype(np.int32)
                for L in lens]
-    exact, _ = _generate(cfg, params, _sc(), prompts)
     chunked, eng = _generate(cfg, params, _chunked_sc(), prompts)
-    assert exact == chunked
+    assert chunked == _reference(model, params, prompts)
     assert eng.pool.free_count == eng.pool.n_pages   # full drain
 
 
@@ -101,15 +102,9 @@ def test_chunked_compressed_matches_exact():
     rng = np.random.default_rng(13)
     prompts = [rng.integers(0, cfg.vocab_size, L).astype(np.int32)
                for L in (9, 5)]
-
-    def gen(sc):
-        eng = ServingEngine(cfg, params, sc, projections=proj)
-        reqs = [Request(rid=i, prompt=p, max_new_tokens=5)
-                for i, p in enumerate(prompts)]
-        eng.generate(reqs)
-        return [r.out_tokens for r in reqs]
-
-    assert gen(_sc()) == gen(_chunked_sc())
+    chunked, _ = _generate(cfg, params, _chunked_sc(), prompts, n=5,
+                           proj=proj)
+    assert chunked == _reference(model, params, prompts, n=5, proj=proj)
 
 
 def test_decode_unchanged_while_other_slot_prefills():
@@ -152,33 +147,28 @@ def test_prefill_compile_count_bounded_by_buckets():
 
 
 def test_bucket_derivation_and_lookup():
-    sc = ServeConfig(paged=True, page_size=4, chunked_prefill=True,
-                     prefill_chunk=64)
+    sc = ServeConfig(page_size=4, prefill_chunk=64)
     assert sc.buckets == (8, 16, 32, 64)       # derived by doubling
     assert sc.bucket_for(1) == 8
     assert sc.bucket_for(8) == 8
     assert sc.bucket_for(9) == 16
     assert sc.bucket_for(64) == 64
-    explicit = ServeConfig(paged=True, page_size=4, chunked_prefill=True,
-                           prefill_chunk=6, prefill_buckets=(2, 6))
+    explicit = ServeConfig(page_size=4, prefill_chunk=6,
+                           prefill_buckets=(2, 6))
     assert explicit.buckets == (2, 6)
     assert explicit.bucket_for(3) == 6
 
 
 def test_bucket_validation():
-    with pytest.raises(ValueError):            # chunked needs paged
-        ServeConfig(chunked_prefill=True)
     with pytest.raises(ValueError):            # largest bucket != chunk
-        ServeConfig(paged=True, page_size=4, chunked_prefill=True,
-                    prefill_chunk=8, prefill_buckets=(2, 4))
+        ServeConfig(page_size=4, prefill_chunk=8, prefill_buckets=(2, 4))
 
 
 def test_bucket_for_out_of_range_raises():
     """A chunk longer than the largest bucket must raise a clear error,
     not silently trace a fresh XLA shape past the len(buckets) compile
     bound (and never clamp, which would drop tokens)."""
-    sc = ServeConfig(paged=True, page_size=4, chunked_prefill=True,
-                     prefill_chunk=64)
+    sc = ServeConfig(page_size=4, prefill_chunk=64)
     with pytest.raises(ValueError, match="compile bound"):
         sc.bucket_for(65)
     with pytest.raises(ValueError, match="chunk length"):
@@ -299,7 +289,6 @@ def test_prefill_kernel_under_jit_traced_lengths():
 
 
 def test_serve_config_chunked_requires_whole_page_seq():
-    """Existing paged invariants still hold with chunking enabled."""
+    """max_seq_len must be a whole number of pages."""
     with pytest.raises(ValueError):
-        ServeConfig(max_seq_len=62, paged=True, page_size=4,
-                    chunked_prefill=True)
+        ServeConfig(max_seq_len=62, page_size=4)

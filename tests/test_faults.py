@@ -133,8 +133,7 @@ def setup():
 # long fresh requests grow the decode footprint past the 8-page pool
 # (swap preemption + reclaim under pressure)
 CHAOS_SC = dict(max_seq_len=32, max_batch=4, temperature=0.0,
-                decode_chunk=4, paged=True, page_size=8,
-                chunked_prefill=True, prefill_chunk=8,
+                decode_chunk=4, page_size=8, prefill_chunk=8,
                 share_prefix=True, admission="optimistic",
                 preempt_mode="swap", n_pages=8, watermark_low=0.1)
 
@@ -297,7 +296,7 @@ def test_admission_retry_exhaustion_fails_pool_exhausted(setup):
     cfg, model, params = setup
     inj = FaultInjector(seed=0).add("page_alloc", prob=1.0, times=None)
     sc = ServeConfig(max_seq_len=32, max_batch=2, temperature=0.0,
-                     decode_chunk=4, paged=True, page_size=8,
+                     decode_chunk=4, page_size=8, prefill_chunk=8,
                      n_pages=8, admission="optimistic",
                      admission_retries=2, audit=True)
     eng = ServingEngine(cfg, params, sc, faults=inj)
@@ -322,8 +321,7 @@ def test_watchdog_raises_on_stall(setup):
     inj = FaultInjector(seed=0).add("prefill_delay", prob=1.0,
                                     times=None)
     sc = ServeConfig(max_seq_len=32, max_batch=2, temperature=0.0,
-                     decode_chunk=4, paged=True, page_size=8,
-                     chunked_prefill=True, prefill_chunk=8,
+                     decode_chunk=4, page_size=8, prefill_chunk=8,
                      stall_steps=5, audit=True)
     eng = ServingEngine(cfg, params, sc, faults=inj)
     rng = np.random.default_rng(5)
@@ -341,7 +339,8 @@ def test_stall_steps_zero_disables_watchdog(setup):
     """``stall_steps=0`` must mean 'off', not 'trip immediately'."""
     cfg, model, params = setup
     sc = ServeConfig(max_seq_len=32, max_batch=2, temperature=0.0,
-                     decode_chunk=4, stall_steps=0)
+                     decode_chunk=4, page_size=8, prefill_chunk=8,
+                     stall_steps=0)
     eng = ServingEngine(cfg, params, sc)
     rng = np.random.default_rng(5)
     reqs = [Request(rid=0, prompt=rng.integers(
@@ -357,7 +356,7 @@ def test_numerics_quarantine_fails_only_poisoned_slot(setup):
     untouched and matches a fault-free run."""
     cfg, model, params = setup
     sc = ServeConfig(max_seq_len=32, max_batch=2, temperature=0.0,
-                     decode_chunk=4)
+                     decode_chunk=4, page_size=8, prefill_chunk=8)
     rng = np.random.default_rng(9)
     prompts = [rng.integers(0, cfg.vocab_size, 8).astype(np.int32)
                for _ in range(2)]
@@ -383,7 +382,8 @@ def test_guard_numerics_off_keeps_legacy_behavior(setup):
     yields), matching the pre-taxonomy engine."""
     cfg, model, params = setup
     sc = ServeConfig(max_seq_len=32, max_batch=1, temperature=0.0,
-                     decode_chunk=4, guard_numerics=False)
+                     decode_chunk=4, page_size=8, prefill_chunk=8,
+                     guard_numerics=False)
     inj = FaultInjector(seed=0).add("nan_logits", nth=1)
     eng = ServingEngine(cfg, params, sc, faults=inj)
     rng = np.random.default_rng(9)
@@ -400,7 +400,7 @@ def test_total_deadline_fails_with_partial_output(setup):
     tokens it already produced; an unbounded sibling is unaffected."""
     cfg, model, params = setup
     sc = ServeConfig(max_seq_len=64, max_batch=2, temperature=0.0,
-                     decode_chunk=2)
+                     decode_chunk=2, page_size=8, prefill_chunk=8)
     rng = np.random.default_rng(11)
     prompts = [rng.integers(0, cfg.vocab_size, 8).astype(np.int32)
                for _ in range(2)]
@@ -422,8 +422,7 @@ def test_ttft_deadline(setup):
     within one step); a sibling with budget completes."""
     cfg, model, params = setup
     sc = ServeConfig(max_seq_len=32, max_batch=2, temperature=0.0,
-                     decode_chunk=4, paged=True, page_size=8,
-                     chunked_prefill=True, prefill_chunk=8)
+                     decode_chunk=4, page_size=8, prefill_chunk=8)
     rng = np.random.default_rng(13)
     prompts = [rng.integers(0, cfg.vocab_size, 14).astype(np.int32)
                for _ in range(2)]

@@ -53,35 +53,29 @@ def _decoding(eng, cfg, n, seed=1):
 def _written(eng, leaf_shape, pos0, pos1, rows):
     """Mask of the entries one decode dispatch may write in a stacked
     cache leaf: each slot's positions ``pos0 .. pos1 - 1`` in every layer
-    and head (paged: through its block-table row; the garbage page takes
-    whatever the dead rows write)."""
+    and head, through its block-table row; the garbage page takes
+    whatever the dead rows write."""
     mask = np.zeros(leaf_shape, bool)
+    ps = eng.sc.page_size
     for b in range(len(pos0)):
         for p in range(pos0[b], pos1[b]):
-            if eng.sc.paged:
-                ps = eng.sc.page_size
-                mask[:, rows[b, p // ps], :, p % ps] = True
-            else:
-                mask[:, b, :, p] = True
-    if eng.sc.paged:
-        mask[:, 0] = True
+            mask[:, rows[b, p // ps], :, p % ps] = True
+    mask[:, 0] = True
     return mask
 
 
-@pytest.mark.parametrize("paged, quant", [(True, "none"), (True, "int8"),
-                                          (False, "none")],
-                         ids=["paged", "paged-int8", "dense"])
-def test_decode_dispatch_writes_only_its_tokens(setup, paged, quant):
+@pytest.mark.parametrize("quant", ["none", "int8"],
+                         ids=["paged", "paged-int8"])
+def test_decode_dispatch_writes_only_its_tokens(setup, quant):
     """One decode dispatch changes exactly the (layer, page, head,
     offset) entries of the tokens it decoded -- data pages and, for
     int8 pages, their scale pools alike -- and nothing else in any pool;
     the cache it was handed is donated (deleted)."""
     cfg, params = setup
-    kw = dict(paged=True, page_size=4, chunked_prefill=True,
-              prefill_chunk=8, cache_quant=quant) if paged else {}
     eng = ServingEngine(cfg, params, ServeConfig(
         max_seq_len=48, max_batch=3, temperature=0.0, decode_chunk=4,
-        **kw), projections=_projections(cfg))
+        page_size=4, prefill_chunk=8, cache_quant=quant),
+        projections=_projections(cfg))
     _decoding(eng, cfg, 3)
     old = eng._cache
     # a host view of a buffer would keep it from being donated: copy
@@ -93,7 +87,7 @@ def test_decode_dispatch_writes_only_its_tokens(setup, paged, quant):
     assert (pos1 - pos0 == eng.sc.decode_chunk).all()
     assert all(x.is_deleted() for x in jax.tree.leaves(old))
     after = jax.tree.map(np.asarray, eng._cache)
-    rows = eng._btabs.rows if paged else None
+    rows = eng._btabs.rows
     names = set()
     for layer_b, layer_a in zip(before["steps"]["layers"],
                                 after["steps"]["layers"]):
@@ -111,8 +105,7 @@ def test_decode_dispatch_writes_only_its_tokens(setup, paged, quant):
 # the chaos workload of test_faults.py: sharers that fork a partial page
 # (copy_page), swap preemption over an 8-page pool, prefix reclaim
 CHAOS_SC = dict(max_seq_len=32, max_batch=4, temperature=0.0,
-                decode_chunk=4, paged=True, page_size=8,
-                chunked_prefill=True, prefill_chunk=8,
+                decode_chunk=4, page_size=8, prefill_chunk=8,
                 share_prefix=True, admission="optimistic",
                 preempt_mode="swap", n_pages=8, watermark_low=0.1)
 

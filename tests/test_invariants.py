@@ -33,8 +33,7 @@ def setup():
 # oversubscribed sharing + swap workload: every audited structure is
 # exercised (shared refcounts > 1, COW, index pins, swap state)
 SC = dict(max_seq_len=32, max_batch=4, temperature=0.0,
-          decode_chunk=4, paged=True, page_size=8,
-          chunked_prefill=True, prefill_chunk=8, share_prefix=True,
+          decode_chunk=4, page_size=8, prefill_chunk=8, share_prefix=True,
           admission="optimistic", preempt_mode="swap", n_pages=8,
           watermark_low=0.1)
 

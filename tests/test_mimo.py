@@ -52,8 +52,7 @@ def _setup(n_layers=6, n_held=4, seed=0):
 
 def _engine(conf, w, proj, **serve):
     # audited every step: ring columns and ring pools included
-    sc = ServeConfig(**{**dict(max_seq_len=96, max_batch=2, paged=True,
-                                chunked_prefill=True, page_size=8,
+    sc = ServeConfig(**{**dict(max_seq_len=96, max_batch=2, page_size=8,
                                 prefill_chunk=32, decode_chunk=1,
                                 audit=True), **serve})
     params = jax.tree.map(lambda a: a.astype(jnp.float32), prog.params(w))
@@ -277,7 +276,6 @@ def test_step_layout_of_the_published_pattern():
 
 @pytest.mark.parametrize("bad", [dict(share_prefix=True),
                                  dict(preempt_mode="swap"),
-                                 dict(chunked_prefill=False),
                                  dict(cache_quant="int8")])
 def test_unsupported_serving_modes_are_refused(bad):
     conf, d, w, proj = _setup()

@@ -278,8 +278,7 @@ def setup():
 
 
 QUANT_SC = dict(max_seq_len=32, max_batch=2, temperature=0.0,
-                decode_chunk=4, paged=True, page_size=4,
-                chunked_prefill=True, prefill_chunk=8,
+                decode_chunk=4, page_size=4, prefill_chunk=8,
                 cache_quant="int8", audit=True)
 
 
@@ -384,8 +383,7 @@ def test_dynamic_splits_snap_to_pow2():
     model = build_model(cfg)
     params = model.init(jax.random.PRNGKey(0))
     sc = ServeConfig(max_seq_len=512, max_batch=2, temperature=0.0,
-                     paged=True, page_size=4, chunked_prefill=True,
-                     prefill_chunk=8, decode_splits=0)
+                     page_size=4, prefill_chunk=8, decode_splits=0)
     eng = ServingEngine(cfg, params, sc)
     assert eng._dynamic_splits
     seen = [eng._splits_for_step(n) for n in range(1, 513, 7)]
@@ -401,8 +399,7 @@ def test_dynamic_splits_bounded_compile_count():
     model = build_model(cfg)
     params = model.init(jax.random.PRNGKey(0))
     sc = ServeConfig(max_seq_len=64, max_batch=2, temperature=0.0,
-                     decode_chunk=4, paged=True, page_size=4,
-                     chunked_prefill=True, prefill_chunk=8,
+                     decode_chunk=4, page_size=4, prefill_chunk=8,
                      decode_splits=0)
     eng = ServingEngine(cfg, params, sc)
     rng_ = np.random.default_rng(9)

@@ -1,5 +1,8 @@
 """Paged KV cache: pool invariants, paged kernel parity, paged serving
-(DESIGN.md §paged-cache).  The dense path is the oracle throughout."""
+(DESIGN.md §paged-cache).  The dense path is the oracle throughout: the
+dense kernel and reference for the paged kernels, the model's own
+prefill / decode over a dense cache (``manual_greedy``) for the
+engine."""
 import dataclasses
 
 import pytest
@@ -8,6 +11,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from conftest import manual_greedy
 from repro.config import CompressionConfig, ServeConfig
 from repro.configs import get_config
 from repro.core.calibration import GramAccumulator
@@ -234,7 +238,7 @@ def test_lane_padding_paged():
 
 
 # ---------------------------------------------------------------------------
-# Paged serving == dense serving
+# Paged serving == the dense model-level reference
 # ---------------------------------------------------------------------------
 
 
@@ -266,6 +270,13 @@ def _run(cfg, params, proj, sc, prompts, max_new=6):
     return eng, reqs
 
 
+def _assert_reference(model, params, proj, sc, prompts, reqs, max_new):
+    """Each request's tokens equal the model-level dense reference."""
+    for p, r in zip(prompts, reqs):
+        assert r.out_tokens == manual_greedy(
+            model, params, p, max_new, sc.max_seq_len, proj=proj), r.rid
+
+
 def _mixed_prompts(cfg, lens, seed=3):
     rng_ = np.random.default_rng(seed)
     return [rng_.integers(0, cfg.vocab_size, n).astype(np.int32)
@@ -275,16 +286,14 @@ def _mixed_prompts(cfg, lens, seed=3):
 def test_paged_engine_matches_dense_mixed_lengths():
     """Mixed prompt lengths crossing page boundaries, more requests than
     slots (forces refill into freed pages): token-identical to the
-    dense engine."""
+    dense reference."""
     cfg, model, params, _ = _tiny()
     prompts = _mixed_prompts(cfg, [3, 9, 6, 12, 5, 8])   # 8, 9 straddle ps=8
     sc = ServeConfig(max_seq_len=32, max_batch=4, temperature=0.0,
-                     decode_chunk=4)
-    _, dense = _run(cfg, params, None, sc, prompts)
-    sc_p = dataclasses.replace(sc, paged=True, page_size=8)
-    eng, paged = _run(cfg, params, None, sc_p, prompts)
-    for d, p in zip(dense, paged):
-        assert d.out_tokens == p.out_tokens, d.rid
+                     decode_chunk=4, page_size=8, prefill_chunk=8)
+    eng, paged = _run(cfg, params, None, sc, prompts)
+    _assert_reference(model, params, None, sc, prompts, paged, 6)
+    for p in paged:
         assert p.done and not p.truncated
     # every page returned to the pool once the batch drained
     assert eng.pool.free_count == eng.pool.n_pages
@@ -293,52 +302,44 @@ def test_paged_engine_matches_dense_mixed_lengths():
 def test_paged_engine_compressed_pallas_kernel(tpu_kernels):
     """Compressed cache with the kernels on the path, as on a TPU
     backend: the paged Pallas kernel runs inside the fused decode scan
-    and matches the dense engine."""
+    and matches the dense reference."""
     cfg, model, params, proj = _tiny(compressed=True)
     prompts = _mixed_prompts(cfg, [4, 11, 7], seed=5)
     sc = ServeConfig(max_seq_len=32, max_batch=2, temperature=0.0,
-                     decode_chunk=4)
-    _, dense = _run(cfg, params, proj, sc, prompts, max_new=5)
-    sc_p = dataclasses.replace(sc, paged=True, page_size=8)
-    _, paged = _run(cfg, params, proj, sc_p, prompts, max_new=5)
-    for d, p in zip(dense, paged):
-        assert d.out_tokens == p.out_tokens, d.rid
+                     decode_chunk=4, page_size=8, prefill_chunk=8)
+    _, paged = _run(cfg, params, proj, sc, prompts, max_new=5)
+    _assert_reference(model, params, proj, sc, prompts, paged, 5)
 
 
 def test_engine_full_cache_kernels_match_lax(monkeypatch):
     """A full (uncompressed) cache takes the kernels too when they are
-    on the path: dense decode, paged decode and paged chunked prefill
-    all match the lax engine token for token."""
+    on the path: paged decode and paged chunked prefill match the lax
+    dense reference token for token."""
     import repro.kernels
     cfg, model, params, _ = _tiny()
     prompts = _mixed_prompts(cfg, [4, 11, 7], seed=9)
     sc = ServeConfig(max_seq_len=32, max_batch=2, temperature=0.0,
-                     decode_chunk=4)
-    sc_p = dataclasses.replace(sc, paged=True, page_size=8,
-                               chunked_prefill=True, prefill_chunk=8)
-    _, ref = _run(cfg, params, None, sc, prompts, max_new=5)
+                     decode_chunk=4, page_size=8, prefill_chunk=8)
+    ref = [manual_greedy(model, params, p, 5, sc.max_seq_len)
+           for p in prompts]
     monkeypatch.setattr(repro.kernels, "use_kernels", lambda: True)
-    for conf in (sc, sc_p):
-        _, got = _run(cfg, params, None, conf, prompts, max_new=5)
-        for r, g in zip(ref, got):
-            assert r.out_tokens == g.out_tokens, (conf.paged, r.rid)
+    _, got = _run(cfg, params, None, sc, prompts, max_new=5)
+    assert [g.out_tokens for g in got] == ref
 
 
 def test_paged_engine_oversubscribed_pool_reuses_freed_pages():
     """A pool sized for ~one request at a time: admission backpressure
     holds later requests pending until freed pages return, and outputs
-    stay identical to the dense engine."""
+    stay identical to the dense reference."""
     cfg, model, params, _ = _tiny()
     prompts = _mixed_prompts(cfg, [9, 7, 10], seed=11)
-    sc = ServeConfig(max_seq_len=32, max_batch=2, temperature=0.0,
-                     decode_chunk=4)
-    _, dense = _run(cfg, params, None, sc, prompts)
     # 3 pages: fits one request (prompt<=10 tokens + 6 new < 3*8) but
     # never two concurrently -> the second/third must reuse freed pages
-    sc_p = dataclasses.replace(sc, paged=True, page_size=8, n_pages=3)
-    eng, paged = _run(cfg, params, None, sc_p, prompts)
-    for d, p in zip(dense, paged):
-        assert d.out_tokens == p.out_tokens, d.rid
+    sc = ServeConfig(max_seq_len=32, max_batch=2, temperature=0.0,
+                     decode_chunk=4, page_size=8, prefill_chunk=8,
+                     n_pages=3)
+    eng, paged = _run(cfg, params, None, sc, prompts)
+    _assert_reference(model, params, None, sc, prompts, paged, 6)
     assert eng.pool.free_count == 3
 
 
@@ -346,8 +347,7 @@ def test_paged_engine_too_big_prompt_fails():
     """A prompt that cannot ever fit the pool is failed at admission
     (not raised, not hung) — DESIGN.md §preemption."""
     cfg, model, params, _ = _tiny()
-    sc = ServeConfig(max_seq_len=32, max_batch=2, paged=True, page_size=8,
-                     n_pages=1)
+    sc = ServeConfig(max_seq_len=32, max_batch=2, page_size=8, n_pages=1)
     eng = ServingEngine(cfg, params, sc)
     prompt = _mixed_prompts(cfg, [12])[0]            # needs 2 pages > 1
     reqs = [Request(rid=0, prompt=prompt, max_new_tokens=4)]
@@ -363,8 +363,8 @@ def test_paged_engine_too_big_growth_fails():
     failed at admission (it could never complete even alone), not
     aborted mid-decode."""
     cfg, model, params, _ = _tiny()
-    sc = ServeConfig(max_seq_len=32, max_batch=1, paged=True, page_size=8,
-                     n_pages=1, decode_chunk=4)
+    sc = ServeConfig(max_seq_len=32, max_batch=1, page_size=8, n_pages=1,
+                     decode_chunk=4)
     eng = ServingEngine(cfg, params, sc)
     prompt = _mixed_prompts(cfg, [5])[0]             # 1 page, then grows
     reqs = [Request(rid=0, prompt=prompt, max_new_tokens=12)]
@@ -376,20 +376,30 @@ def test_paged_engine_too_big_growth_fails():
 def test_paged_engine_truncation_matches_dense():
     cfg, model, params, _ = _tiny()
     prompts = _mixed_prompts(cfg, [10], seed=13)
-    sc = ServeConfig(max_seq_len=16, max_batch=2, decode_chunk=4)
-    _, dense = _run(cfg, params, None, sc, prompts, max_new=10)
-    sc_p = dataclasses.replace(sc, paged=True, page_size=8)
-    _, paged = _run(cfg, params, None, sc_p, prompts, max_new=10)
-    assert dense[0].out_tokens == paged[0].out_tokens
+    sc = ServeConfig(max_seq_len=16, max_batch=2, decode_chunk=4,
+                     page_size=8, prefill_chunk=8)
+    _, paged = _run(cfg, params, None, sc, prompts, max_new=10)
+    _assert_reference(model, params, None, sc, prompts, paged, 10)
+    # positions 10..15 plus the final sampled-but-unplaceable token
+    assert len(paged[0].out_tokens) == 7
     assert paged[0].done and paged[0].truncated
 
 
-def test_paged_rejects_unsupported_configs():
-    cfg, model, params, _ = _tiny()
-    cfg_w = dataclasses.replace(cfg, sliding_window=16)
-    params_w = build_model(cfg_w).init(jax.random.PRNGKey(0))
-    sc = ServeConfig(max_seq_len=32, max_batch=2, paged=True, page_size=8)
-    with pytest.raises(NotImplementedError):
-        ServingEngine(cfg_w, params_w, sc)
+@pytest.mark.parametrize("arch, refusal", [
+    ("tinyllama-1.1b", "sliding window not supported"),
+    ("mamba2-2.7b", "plain attention stacks only"),
+    ("deepseek-v2-lite-16b", "plain attention stacks only")],
+    ids=["model-wide-window", "ssm", "mla"])
+def test_paged_rejects_unsupported_configs(arch, refusal):
+    """Stacks the paged path cannot hold are refused at construction,
+    not mid-serve: a model-wide sliding window, state-space layers and
+    latent attention."""
+    cfg = get_config(arch).reduced()
+    if arch == "tinyllama-1.1b":
+        cfg = dataclasses.replace(cfg, sliding_window=16)
+    params = build_model(cfg).init(jax.random.PRNGKey(0))
+    sc = ServeConfig(max_seq_len=32, max_batch=2, page_size=8)
+    with pytest.raises(NotImplementedError, match=refusal):
+        ServingEngine(cfg, params, sc)
     with pytest.raises(ValueError):                  # T % page_size != 0
-        ServeConfig(max_seq_len=20, max_batch=2, paged=True, page_size=8)
+        ServeConfig(max_seq_len=20, max_batch=2, page_size=8)

@@ -45,13 +45,14 @@ def _run(cfg, params, sc, prompts, max_new=6):
 # five requests of worst case 3 pages each (prompt ~14 + 6 new @ ps=8)
 # against a 9-page pool: sum of worst cases 15 > 9 — oversubscribed
 OVERSUB = dict(max_seq_len=32, max_batch=4, temperature=0.0,
-               decode_chunk=4, paged=True, page_size=8)
+               decode_chunk=4, page_size=8, prefill_chunk=8)
 LENS = (14, 13, 14, 13, 14)
 
 
-def _oversub_case(cfg, params, **kw):
+def _oversub_case(cfg, params, n_pages=9, **kw):
     ample = ServeConfig(**OVERSUB)               # n_pages=0: full capacity
-    small = ServeConfig(**OVERSUB, n_pages=9, admission="optimistic", **kw)
+    small = ServeConfig(**OVERSUB, n_pages=n_pages, admission="optimistic",
+                        **kw)
     prompts = _prompts(cfg, LENS)
     _, reserve = _run(cfg, params, ample, prompts)
     eng, opt = _run(cfg, params, small, prompts)
@@ -74,9 +75,12 @@ def test_optimistic_recompute_matches_reserve():
 
 def test_optimistic_swap_matches_reserve():
     """Swap mode round-trips victims through host RAM instead of
-    recomputing: byte-exact restore, same outputs, swaps observed."""
+    recomputing: byte-exact restore, same outputs, swaps observed.
+    A 5-page pool admits only two prompts at a time, so the victims
+    have finished their prefill and hold decode state to swap (a
+    mid-prefill victim is recomputed, not swapped)."""
     cfg, model, params = _setup()
-    eng = _oversub_case(cfg, params, preempt_mode="swap")
+    eng = _oversub_case(cfg, params, n_pages=5, preempt_mode="swap")
     assert eng.n_preempted >= 1
     assert eng.n_swapped_out >= 1
     assert eng.n_swapped_in == eng.n_swapped_out     # every victim resumed
@@ -89,8 +93,7 @@ def test_optimistic_chunked_prefill_matches_reserve():
     chunk-by-chunk; mid-prefill victims fall back to recompute)."""
     cfg, model, params = _setup()
     base = dict(max_seq_len=32, max_batch=4, temperature=0.0,
-                decode_chunk=4, paged=True, page_size=4,
-                chunked_prefill=True, prefill_chunk=8)
+                decode_chunk=4, page_size=4, prefill_chunk=8)
     prompts = _prompts(cfg, (14, 13, 14, 13, 14, 6), seed=5)
     _, reserve = _run(cfg, params, ServeConfig(**base), prompts)
     for mode in ("recompute", "swap"):
@@ -109,15 +112,16 @@ def test_victims_are_lifo():
     keeps running."""
     cfg, model, params = _setup()
     sc = ServeConfig(max_seq_len=32, max_batch=2, temperature=0.0,
-                     decode_chunk=4, paged=True, page_size=8, n_pages=5,
-                     admission="optimistic")
+                     decode_chunk=4, page_size=8, prefill_chunk=16,
+                     n_pages=4, admission="optimistic")
     eng = ServingEngine(cfg, params, sc)
     reqs = [Request(rid=i, prompt=p, max_new_tokens=6)
             for i, p in enumerate(_prompts(cfg, (14, 14), seed=7))]
     eng.start(reqs)
     eng.step()
-    # both prompts admitted (2 pages each of 5); the first growth to a
-    # 3rd page fits only one slot -> slot 1 (younger stamp) evicted
+    # both prompts admitted (2 pages each of 4) and slot 0 prefilled in
+    # one chunk; its first growth to a 3rd page finds the pool full ->
+    # slot 1 (younger stamp) evicted
     assert eng._slot_req[0] is reqs[0]
     assert eng._slot_req[1] is None
     assert eng._pending and eng._pending[0] is reqs[1]
@@ -162,7 +166,8 @@ def test_reserve_window_admits_small_request_past_blocked_head():
     a bounded window instead of only _pending[0])."""
     cfg, model, params = _setup()
     sc = ServeConfig(max_seq_len=32, max_batch=2, temperature=0.0,
-                     decode_chunk=4, paged=True, page_size=8, n_pages=4)
+                     decode_chunk=4, page_size=8, prefill_chunk=8,
+                     n_pages=4)
     prompts = _prompts(cfg, (8, 8, 6), seed=13)
     reqs = [Request(rid=0, prompt=prompts[0], max_new_tokens=8),
             Request(rid=1, prompt=prompts[1], max_new_tokens=24),  # 4 pages
@@ -246,7 +251,7 @@ def test_too_big_request_fails_without_aborting_batch():
     cfg, model, params = _setup()
     for admission in ("reserve", "optimistic"):
         sc = ServeConfig(max_seq_len=32, max_batch=2, temperature=0.0,
-                         decode_chunk=4, paged=True, page_size=8,
+                         decode_chunk=4, page_size=8, prefill_chunk=8,
                          n_pages=2, admission=admission)
         prompts = _prompts(cfg, (6, 6, 5), seed=19)
         reqs = [Request(rid=0, prompt=prompts[0], max_new_tokens=4),

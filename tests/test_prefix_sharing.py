@@ -31,8 +31,7 @@ def _setup():
 
 def _sc(**kw) -> ServeConfig:
     base = dict(max_seq_len=32, max_batch=4, temperature=0.0,
-                decode_chunk=4, paged=True, page_size=4,
-                chunked_prefill=True, prefill_chunk=4)
+                decode_chunk=4, page_size=4, prefill_chunk=4)
     base.update(kw)
     return ServeConfig(**base)
 
@@ -161,8 +160,8 @@ def test_shared_pages_survive_sibling_release():
 
 
 OVERSUB = dict(max_seq_len=32, max_batch=4, temperature=0.0,
-               decode_chunk=4, paged=True, page_size=8,
-               chunked_prefill=True, prefill_chunk=8, share_prefix=True)
+               decode_chunk=4, page_size=8, prefill_chunk=8,
+               share_prefix=True)
 
 
 @pytest.mark.parametrize("mode", ["recompute", "swap"])
@@ -361,12 +360,9 @@ def test_block_table_device_export_is_cached():
     assert bt.device() is not dev2
 
 
-def test_share_prefix_requires_chunked_prefill():
-    with pytest.raises(ValueError, match="chunked_prefill"):
-        ServeConfig(paged=True, page_size=4, share_prefix=True)
+def test_prefix_index_capacity_validation():
     with pytest.raises(ValueError, match="capacity"):
-        ServeConfig(paged=True, page_size=4, chunked_prefill=True,
-                    prefill_chunk=4, share_prefix=True,
+        ServeConfig(page_size=4, prefill_chunk=4, share_prefix=True,
                     prefix_index_capacity=0)
 
 

@@ -4,10 +4,9 @@ import dataclasses
 import pytest
 
 import jax
-import jax.numpy as jnp
 import numpy as np
 
-from conftest import dropless, serve_config
+from conftest import dropless, manual_greedy, serve_config
 from repro.config import CompressionConfig
 from repro.configs import get_config
 from repro.core.calibration import GramAccumulator
@@ -35,23 +34,6 @@ def setup(compressed=False, rank=None):
     sc = serve_config(max_seq_len=64, max_batch=4, temperature=0.0)
     return cfg, model, params, ServingEngine(cfg, params, sc,
                                              projections=proj)
-
-
-def manual_greedy(model, params, prompt, n):
-    toks = jnp.asarray(prompt)[None]
-    out = []
-    logits, cache = model.prefill(params, {"tokens": toks}, 64)
-    nxt = int(jnp.argmax(logits[0, -1]))
-    out.append(nxt)
-    pos = toks.shape[1]
-    for _ in range(n - 1):
-        logits, cache = model.decode_step(
-            params, cache, jnp.asarray([[nxt]], jnp.int32),
-            jnp.int32(pos))
-        nxt = int(jnp.argmax(logits[0, -1]))
-        out.append(nxt)
-        pos += 1
-    return out
 
 
 def test_engine_matches_manual_greedy():

@@ -52,9 +52,8 @@ def mk(rid, length, max_new=5):
     return Request(rid=rid, prompt=p, max_new_tokens=max_new)
 
 
-BASE = dict(max_seq_len=64, temperature=0.0, decode_chunk=4, paged=True,
-            page_size=4, chunked_prefill=True, prefill_chunk=8,
-            share_prefix=True, preempt_mode="swap",
+BASE = dict(max_seq_len=64, temperature=0.0, decode_chunk=4,
+            page_size=4, prefill_chunk=8, share_prefix=True, preempt_mode="swap",
             admission="optimistic", watermark_low=0.1, audit=True,
             audit_every=2)
 
@@ -144,17 +143,14 @@ def test_sharded_subprocess():
 
 
 def test_shards_validation():
-    base = dict(max_seq_len=32, max_batch=4, paged=True, page_size=4,
-                chunked_prefill=True, prefill_chunk=8)
+    base = dict(max_seq_len=32, max_batch=4, page_size=4, prefill_chunk=8)
     with pytest.raises(ValueError, match="shards must be >= 1"):
         ServeConfig(**base, shards=0)
-    with pytest.raises(ValueError, match="paged=True"):
-        ServeConfig(max_seq_len=32, max_batch=4, shards=2)
     with pytest.raises(ValueError, match="token-budget"):
         ServeConfig(**base, shards=2, max_num_batched_tokens=6)
     with pytest.raises(ValueError, match="max_batch 3"):
-        ServeConfig(max_seq_len=32, max_batch=3, paged=True, page_size=4,
-                    chunked_prefill=True, prefill_chunk=8, shards=2)
+        ServeConfig(max_seq_len=32, max_batch=3, page_size=4,
+                    prefill_chunk=8, shards=2)
     with pytest.raises(ValueError, match="total_pages 5"):
         ServeConfig(**base, shards=2, n_pages=5)
     # equal slices of both axes: fine

@@ -309,8 +309,7 @@ def test_engine_split_decode_greedy_parity():
         return [Request(rid=i, prompt=prompts[i], max_new_tokens=6)
                 for i in range(4)]
     base = dict(max_seq_len=32, max_batch=4, temperature=0.0,
-                decode_chunk=4, paged=True, page_size=4,
-                chunked_prefill=True, prefill_chunk=8)
+                decode_chunk=4, page_size=4, prefill_chunk=8)
     outs = {}
     for splits in (1, 3):
         eng = ServingEngine(cfg, params,
@@ -323,12 +322,9 @@ def test_engine_split_decode_greedy_parity():
 
 def test_decode_splits_config_validation():
     with pytest.raises(ValueError):
-        ServeConfig(decode_splits=2)          # requires paged
-    with pytest.raises(ValueError):
-        ServeConfig(paged=True, decode_splits=-1)
+        ServeConfig(decode_splits=-1)
     # 0 derives the heuristic at engine construction
-    sc = ServeConfig(paged=True, page_size=64, max_seq_len=4096,
-                     decode_splits=0)
+    sc = ServeConfig(page_size=64, max_seq_len=4096, decode_splits=0)
     assert sc.decode_splits == 0
     sc1 = dataclasses.replace(sc, decode_splits=1)
     assert sc1.decode_splits == 1

@@ -42,7 +42,8 @@ def test_full_lifecycle():
 
     # compressed serving
     eng = ServingEngine(cfg, params, ServeConfig(max_seq_len=64,
-                                                 max_batch=2),
+                                                 max_batch=2, page_size=16,
+                                                 prefill_chunk=16),
                         projections=mp)
     reqs = [Request(rid=i, prompt=np.arange(8, dtype=np.int32),
                     max_new_tokens=4) for i in range(2)]

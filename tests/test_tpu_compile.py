@@ -214,8 +214,7 @@ def engine_programs(one_chip, kernels_on_path):
     cfg = get_config("tinyllama-1.1b").reduced()
     params = build_model(cfg).init(jax.random.PRNGKey(0))
     eng = ServingEngine(cfg, params, ServeConfig(
-        max_seq_len=64, max_batch=4, paged=True, page_size=16,
-        chunked_prefill=True, prefill_chunk=32))
+        max_seq_len=64, max_batch=4, page_size=16, prefill_chunk=32))
     eng.start([])
     return _abstract_engine(eng, one_chip, 32)
 
@@ -243,8 +242,7 @@ def phi3_programs(one_chip, kernels_on_path):
                             c_v=np.zeros((L, hkv, r, d), np.float32),
                             ranks_k=[r] * L, ranks_v=[r] * L)
     eng = ServingEngine(cfg, params, ServeConfig(
-        max_seq_len=3072, max_batch=3, paged=True, chunked_prefill=True,
-        n_pages=48), projections=proj)
+        max_seq_len=3072, max_batch=3, n_pages=48), projections=proj)
     eng.start([])
     return _abstract_engine(eng, one_chip, 512)
 
